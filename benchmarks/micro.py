@@ -16,9 +16,10 @@ ops/_algos.py; the measured table lives in docs/microbenchmarks.md).
 Usage:  python benchmarks/micro.py [--json] [--save]
 
 Timing protocol: each measurement chains ``iters`` collectives inside one
-jitted program (so dispatch overhead amortizes), syncs via a host fetch
-(remote-attached devices do not honor block_until_ready), and reports the
-best of 3 trials.
+jitted program (so dispatch overhead amortizes), closes the timed region
+with ``jax.block_until_ready`` on the program's outputs, and reports the
+best of 3 trials.  Operands are committed to the mesh once
+(``mpx.shard_global``), so no timed call re-shards them off one device.
 """
 
 import argparse
@@ -37,20 +38,18 @@ import jax.numpy as jnp  # noqa: E402
 import mpi4jax_tpu as mpx  # noqa: E402
 
 
-def _time_program(fn, args, trials=3):
-    """Best-of-N wall time of ``fn(*args)`` with host-fetch sync."""
-    def sync(out):
-        # single-element fetch with no reshape: plain indexing slices one
-        # element off the leading shard (ravel() would dispatch a full
-        # device reshape of the global array inside the timed window)
-        leaf = jax.tree.leaves(out)[0]
-        np.asarray(leaf[(0,) * leaf.ndim])
+def _ones(comm, *shape):
+    """A global f32 operand of ones, committed to ``comm``'s mesh."""
+    return mpx.shard_global(np.ones(shape, np.float32), comm)
 
-    sync(fn(*args))  # compile + drain queue
+
+def _time_program(fn, args, trials=3):
+    """Best-of-N wall time of ``fn(*args)``, device work included."""
+    jax.block_until_ready(fn(*args))  # compile + drain queue
     best = float("inf")
     for _ in range(trials):
         t0 = time.perf_counter()
-        sync(fn(*args))
+        jax.block_until_ready(fn(*args))
         best = min(best, time.perf_counter() - t0)
     return best
 
@@ -69,7 +68,7 @@ def bench_allreduce(comm, sizes_mb, iters=20):
 
             return jax.lax.fori_loop(0, iters, body, x)
 
-        x = jnp.ones((n, nelem), jnp.float32)
+        x = _ones(comm, n, nelem)
         t = _time_program(prog, (x,)) / iters
         # ring-allreduce bus bandwidth per device
         bus_bytes = 2 * (n - 1) / n * nelem * 4
@@ -95,7 +94,7 @@ def bench_sendrecv_ring(comm, sizes_kb, iters=50):
 
             return jax.lax.fori_loop(0, iters, body, x)
 
-        x = jnp.ones((n, nelem), jnp.float32)
+        x = _ones(comm, n, nelem)
         t = _time_program(prog, (x,)) / iters
         rows.append({
             "size_kb": round(nelem * 4 / 1e3, 2),
@@ -123,7 +122,7 @@ def bench_prod_and_split(comm, sizes_mb, iters=20):
 
             return jax.lax.fori_loop(0, iters, body, x)
 
-        x = jnp.ones((n, nelem), jnp.float32)
+        x = _ones(comm, n, nelem)
         t_whole = _time_program(prog, (x,)) / iters
 
         t_split = None
@@ -172,7 +171,7 @@ def bench_allreduce_algos(comm, sizes_mb, iters=20):
 
                     return jax.lax.fori_loop(0, iters, body, x)
 
-                x = jnp.ones((n, nelem), jnp.float32)
+                x = _ones(comm, n, nelem)
                 t = _time_program(prog, (x,)) / iters
                 row[f"{algo}_us"] = round(t * 1e6, 1)
             # on 1 device both settings lower to the identity — no crossover
@@ -230,7 +229,7 @@ def bench_hierarchy(comm, sizes_mb=(1, 4), topologies=("2x4", "4x2"),
 
                         return jax.lax.fori_loop(0, iters, body, x)
 
-                    x = jnp.ones((n, nelem), jnp.float32)
+                    x = _ones(comm, n, nelem)
                     t = _time_program(prog, (x,)) / iters
                     row[f"{label}_us"] = round(t * 1e6, 1)
                 row["hier_speedup"] = (
@@ -294,7 +293,7 @@ def bench_alltoall(comm, sizes_mb=(0.25, 1), topologies=(None,), iters=10,
                     for k, v in env.items():
                         os.environ[k] = str(v)
                     try:
-                        x = jnp.ones((n, n, per), jnp.float32)
+                        x = _ones(comm, n, n, per)
                         w = jnp.full((n, compute_dim, compute_dim), 0.01,
                                      jnp.float32)
                         return _time_program(fn(), (x, w)) / iters
@@ -441,7 +440,7 @@ def bench_overlap(comm, sizes_mb=(1, 4), iters=10, compute_dim=128):
 
             return jax.lax.fori_loop(0, iters, body, (x, w))
 
-        x = jnp.ones((n, nelem), jnp.float32)
+        x = _ones(comm, n, nelem)
         w = jnp.full((n, compute_dim, compute_dim), 0.01, jnp.float32)
         from mpi4jax_tpu.utils.config import overlap_chunks
 
@@ -541,7 +540,7 @@ def bench_dispatch(comm, sizes_kb=(0.004, 4, 64), iters=100):
     rows = []
     for kb in sizes_kb:
         nelem = max(1, int(kb * 1e3 / 4))
-        x = jnp.ones((n, nelem), jnp.float32)
+        x = _ones(comm, n, nelem)
 
         def eager_call(v):
             return mpx.allreduce(v, op=mpx.SUM)[0]
@@ -598,17 +597,15 @@ def bench_dispatch_unroll(comm, unrolls=(1, 8, 64), size_kb=0.004,
     """
     n = comm.Get_size()
     nelem = max(1, int(size_kb * 1e3 / 4))
-    x = jnp.ones((n, nelem), jnp.float32)
+    x = _ones(comm, n, nelem)
     unrolls = sorted(set(int(u) for u in unrolls))
 
     def per_rank(v):
         return mpx.varying(mpx.allreduce(v, op=mpx.SUM)[0] * (1.0 / n))
 
     walls = {}
-    fast_paths = {}
     for u in unrolls:
         pinned = mpx.compile(per_rank, x, comm=comm, unroll=u)
-        fast_paths[u] = pinned.fast_path
         pinned(x)
         jax.block_until_ready(pinned(x))  # compile + drain
         best = float("inf")
@@ -635,7 +632,6 @@ def bench_dispatch_unroll(comm, unrolls=(1, 8, 64), size_kb=0.004,
             "megastep_us": round(wall * 1e6, 2),
             "per_step_us": round(wall / u * 1e6, 3),
             "per_step_host_us": round(max(0.0, wall / u - d) * 1e6, 3),
-            "fast_path": fast_paths[u],
         })
     return {
         "size_kb": round(nelem * 4 / 1e3, 3),
@@ -668,7 +664,7 @@ def bench_health_overhead(comm, sizes_kb=(0.004, 4, 64), iters=200):
     try:
         for kb in sizes_kb:
             nelem = max(1, int(kb * 1e3 / 4))
-            x = jnp.ones((n, nelem), jnp.float32)
+            x = _ones(comm, n, nelem)
             row = {"size_kb": round(nelem * 4 / 1e3, 3)}
 
             def eager_call(v):
@@ -950,7 +946,7 @@ def main():
                    help="also run the health-plane overhead sweep "
                         "(per-call dispatch cost under off / counters / "
                         "counters+flight-ring / events across payloads; "
-                        "the counters+ring column must stay within 10% "
+                        "the counters+ring column must stay within 10%% "
                         "of counters-only — docs/observability.md "
                         "'Runtime health')")
     p.add_argument("--health-sizes-kb", type=float, nargs="+",
@@ -969,6 +965,9 @@ def main():
                         "docs/analysis.md 'Cost model')")
     args = p.parse_args()
 
+    from mpi4jax_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     devices = jax.devices()
     mesh = mpx.make_world_mesh(devices=devices)
     comm = mpx.Comm(mesh.axis_names[0], mesh=mesh)
@@ -1047,15 +1046,12 @@ def main():
         # self-description (jax/jaxlib, topology, config stamp): saved
         # sweeps are fitter inputs, so they must say what produced them
         "provenance": provenance_block(devices[0].platform, n),
-        # honesty marker (docs/microbenchmarks.md): with a single
-        # device there is no interconnect to measure, and dispatch/
-        # attach overhead can dominate the timings — never read 1-device
-        # numbers as link bandwidth or latency
+        # with a single device there is no interconnect to measure:
+        # never read 1-device numbers as link bandwidth or latency
         "environment": (
-            f"{n}-device {devices[0].platform}"
-            + ("; no interconnect to measure — timings may be "
-               "dispatch/attach-dominated (docs/microbenchmarks.md)"
-               if n == 1 else "")
+            f"{n}-device {devices[0].platform} "
+            f"({devices[0].device_kind})"
+            + ("; no interconnect to measure" if n == 1 else "")
         ),
         "allreduce": ar,
         "sendrecv_ring": pp,

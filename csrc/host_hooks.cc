@@ -192,9 +192,13 @@ ffi::Error WatchdogArmImpl(ffi::BufferR0<ffi::U32> rank,
   return ffi::Error::Success();
 }
 
-ffi::Error WatchdogDisarmImpl(ffi::BufferR0<ffi::U32> rank,
+// `dep` is an element of the collective's first output: an operand only so
+// that the call cannot be scheduled before the collective (and so before its
+// arm, whose output the collective's inputs are computed from).
+ffi::Error WatchdogDisarmImpl(ffi::BufferR0<ffi::U32> rank, ffi::AnyBuffer dep,
                               ffi::Result<ffi::BufferR0<ffi::U32>> out,
                               std::string_view call_id) {
+  (void)dep;
   uint32_t r = rank.typed_data()[0];
   std::string key = std::string(call_id) + ":" + std::to_string(r);
   {
@@ -261,5 +265,6 @@ XLA_FFI_DEFINE_HANDLER_SYMBOL(MpxWatchdogArm, WatchdogArmImpl,
 XLA_FFI_DEFINE_HANDLER_SYMBOL(MpxWatchdogDisarm, WatchdogDisarmImpl,
                               ffi::Ffi::Bind()
                                   .Arg<ffi::BufferR0<ffi::U32>>()
+                                  .Arg<ffi::AnyBuffer>()
                                   .Ret<ffi::BufferR0<ffi::U32>>()
                                   .Attr<std::string_view>("call_id"));

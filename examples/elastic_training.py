@@ -17,8 +17,11 @@ Two modes:
 
       python examples/elastic_training.py
 
-- **multi-process drill** (``--launch N``): N worker processes (one CPU
-  device each) over ``jax.distributed``; kill one with the fault
+- **multi-process drill** (``--launch N``): a CPU-world drill — N worker
+  processes over ``jax.distributed``, each forced onto one virtual CPU
+  device (``JAX_PLATFORMS=cpu``), so it never needs an accelerator and
+  never competes for one (a chip belongs to one process; the launching
+  parent stays off jax); kill one with the fault
   injector and the survivors re-bootstrap a smaller world —
 
       MPI4JAX_TPU_FAULT_SPEC='die:rank=3:op=allreduce:after=5' \\
@@ -81,7 +84,9 @@ def _parse_args(argv=None):
                    help="write the per-step loss trace as JSON here")
     # multi-process drill plumbing
     p.add_argument("--launch", type=int, default=0, metavar="N",
-                   help="launch an N-process world and run the drill")
+                   help="launch an N-process world and run the drill (a "
+                        "CPU-world drill: every worker is forced onto "
+                        "one virtual CPU device)")
     p.add_argument("--grow", action="store_true",
                    help="--launch parent: spawn a replacement worker "
                         "(join_and_run) for each rank the fault injector "

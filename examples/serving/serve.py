@@ -18,7 +18,11 @@ KV slots scatter-managed so churn never retraces.  Three modes:
   scheduler on the cost-model replay (serving/sim.py) — no devices
   touched; the capture path for containers without an accelerator;
 
-- **drain drill** (``--launch N``): N worker processes serve one trace
+- **drain drill** (``--launch N``): a CPU-world drill — N worker
+  processes, each forced onto one virtual CPU device
+  (``JAX_PLATFORMS=cpu``), so it never needs an accelerator and never
+  competes for one (a chip belongs to one process; the launching parent
+  stays off jax).  The workers serve one trace
   in lockstep (virtual clock); at ``--drain-boundary`` the drained rank
   posts its preemption notice (the same ``request_drain`` path a
   SIGTERM or the ``preempt`` fault verb feeds), the world executes the
@@ -89,7 +93,9 @@ def _parse_args(argv=None):
                    help="write the BENCH_serving.json payload here")
     # drain drill plumbing
     p.add_argument("--launch", type=int, default=0, metavar="N",
-                   help="launch an N-process drill world")
+                   help="launch an N-process drill world (a CPU-world "
+                        "drill: every worker is forced onto one virtual "
+                        "CPU device)")
     p.add_argument("--drain-rank", type=int, default=-1,
                    help="drill: rank that receives the preemption notice "
                         "(-1 = last)")
@@ -167,19 +173,28 @@ def run_simulate(args):
 
 
 def run_benchmark(args):
+    import jax
+
     import mpi4jax_tpu as mpx
     from mpi4jax_tpu import serving
+    from mpi4jax_tpu.utils.compile_cache import ensure_compile_cache
 
+    ensure_compile_cache()
     cfg = _config(args, serving)
     trace, meta = _trace(args, cfg, serving)
     comm = mpx.get_default_comm()
     k = comm.world_size()
+    device = jax.devices()[0]
 
     results = {}
+    warm_s = {}
     schedulers = (("continuous", "static") if args.scheduler == "both"
                   else (args.scheduler,))
     for sched in schedulers:
         engine = serving.ServingEngine(cfg, comm)
+        # compile every (bucket, phase) program before the first request:
+        # a latency percentile must not contain a compile
+        warm_s[sched] = round(engine.warm(), 3)
         results[sched] = engine.run(trace, scheduler=sched)
         if not args.json:
             r = results[sched]
@@ -193,12 +208,13 @@ def run_benchmark(args):
     payload = serving.bench_payload(
         workload=cfg.workload_meta(k), trace_meta=meta, chips=k,
         continuous=cont, static=results.get("static"),
-        environment=(f"measured: {k}-device "
-                     "mesh (examples/serving/serve.py)"),
+        environment=(f"measured: {k}-device {device.platform} mesh, "
+                     f"{device.device_kind} (examples/serving/serve.py)"),
     )
     from mpi4jax_tpu.aot import stats as aot_stats
 
     payload["compile_cache"] = aot_stats()
+    payload["warm_compile_s"] = warm_s
     _emit(args, payload)
 
 

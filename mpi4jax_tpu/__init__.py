@@ -75,6 +75,7 @@ from .parallel import (  # noqa: F401
     pipeline,
     run,
     set_default_mesh,
+    shard_global,
     shift,
     spmd,
 )
@@ -182,6 +183,7 @@ __all__ = [
     "init_distributed",
     "spmd",
     "run",
+    "shard_global",
     "shift",
     "flush",
     "clear_caches",

@@ -89,12 +89,12 @@ MODELED_OPS = (
 # - gamma: ~400 GB/s local fold throughput (reduction combine is
 #   HBM-streaming-bound, faster than the wire);
 # - compute_gb_per_s: the HBM-roofline throughput the per-rank compute
-#   estimate divides jaxpr memory traffic by — ~300 GB/s matches the
-#   measured shallow-water state traffic (BENCH_r05
-#   state_traffic_gb_per_s = 298);
-# - dispatch_us: fixed host dispatch per step — BENCH_r05's
-#   dispatch_overhead_s over its step count is ~140 us/step (the cost
-#   ``mpx.compile`` unroll= amortizes ~1/N, docs/aot.md).
+#   estimate divides jaxpr memory traffic by — ~300 GB/s, an analytic
+#   placeholder well under the v5e's 819 GB/s peak;
+# - dispatch_us: fixed host dispatch per step, ~140 us (the cost
+#   ``mpx.compile`` unroll= amortizes ~1/N, docs/aot.md).  Neither is
+#   fitted to a chip: the committed replay records (BENCH_*.json) were
+#   generated with these values, and a tuning file overrides them.
 DEFAULT_PARAMS = {
     "links": {
         ICI: {"alpha_us": 1.0, "gb_per_s": 100.0},

@@ -8,8 +8,7 @@ multi-host cold start.
 
 - ``mpx.compile(fn, *abstract_args, comm=..., donate_argnums=...,
   unroll=N)`` -> :class:`PinnedProgram` (pinning.py; ``unroll=N`` pins
-  a device-resident megastep — parallel/megastep.py), driven through
-  jax's C++ fast-path dispatch where available (fastpath.py);
+  a device-resident megastep — parallel/megastep.py);
 - ``mpx.aot.compile_step(fn, unroll=N)`` — the elastic adapter: pinned
   (mega)step functions that ``mpx.elastic.run`` re-pins across epoch
   changes;
@@ -26,7 +25,7 @@ invalidation rules, the multi-host cold-start recipe, flag table).
 """
 
 from .invalidation import StaleProgramError, WorldStamp  # noqa: F401
-from . import diskcache, fastpath, keys, warm  # noqa: F401
+from . import diskcache, keys, warm  # noqa: F401
 from .pinning import (  # noqa: F401
     ElasticStep,
     PinnedProgram,

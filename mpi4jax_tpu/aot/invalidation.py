@@ -58,15 +58,8 @@ STORAGE_ONLY_FLAGS = (
     "MPI4JAX_TPU_COMPILE_CACHE_MAX_BYTES",
 )
 
-# Like the storage-only knobs, the C++ fast-path toggle never shapes a
-# trace — it only decides HOW an already-compiled pin is driven
-# (aot/fastpath.py).  Flipping it affects future pins' call path, not
-# the validity of live ones, so it must not revoke them either.
-DISPATCH_ONLY_FLAGS = ("MPI4JAX_TPU_CPP_DISPATCH",)
-
 _WORLD_FLAG_NAMES = tuple(
-    n for n in config.FLAG_NAMES
-    if n not in STORAGE_ONLY_FLAGS + DISPATCH_ONLY_FLAGS
+    n for n in config.FLAG_NAMES if n not in STORAGE_ONLY_FLAGS
 )
 
 

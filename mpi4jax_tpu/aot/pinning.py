@@ -1,7 +1,6 @@
 """AOT program pinning: ``mpx.compile`` and the persistent-tier glue.
 
-BENCH_r05 put host-side dispatch at ~14% of the shallow-water wall even
-after the flag-parse fast path (PR 5): a cache-HIT ``spmd`` call still
+Even after the flag-parse fast path (PR 5), a cache-HIT ``spmd`` call still
 normalizes statics, rebuilds the key, probes the program cache, and
 meters — per call, forever.  The AOT layer ends that: once the program
 is fixed, the hot loop should execute a **pinned artifact** (JAX's
@@ -55,7 +54,7 @@ __all__ = ["PinnedProgram", "compile", "compile_step", "stats",
 
 class _Stats:
     __slots__ = ("pins", "calls", "stale_raises", "disk_loads", "compiles",
-                 "fast_path_pins", "warmed")
+                 "warmed")
 
     def __init__(self):
         self.reset()
@@ -66,7 +65,6 @@ class _Stats:
         self.stale_raises = 0
         self.disk_loads = 0
         self.compiles = 0
-        self.fast_path_pins = 0
         self.warmed = 0
 
 
@@ -77,9 +75,8 @@ def stats() -> dict:
     """AOT-layer counters: ``pins`` (programs pinned), ``calls`` (pinned
     executions), ``stale_raises`` (MPX129 refusals), ``disk_loads``
     (pins served by deserializing a persistent artifact), ``compiles``
-    (pins that lowered+compiled fresh), ``fast_path_pins`` (pins driven
-    through jax's C++ fast-path dispatch — aot/fastpath.py), ``warmed``
-    (programs pre-compiled by the cache-warming CLI — aot/warm.py)."""
+    (pins that lowered+compiled fresh), ``warmed`` (programs
+    pre-compiled by the cache-warming CLI — aot/warm.py)."""
     return {k: getattr(_stats, k) for k in _Stats.__slots__}
 
 
@@ -195,19 +192,15 @@ def _consts_digest(closed_jaxpr) -> tuple:
     ``str(jaxpr)`` prints constants by shape/dtype only — two programs
     differing in a baked-in weight array would render identically and
     collide on one disk key, serving the wrong executable.  Hash the
-    bytes; anything unhashable falls back to a type marker plus a
-    process-independent best-effort repr (and, being unrecognizable,
-    simply keys conservatively)."""
+    bytes (jaxpr constants are arrays; one that is not is an error, not
+    a guessed key)."""
     import numpy as np
 
     out = []
-    for c in getattr(closed_jaxpr, "consts", ()):
-        try:
-            arr = np.asarray(c)
-            out.append((str(arr.dtype), arr.shape,
-                        keys.fingerprint(arr.tobytes())))
-        except Exception:
-            out.append((type(c).__name__, repr(c)[:256]))
+    for c in closed_jaxpr.consts:
+        arr = np.asarray(c)
+        out.append((str(arr.dtype), arr.shape,
+                    keys.fingerprint(arr.tobytes())))
     return tuple(out)
 
 
@@ -234,57 +227,26 @@ def _pin_executable(jitted, mesh, avals, label: str,
     for them — only a true ``mpx.compile`` pin is exempt.
     """
     with (_pinned_trace_scope() if mark_pinned else _null_scope()):
-        use_disk = diskcache.enabled() and serialization.supported()
-        trace_fn = getattr(jitted, "trace", None)
-        if trace_fn is not None:
-            traced = trace_fn(*avals)
-            program_text = str(traced.jaxpr)
-            consts = _consts_digest(traced.jaxpr)
-            lower = traced.lower
-        else:  # older AOT API: no .trace — fingerprint the lowering
-            lowered = jitted.lower(*avals)
-            program_text = lowered.as_text()
-            consts = ()
-            lower = lambda: lowered  # noqa: E731
-
+        traced = jitted.trace(*avals)
         key = None
-        if use_disk:
+        if diskcache.enabled():
             key = keys.derive_key(
-                keys.fingerprint(program_text) + ":"
-                + keys.fingerprint(keys.canonical(consts)),
+                keys.fingerprint(str(traced.jaxpr)) + ":"
+                + keys.fingerprint(
+                    keys.canonical(_consts_digest(traced.jaxpr))),
                 mesh_descriptor(mesh),
                 _dynamic_token(),
                 toolchain_versions(),
             )
             payload = diskcache.get(key)
             if payload is not None:
-                loaded = serialization.loads(payload)
-                if loaded is not None:
-                    _stats.disk_loads += 1
-                    return loaded, key, True
-                # version-skew the key should have caught, or a pickle
-                # the running process cannot reconstruct: recompile and
-                # overwrite the artifact
-        compiled = lower().compile()
+                _stats.disk_loads += 1
+                return serialization.loads(payload), key, True
+        compiled = traced.lower().compile()
         _stats.compiles += 1
         if key is not None:
-            data = serialization.dumps(compiled)
-            if data is not None:
-                diskcache.put(key, data)
+            diskcache.put(key, serialization.dumps(compiled))
         return compiled, key, False
-
-
-def _dispatch_call(compiled):
-    """The call the hot loop will drive: jax's C++ fast-path dispatch
-    when available and not disabled (``MPI4JAX_TPU_CPP_DISPATCH``), else
-    the plain ``Compiled`` — returns ``(call, used_fastpath)``."""
-    from ..utils.config import cpp_dispatch
-
-    if not cpp_dispatch():
-        return compiled, False
-    from . import fastpath
-
-    return fastpath.cpp_call_for(compiled)
 
 
 def through_disk_cache(jitted, c, label: str = "fn"):
@@ -309,9 +271,6 @@ def through_disk_cache(jitted, c, label: str = "fn"):
         if call is None:
             call, _, _ = _pin_executable(jitted, mesh, _abstract(args),
                                          label, mark_pinned=False)
-            # spmd misses served through the disk tier get the same C++
-            # fast-path dispatch a pin would (fallback: the Compiled)
-            call, _ = _dispatch_call(call)
             memo[sig] = call
         return call(*args)
 
@@ -329,10 +288,8 @@ class PinnedProgram:
     ``program(*dynamic_args)`` validates the captured world — one epoch
     int compare plus one raw-environment fingerprint compare; no flag
     parsing, no key hashing, no cache probe — and executes the pinned
-    executable.  Where the running jaxlib exposes the C++ fast-path
-    dispatch (aot/fastpath.py; ``fast_path`` records it), that execution
-    is ONE C++ call — no Python tree flattening or signature re-checking
-    either.  A moved world (config stamp or elastic epoch) raises
+    executable (``jax.stages.Compiled.__call__``, which dispatches
+    through jax's C++ pjit path after its first call).  A moved world (config stamp or elastic epoch) raises
     :class:`StaleProgramError` (MPX129); ``repin()`` rebuilds against
     the current world.
 
@@ -345,12 +302,11 @@ class PinnedProgram:
     """
 
     __slots__ = ("_call", "_world", "_stats", "_respec", "fn_name", "key",
-                 "from_disk", "donate_argnums", "fast_path", "unroll",
+                 "from_disk", "donate_argnums", "unroll",
                  "_traceable", "_donate_call")
 
     def __init__(self, call, world: WorldStamp, respec, fn_name: str,
-                 key, from_disk: bool, donate_argnums,
-                 fast_path: bool = False, unroll: int = 1,
+                 key, from_disk: bool, donate_argnums, unroll: int = 1,
                  traceable=None, donate_call=None):
         self._call = call
         self._world = world
@@ -360,7 +316,6 @@ class PinnedProgram:
         self.key = key
         self.from_disk = from_disk
         self.donate_argnums = donate_argnums
-        self.fast_path = fast_path
         self.unroll = unroll
         # the traceable jit twin of the pinned executable (same fn, same
         # donation semantics): the dataflow hazard verifier's re-trace
@@ -402,7 +357,6 @@ class PinnedProgram:
         return (f"PinnedProgram({self.fn_name!r}, {src}, "
                 f"epoch={self._world.epoch}"
                 + (f", unroll={self.unroll}" if self.unroll > 1 else "")
-                + (", cpp" if self.fast_path else "")
                 + (", STALE" if self.is_stale() else "") + ")")
 
 
@@ -621,17 +575,14 @@ def compile(fn, *abstract_args, comm=None, donate_argnums=(),
     # stamp that (correctly, conservatively) refuses the first call
     world = WorldStamp.capture()
     call, key, from_disk = _pin_executable(jitted, mesh, trace_args, name)
-    call, fast = _dispatch_call(call)
     _stats.pins += 1
-    if fast:
-        _stats.fast_path_pins += 1
     _meter("aot.pins")
 
     def respec():
         return compile(fn, *abstract_args, **spec)
 
     return PinnedProgram(call, world, respec, name, key, from_disk, donate,
-                         fast_path=fast, unroll=n_unroll,
+                         unroll=n_unroll,
                          traceable=traceable, donate_call=donate_call)
 
 

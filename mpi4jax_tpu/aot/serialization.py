@@ -1,70 +1,44 @@
-"""Compiled-executable (de)serialization — gated on JAX support.
+"""Compiled-executable (de)serialization for the persistent tier.
 
-The persistent tier stores *loaded-executable* artifacts: the XLA
-executable bytes plus the call signature trees, via
-``jax.experimental.serialize_executable`` (the same machinery JAX's own
-persistent compilation cache rides).  Everything here degrades
-gracefully:
+The persistent tier (``MPI4JAX_TPU_COMPILE_CACHE_DIR``) stores
+*loaded-executable* artifacts: the XLA executable bytes plus the call
+signature trees, via ``jax.experimental.serialize_executable`` (the same
+machinery JAX's own persistent compilation cache rides).
 
-- ``supported()`` probes the API once; absent (old JAX, or a backend
-  whose PjRt client cannot serialize executables) the persistent tier
-  simply stores nothing — pinning still works, it just recompiles;
-- ``dumps`` returns ``None`` instead of raising on any serialization
-  failure (an unserializable program must not take the pin down);
-- ``loads`` returns ``None`` on any deserialization failure — the
-  caller treats it as a cache miss and recompiles (diskcache's container
-  digest already filtered bit-rot; this filters version skew the key
-  should have caught and anything pickle-level).
+The tier is opt-in, so a failure here is reported, not hidden: an
+operator who named a cache directory and gets nothing stored — or
+recompiles on every boot — must see why.  ``dumps`` and ``loads`` raise
+whatever the serializer raises (an unserializable program, a backend
+whose PjRt client cannot serialize, a payload this process cannot
+reconstruct).  diskcache's container digest has already filtered
+bit-rot, and the key carries the toolchain versions, so a payload that
+reaches ``loads`` was written by this same toolchain.
 
 Payload format (inside the diskcache container): pickle of
-``(SERIALIZED_EXECUTABLE_BYTES, in_tree, out_tree)``.  PyTreeDefs of
-standard containers pickle portably; exotic custom nodes may not — that
-is one of the graceful-``None`` paths above.
+``(SERIALIZED_EXECUTABLE_BYTES, in_tree, out_tree)``.  Only payloads
+this program wrote are ever unpickled.
 """
 
 from __future__ import annotations
 
 import pickle
-from typing import Optional
 
 _PROTO = 4  # stable across the supported Pythons
 
 
-def _api():
-    from jax.experimental import serialize_executable as se
+def dumps(compiled) -> bytes:
+    """Serialize a ``jax.stages.Compiled`` into an artifact payload."""
+    from jax.experimental import serialize_executable
 
-    return se
-
-
-def supported() -> bool:
-    """True when this JAX exposes the executable-serialization API."""
-    try:
-        se = _api()
-    except ImportError:
-        return False
-    return hasattr(se, "serialize") and hasattr(se, "deserialize_and_load")
-
-
-def dumps(compiled) -> Optional[bytes]:
-    """Serialize a ``jax.stages.Compiled`` into an artifact payload, or
-    ``None`` when this program/backend cannot serialize."""
-    if not supported():
-        return None
-    try:
-        payload, in_tree, out_tree = _api().serialize(compiled)
-        return pickle.dumps((payload, in_tree, out_tree), protocol=_PROTO)
-    except Exception:
-        return None
+    payload, in_tree, out_tree = serialize_executable.serialize(compiled)
+    return pickle.dumps((payload, in_tree, out_tree), protocol=_PROTO)
 
 
 def loads(data: bytes):
     """Deserialize an artifact payload back into a callable
-    ``jax.stages.Compiled``, or ``None`` on any failure (caller
-    recompiles)."""
-    if not supported():
-        return None
-    try:
-        payload, in_tree, out_tree = pickle.loads(data)
-        return _api().deserialize_and_load(payload, in_tree, out_tree)
-    except Exception:
-        return None
+    ``jax.stages.Compiled``."""
+    from jax.experimental import serialize_executable
+
+    payload, in_tree, out_tree = pickle.loads(data)
+    return serialize_executable.deserialize_and_load(
+        payload, in_tree, out_tree)

@@ -223,7 +223,6 @@ def warm_program(spec: ProgramSpec, comm=None) -> dict:
     return {
         "fn": spec.fn,
         "from_disk": program.from_disk,
-        "fast_path": program.fast_path,
         "unroll": program.unroll,
         "key": program.key,
         "pin_wall_s": round(wall, 4),

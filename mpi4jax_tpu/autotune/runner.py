@@ -246,13 +246,10 @@ def autotune(comm=None, budget_s: float = 60.0, save: Optional[str] = None,
     if comm is None:
         comm = _world_comm()
     n = comm.Get_size()
-    platform = "unknown"
-    try:
-        import jax
-
-        platform = jax.devices()[0].platform
-    except Exception:
-        pass
+    # the tuning is for the devices the sweeps run on: the comm's mesh
+    # (only a scripted comm has none — tests/test_autotune_pure.py)
+    platform = ("unknown" if comm.mesh is None
+                else comm.mesh.devices.flat[0].platform)
     _meter("autotune.runs")
 
     def note(msg):

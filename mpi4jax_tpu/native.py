@@ -48,10 +48,6 @@ _HANDLERS = ("MpxOpBegin", "MpxOpEnd", "MpxAbortIf", "MpxWallclock",
 _TARGETS = ("mpx_op_begin", "mpx_op_end", "mpx_abort_if", "mpx_wallclock",
             "mpx_watchdog_arm", "mpx_watchdog_disarm")
 
-# handlers actually present in the loaded .so (an older build may predate
-# the watchdog hooks; feature probes below consult this set)
-_loaded_handlers: set = set()
-
 
 def build(verbose: bool = True) -> str:
     """Compile csrc/host_hooks.cc → mpi4jax_tpu/_lib/libmpx_hooks.so.
@@ -82,15 +78,14 @@ def _load() -> Optional[ctypes.CDLL]:
         return None
     _lib = ctypes.CDLL(_LIB_PATH)
     if not _registered:
+        # the library is built from csrc/host_hooks.cc of this checkout
+        # (build()); one that lacks a handler is a broken build, and the
+        # AttributeError says which
         for handler, target in zip(_HANDLERS, _TARGETS):
-            try:
-                sym = getattr(_lib, handler)
-            except AttributeError:
-                continue  # stale .so from before this hook existed
             jax.ffi.register_ffi_target(
-                target, jax.ffi.pycapsule(sym), platform="cpu",
+                target, jax.ffi.pycapsule(getattr(_lib, handler)),
+                platform="cpu",
             )
-            _loaded_handlers.add(handler)
         _registered = True
     return _lib
 
@@ -205,14 +200,13 @@ def watchdog_supported() -> bool:
     """True when the C++ watchdog registry/monitor can back the collective
     watchdog (native library built with the watchdog hooks, CPU backend —
     same availability rule as the runtime trace hooks)."""
-    return (
-        runtime_tracing_supported() and "MpxWatchdogArm" in _loaded_handlers
-    )
+    return runtime_tracing_supported()
 
 
 def watchdog_arm(opname: str, call_id: str, rank, axes: str, timeout: float):
     """Register one in-flight collective with the C++ watchdog; returns a u32
-    the op's inputs must be tied to (so arming precedes the collective)."""
+    the op's inputs are computed from (``watchdog.after_arm``), so arming
+    precedes the collective."""
     call = jax.ffi.ffi_call(
         "mpx_watchdog_arm",
         jax.ShapeDtypeStruct((), jnp.uint32),
@@ -228,14 +222,15 @@ def watchdog_arm(opname: str, call_id: str, rank, axes: str, timeout: float):
 
 
 def watchdog_disarm(call_id: str, rank, dep):
-    """Deregister after the collective: ``dep`` (the op's first output) ties
-    the call after completion."""
+    """Deregister after the collective: ``dep`` (an element of the op's
+    first output) is a second operand the handler ignores, which orders the
+    call after completion."""
     call = jax.ffi.ffi_call(
         "mpx_watchdog_disarm",
         jax.ShapeDtypeStruct((), jnp.uint32),
         has_side_effect=True,
     )
-    return call(_tie(jnp.asarray(rank, jnp.uint32), dep), call_id=call_id)
+    return call(jnp.asarray(rank, jnp.uint32), dep, call_id=call_id)
 
 
 # Base timestamp for the pure-Python fallback, captured at first use.  Raw

@@ -458,9 +458,8 @@ _eager_cache_stats = {"hits": 0, "misses": 0, "evictions": 0}
 # the dispatch fast path
 # ---------------------------------------------------------------------------
 #
-# BENCH_r05.json measured dispatch_overhead_s at ~14% of the shallow-water
-# wall: the cache-HIT path was re-parsing ~10 environment flags (float,
-# choice, and fault-spec grammars) and re-hashing the full key tuple on
+# The cache-HIT path used to re-parse ~10 environment flags (float,
+# choice, and fault-spec grammars) and re-hash the full key tuple on
 # every call.  Two memos remove that:
 #
 # - ``_dynamic_state()``: the flag-derived half of the cache key, parsed

@@ -40,9 +40,8 @@ def _apply_permute(xl, recvbuf, pairs, comm):
 
     An identity routing — every pair ``(r, r)``, e.g. any wrapping
     ``shift`` on a size-1 axis — skips the collective entirely: the
-    permutation is a per-rank no-op, and CollectivePermute is far from
-    free on real interconnects (and costs ~100 us per MB on the
-    single-chip attach platform, docs/shallow_water.md "Roofline").
+    permutation is a per-rank no-op, and a CollectivePermute that moves
+    nothing still costs a copy.
     Empty pairs (a non-wrapping shift on a size-1 axis) elide the same
     way — the receiver mask below already hands every rank its recvbuf.
     Transpose/AD semantics are unchanged (the inverse of the identity is

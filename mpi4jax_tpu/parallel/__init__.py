@@ -31,5 +31,6 @@ from .region import (  # noqa: F401
     in_parallel_region,
     resolve_comm,
     run,
+    shard_global,
     spmd,
 )

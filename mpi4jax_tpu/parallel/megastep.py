@@ -1,8 +1,6 @@
 """Megastep execution: device-resident multi-step loops.
 
-BENCH_r05 put the residual host tax at ~14% of the shallow-water wall
-even on the pinned path (956 delivered vs 1106 on-chip steps/s/chip,
-``dispatch_overhead_s`` 0.063): every step still crosses Python once.
+A pinned program still crosses Python once per step.
 The megastep compiler ends that the way CUDA Graphs' capture-and-replay
 amortizes launch overhead — ``mpx.compile(fn, unroll=N)`` (and
 ``mpx.spmd(..., unroll=N)``) rewrite the step body into a device-resident
@@ -243,14 +241,13 @@ def megastep_loop(body_fn, carry, unroll: int, comm, label: str = "fn"):
     timeout = _resilience.effective_watchdog_timeout()
     wd_call_id = rank = None
     if timeout is not None and leaves:
-        from .. import native
         from ..resilience import watchdog as wd
 
         wd_call_id = _next_call_id()
         rank = comm.global_rank()
         armed = wd.arm_in_graph(f"MPI_Megastep[{label}]", wd_call_id, comm,
                                 rank, timeout * n)
-        carry = jax.tree.map(lambda v: native._tie(v, armed), carry)
+        carry = jax.tree.map(lambda v: wd.after_arm(v, armed), carry)
 
     # one events-tier journal bracket per megastep execution
     from ..telemetry import core as _tcore

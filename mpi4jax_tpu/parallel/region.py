@@ -109,6 +109,20 @@ def region_axes_spec(c: Comm):
     return P(c.axes if len(c.axes) > 1 else c.axes[0])
 
 
+def shard_global(tree, comm: Optional[Comm] = None):
+    """Commit a pytree of global ``(size, *local_shape)`` arrays (host or
+    device) to ``comm``'s mesh in the layout ``spmd``/``mpx.compile``
+    programs take by default: ``global[r]`` on rank ``r``'s device.
+
+    Arrays made with ``jnp.asarray`` live whole on the first device and
+    are re-sharded from there by every call they are passed to; placing
+    long-lived state (model state, weights, KV pools) once avoids that
+    per-call copy off one chip."""
+    c = resolve_comm(comm)
+    sharding = jax.sharding.NamedSharding(c.mesh, region_axes_spec(c))
+    return jax.device_put(tree, sharding)
+
+
 def make_region_body(f, c: Comm, statics, static_vals, kw_names, n_dyn,
                      squeeze_in: bool, squeeze_out: bool, unroll: int = 1):
     """Build the per-rank region body ``spmd`` traces: argument

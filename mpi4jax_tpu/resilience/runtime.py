@@ -194,9 +194,9 @@ class Plan:
 
         if self.timeout is not None:
             armed = wd.arm_in_graph(mpi_name, call_id, comm, rank, self.timeout)
-            arrays = tuple(native._tie(a, armed) for a in arrays)
+            arrays = tuple(wd.after_arm(a, armed) for a in arrays)
             if token is not None:
-                token = Token(native._tie(token.value, armed))
+                token = Token(wd.after_arm(token.value, armed))
 
         return arrays, token
 

@@ -22,10 +22,16 @@ Two implementations, chosen per availability:
   registry, watched by a daemon thread.  Collectives block with the GIL
   released, so the thread fires reliably in practice; works on any backend.
 
-Arm is ordered *before* the collective by tying the op's inputs to the arm
-token; disarm is tied *after* the first output — exactly the bracket the
-runtime trace hooks use, so the elapsed time the diagnostics report is the
-collective's true in-flight time on this host.
+Arm, collective and disarm are ordered by real data dependence: the op's
+inputs are computed from the arm's output (``after_arm``) and the disarm
+takes an element of the op's first output as an operand, so a disarm can
+run neither before its collective nor before its arm.  (An
+``optimization_barrier`` tie, which the trace hooks use, is not enough
+here: XLA:CPU expands barriers away before it schedules, and inside a
+``fori_loop`` body it then ran every disarm ahead of its arm — an arm left
+in flight kills a healthy process ``timeout`` seconds later.)  The elapsed
+time the diagnostics report is the collective's in-flight time on this
+host.
 """
 
 from __future__ import annotations
@@ -37,6 +43,7 @@ from typing import Callable, Optional
 
 __all__ = [
     "arm_in_graph",
+    "after_arm",
     "disarm_in_graph",
     "inflight_snapshot",
     "registry_empty",
@@ -116,6 +123,8 @@ class _Registry:
     id, and the data dependencies order iteration N+1's arm after iteration
     N's collective but not after N's disarm (the same aliasing the native
     trace hooks handle, csrc/host_hooks.cc ``begin_times``).
+    A disarm that finds no entry (its arm was drained by an epoch
+    revocation or a claimed expiry) is dropped.
     """
 
     def __init__(self, on_timeout: Optional[Callable] = None,
@@ -311,19 +320,24 @@ def drain_registry() -> int:
 # ---------------------------------------------------------------------------
 
 
-def _io_callback(fn, rank):
+def _io_callback(fn, *operands):
     import jax
     import jax.numpy as jnp
     from jax.experimental import io_callback
 
     return io_callback(
-        fn, jax.ShapeDtypeStruct((), jnp.uint32), rank, ordered=False
+        fn, jax.ShapeDtypeStruct((), jnp.uint32), *operands, ordered=False
     )
 
 
+# the arm returns its rank, which is never this value
+_NO_RANK = 0xFFFFFFFF
+
+
 def arm_in_graph(mpi_name: str, call_id: str, comm, rank, timeout: float):
-    """Arm the watchdog for one collective; returns a u32 the op's inputs
-    must be tied to (so arming precedes the collective's execution)."""
+    """Arm the watchdog for one collective; returns a u32 to pass, with
+    each of the op's inputs, through ``after_arm`` (so arming precedes
+    the collective's execution)."""
     from .. import native
 
     # metered HERE — the shared entry of both implementations — so the
@@ -351,20 +365,41 @@ def arm_in_graph(mpi_name: str, call_id: str, comm, rank, timeout: float):
     return _io_callback(_arm, jnp.asarray(rank, jnp.uint32))
 
 
+def after_arm(x, armed):
+    """``x``, bit for bit, as a function of the arm's output ``armed``: an
+    elementwise select on a condition the compiler cannot decide (the
+    arm's opaque result against a value it never returns), so whatever
+    consumes ``x`` cannot be scheduled before the arm has run."""
+    import jax.numpy as jnp
+
+    x = jnp.asarray(x)
+    return jnp.where(armed == jnp.uint32(_NO_RANK), jnp.zeros_like(x), x)
+
+
+def _anchor(dep):
+    """One element of ``dep`` (none when it is empty): a real operand that
+    orders a callback after ``dep`` was computed, small enough to ship to
+    the host."""
+    import jax.numpy as jnp
+
+    return jnp.ravel(dep)[:1]
+
+
 def disarm_in_graph(mpi_name: str, call_id: str, comm, rank, dep):
-    """Disarm after the collective: ``dep`` (the op's first output) orders
-    the callback after completion."""
+    """Disarm after the collective: an element of ``dep`` (the op's first
+    output) is an operand of the callback, which orders it after
+    completion."""
     from .. import native
 
     if native.watchdog_supported() and not _force_fallback:
-        return native.watchdog_disarm(call_id, rank, dep)
+        return native.watchdog_disarm(call_id, rank, _anchor(dep))
 
     import numpy as np
 
-    def _disarm(r):
+    def _disarm(r, _dep):
         _registry.disarm(call_id, int(r))
         return np.uint32(r)
 
     import jax.numpy as jnp
 
-    return _io_callback(_disarm, native._tie(jnp.asarray(rank, jnp.uint32), dep))
+    return _io_callback(_disarm, jnp.asarray(rank, jnp.uint32), _anchor(dep))

@@ -322,17 +322,19 @@ class ServingEngine:
                             params + (kv, kv.copy(), tok))
 
     def _prep(self, arr):
-        """Host array -> program input.  Single-controller: a committed
-        device array (the pinned AOT path).  Multi-process: the plain
-        numpy array — every process passes the identical global value
-        and jit commits it against the mesh (the elastic-drill
-        convention)."""
+        """Host array -> program input.  Single-controller: committed
+        to the serving mesh in the programs' own layout (row ``r`` on
+        rank ``r``'s device), so the parameters and the KV pool are
+        placed once at build time instead of being re-sharded off the
+        first device by every dispatch.  Multi-process: the plain numpy
+        array — every process passes the identical global value and jit
+        commits it against the mesh (the elastic-drill convention)."""
         import jax
 
         if jax.process_count() == 1:
-            import jax.numpy as jnp
+            from ..parallel.region import shard_global
 
-            return jnp.asarray(arr)
+            return shard_global(arr, self.comm)
         return arr
 
     def _lane(self, values, fill) -> "object":
@@ -380,6 +382,18 @@ class ServingEngine:
         self._programs[key] = prog
         self._meter(f"serving.programs.{phase}")
         return prog
+
+    def warm(self) -> float:
+        """Build every admission/decode program of the bucket table now
+        (a pin compiles at build time), so no request pays a compile
+        inside the serving loop; returns the seconds it took.  The
+        elastic ``replay`` programs stay on demand: only a drain boundary
+        needs them."""
+        t0 = time.perf_counter()
+        for bucket in self.table.buckets:
+            for phase in PHASES:
+                self._program(phase, bucket)
+        return time.perf_counter() - t0
 
     # -- telemetry ---------------------------------------------------------
 

@@ -100,20 +100,10 @@ def decode_step(emb, wqkv, wo, w1, w2, kk, vv, tok_table, last_tok, lens,
                     lens + jnp.int32(1), slots))
 
 
-def prefill_step(emb, wqkv, wo, w1, w2, kk, vv, tok_table, prompts, plens,
-                 slots):
-    """Prompt processing for a bucketed batch (per-rank body).
-
-    Causal self-attention over the padded prompt buffer ``[B, L]``,
-    K/V written for every position (garbage beyond ``plen`` is masked
-    by ``lens`` downstream and overwritten as the sequence grows), and
-    the FIRST generated token sampled from the last live position and
-    recorded at column ``plen``.  Returns ``(kk, vv, tok_table,
-    first_token)``.
-    """
+def _prefill_forward(emb, wqkv, wo, w1, w2, kk, vv, prompts, plens, slots):
+    """The prefill body up to the logits: ``(kk, vv, logits [B, V])``."""
     import jax.numpy as jnp
 
-    from ..ops import varying
     from .kvcache import scatter_prefill
 
     n_local_heads, head_dim = kk.shape[2], kk.shape[3]
@@ -138,10 +128,40 @@ def prefill_step(emb, wqkv, wo, w1, w2, kk, vv, tok_table, prompts, plens,
     x = x + mlp(x)
 
     x_last = x[jnp.arange(batch), plens - 1]       # [B, D]
-    logits = x_last @ emb.T
+    return kk, vv, x_last @ emb.T
+
+
+def prefill_step(emb, wqkv, wo, w1, w2, kk, vv, tok_table, prompts, plens,
+                 slots):
+    """Prompt processing for a bucketed batch (per-rank body).
+
+    Causal self-attention over the padded prompt buffer ``[B, L]``,
+    K/V written for every position (garbage beyond ``plen`` is masked
+    by ``lens`` downstream and overwritten as the sequence grows), and
+    the FIRST generated token sampled from the last live position and
+    recorded at column ``plen``.  Returns ``(kk, vv, tok_table,
+    first_token)``.
+    """
+    import jax.numpy as jnp
+
+    from ..ops import varying
+
+    kk, vv, logits = _prefill_forward(emb, wqkv, wo, w1, w2, kk, vv,
+                                      prompts, plens, slots)
     first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     tok_table = tok_table.at[slots, plens].set(first)
     return varying((kk, vv, tok_table, first))
+
+
+def prefill_logits(emb, wqkv, wo, w1, w2, kk, vv, tok_table, prompts, plens,
+                   slots):
+    """``prefill_step``'s arguments -> the last-position logits ``[B, V]``
+    it samples from (per-rank body; replicated content): what
+    ``chip_smoke.py`` checks against an unsharded forward."""
+    from ..ops import varying
+
+    return varying(_prefill_forward(emb, wqkv, wo, w1, w2, kk, vv,
+                                    prompts, plens, slots)[2])
 
 
 # ---------------------------------------------------------------------------

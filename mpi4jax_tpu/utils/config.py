@@ -488,16 +488,6 @@ FLAGS = {
              "against (tokens/s/chip AT this p99 bound — "
              "BENCH_serving.json), and the bound the CI serving lane "
              "asserts.  Default 1000."),
-        Flag("MPI4JAX_TPU_CPP_DISPATCH", "bool", True,
-             "Drive pinned executables (``mpx.compile`` -> "
-             "``PinnedProgram``) through jax's C++ fast-path dispatch "
-             "(``MeshExecutable.create_cpp_call``) where the installed "
-             "jaxlib supports it, so a pinned call costs one "
-             "world-stamp check plus one C++ call "
-             "(mpi4jax_tpu/aot/fastpath.py).  ``false`` forces the "
-             "plain Python ``Compiled`` call path (debugging, or a "
-             "jaxlib whose fast path misbehaves).  Never shapes a "
-             "trace: flipping it does not stale live pins."),
         Flag("MPI4JAX_TPU_HEALTH", "choice", "off",
              "Runtime health plane (mpi4jax_tpu/telemetry/health.py, "
              "docs/observability.md 'Runtime health'): ``on`` arms the "
@@ -542,9 +532,8 @@ FLAGS = {
 #
 # Every compiled-program cache key folds in ~10 dynamically-read flags so
 # that toggling one retraces.  Re-parsing them on EVERY dispatch made the
-# cache-hit path pay float/choice/fault-spec parsing per call
-# (BENCH_r05.json: dispatch_overhead_s ~14% of wall).  Instead, the parsed
-# token is memoized against a cheap *stamp*:
+# cache-hit path pay float/choice/fault-spec parsing per call.  Instead,
+# the parsed token is memoized against a cheap *stamp*:
 #
 # - ``env_fingerprint()`` — the raw (unparsed) values of every declared
 #   flag, one dict read each: catches environment mutation;
@@ -1256,13 +1245,6 @@ def unroll_default() -> int:
     docs/aot.md 'Megastep execution')."""
     return _parse_env_positive_int("MPI4JAX_TPU_UNROLL_DEFAULT", 1,
                                    minimum=1)
-
-
-def cpp_dispatch() -> bool:
-    """Whether pinned executables use jax's C++ fast-path dispatch where
-    available (``MPI4JAX_TPU_CPP_DISPATCH``; default on — see
-    mpi4jax_tpu/aot/fastpath.py)."""
-    return parse_env_bool("MPI4JAX_TPU_CPP_DISPATCH", True)
 
 
 def serving_max_batch() -> int:

@@ -1,12 +1,10 @@
-"""JAX version advisory.
+"""JAX version check.
 
 Analog of ref mpi4jax/_src/jax_compat.py:11-47: the reference pins a
 latest-validated JAX version (shipped as ``_latest_jax_version.txt``) and
-warns when the installed JAX is newer (its custom-call lowerings reach into
-JAX internals that move between releases).  This framework touches far fewer
-internals (public ``jax.lax`` collectives + ``shard_map``), so the advisory
-is informational: warn above the validated ceiling, error below the hard
-floor (``shard_map``/VMA typing requirements).
+warns when the installed JAX is newer.  This package states ONE supported
+release, ``SUPPORTED_JAX_VERSION`` — the one it is built, tested and run
+on the chip against: an older JAX is an error, a newer one a warning.
 
 ``MPI4JAX_TPU_NO_WARN_JAX_VERSION=1`` silences the warning
 (ref jax_compat.py:35-36 ``MPI4JAX_NO_WARN_JAX_VERSION``).
@@ -80,10 +78,8 @@ def tracer_is_live(tracer) -> bool:
     return False
 
 
-# oldest JAX with the shard_map/VMA semantics the ops rely on
-MIN_JAX_VERSION = "0.6.0"
-# newest JAX this package was validated against
-LATEST_JAX_VERSION = "0.9.0"
+# the JAX release this package is written for and validated against
+SUPPORTED_JAX_VERSION = "0.9.0"
 
 
 def versiontuple(v: str):
@@ -108,21 +104,21 @@ def check_jax_version(jax_version: str = None) -> None:
 
         jax_version = jax.__version__
 
-    if versiontuple(jax_version) < versiontuple(MIN_JAX_VERSION):
+    if versiontuple(jax_version) < versiontuple(SUPPORTED_JAX_VERSION):
         raise RuntimeError(
-            f"mpi4jax_tpu requires jax>={MIN_JAX_VERSION} (found "
-            f"{jax_version}): the collective ops rely on jax.shard_map and "
-            "collective (VMA) typing introduced there."
+            f"mpi4jax_tpu requires jax>={SUPPORTED_JAX_VERSION} (found "
+            f"{jax_version}): the package is written against that "
+            "release's shard_map, VMA typing, AOT and Pallas surfaces."
         )
 
-    if versiontuple(jax_version) > versiontuple(LATEST_JAX_VERSION):
+    if versiontuple(jax_version) > versiontuple(SUPPORTED_JAX_VERSION):
         if parse_env_bool("MPI4JAX_TPU_NO_WARN_JAX_VERSION", False):
             return
         warnings.warn(
             f"The latest supported JAX version with this release of "
-            f"mpi4jax_tpu is {LATEST_JAX_VERSION} (found {jax_version}). "
+            f"mpi4jax_tpu is {SUPPORTED_JAX_VERSION} (found {jax_version}). "
             "If you encounter problems, consider pinning "
-            f"jax=={LATEST_JAX_VERSION}. Set "
+            f"jax=={SUPPORTED_JAX_VERSION}. Set "
             "MPI4JAX_TPU_NO_WARN_JAX_VERSION=1 to silence this warning.",
             UserWarning,
             stacklevel=3,

@@ -2,7 +2,7 @@
 
 Some tests spawn a fresh interpreter that ``import mpi4jax_tpu``s; in a
 sandbox whose installed JAX is below the package's hard floor
-(utils/jax_compat.MIN_JAX_VERSION) that import refuses by design, so the
+(utils/jax_compat.SUPPORTED_JAX_VERSION) that import refuses by design, so the
 subprocess can only ever report the version error.  Those tests carry
 ``pytest.mark.skipif(not jax_meets_package_floor(), ...)`` — the skip
 reason documents that this is a container-environment limitation, not a
@@ -34,8 +34,8 @@ def _versiontuple(v: str):
 
 def package_jax_floor() -> str:
     src = (REPO / "mpi4jax_tpu" / "utils" / "jax_compat.py").read_text()
-    m = re.search(r'MIN_JAX_VERSION\s*=\s*"([^"]+)"', src)
-    assert m, "MIN_JAX_VERSION not found in utils/jax_compat.py"
+    m = re.search(r'SUPPORTED_JAX_VERSION\s*=\s*"([^"]+)"', src)
+    assert m, "SUPPORTED_JAX_VERSION not found in utils/jax_compat.py"
     return m.group(1)
 
 

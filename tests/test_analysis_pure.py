@@ -13,7 +13,6 @@ lives in tests/test_analysis.py.
 """
 
 import importlib
-import os
 import pathlib
 import sys
 import types
@@ -666,29 +665,27 @@ def _clean_analyze_env(monkeypatch):
     hook.set_analyze_mode(None)
 
 
-def test_analyze_mode_parsing():
+def test_analyze_mode_parsing(monkeypatch):
     assert config.analyze_mode() == "off"
-    os.environ["MPI4JAX_TPU_ANALYZE"] = "WARN"  # case-insensitive
+    monkeypatch.setenv("MPI4JAX_TPU_ANALYZE", "WARN")  # case-insensitive
     assert config.analyze_mode() == "warn"
-    os.environ["MPI4JAX_TPU_ANALYZE"] = "loud"
+    monkeypatch.setenv("MPI4JAX_TPU_ANALYZE", "loud")
     with pytest.raises(ValueError, match="MPI4JAX_TPU_ANALYZE"):
         config.analyze_mode()
 
 
-def test_mode_override_and_cache_token():
+def test_mode_override_and_cache_token(monkeypatch):
     assert hook.effective_mode() == "off"
     assert hook.analysis_cache_token() == ("off", "auto")
     hook.set_analyze_mode("error")
     assert hook.effective_mode() == "error"
     assert hook.analysis_cache_token() == ("error", "auto")
     # the cross-rank setting is part of the token: flipping it retraces
-    os.environ["MPI4JAX_TPU_ANALYZE_RANKS"] = "off"
-    try:
-        assert hook.analysis_cache_token() == ("error", "off")
-    finally:
-        del os.environ["MPI4JAX_TPU_ANALYZE_RANKS"]
+    monkeypatch.setenv("MPI4JAX_TPU_ANALYZE_RANKS", "off")
+    assert hook.analysis_cache_token() == ("error", "off")
+    monkeypatch.delenv("MPI4JAX_TPU_ANALYZE_RANKS")
     hook.set_analyze_mode(None)
-    os.environ["MPI4JAX_TPU_ANALYZE"] = "warn"
+    monkeypatch.setenv("MPI4JAX_TPU_ANALYZE", "warn")
     assert hook.effective_mode() == "warn"
     with pytest.raises(ValueError, match="analyze mode"):
         hook.set_analyze_mode("loud")

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 import mpi4jax_tpu as mpx
-from mpi4jax_tpu.aot import serialization
 from mpi4jax_tpu.ops._base import dynamic_cache_token
 from mpi4jax_tpu.resilience import elastic as el
 
@@ -198,13 +197,6 @@ def test_hlo_and_cache_keys_unchanged_by_aot(monkeypatch, tmp_path):
 # the persistent tier
 # ---------------------------------------------------------------------------
 
-needs_serialization = pytest.mark.skipif(
-    not serialization.supported(),
-    reason="this jax cannot serialize compiled executables",
-)
-
-
-@needs_serialization
 def test_repin_served_from_disk(monkeypatch, tmp_path):
     monkeypatch.setenv("MPI4JAX_TPU_COMPILE_CACHE_DIR", str(tmp_path))
     comm = _world_comm()
@@ -229,7 +221,6 @@ def test_repin_served_from_disk(monkeypatch, tmp_path):
     assert stats["aot"]["disk_loads"] == 1
 
 
-@needs_serialization
 def test_spmd_program_cache_consults_disk_on_miss(monkeypatch, tmp_path):
     monkeypatch.setenv("MPI4JAX_TPU_COMPILE_CACHE_DIR", str(tmp_path))
     comm = _world_comm()
@@ -249,7 +240,6 @@ def test_spmd_program_cache_consults_disk_on_miss(monkeypatch, tmp_path):
     assert stats["misses"] == 0
 
 
-@needs_serialization
 @pytest.mark.slow
 def test_cold_start_second_process_served_from_disk(tmp_path):
     """The multi-host cold-start contract in miniature: a SECOND process

@@ -57,6 +57,31 @@ def test_comm_2d_mesh_sub():
     assert np.allclose(cs, [12, 16, 12, 16, 12, 16, 12, 16])  # sums over y
 
 
+def test_shard_global_places_one_row_per_rank():
+    """Host arrays go straight to the layout region programs take —
+    ``global[r]`` on rank r's device, also on a 2-axis comm — and a
+    program fed the placed array returns what the unplaced one does."""
+    mesh = mpx.make_world_mesh((4, 2), ("y", "x"))
+    comm = mpx.Comm(("y", "x"), mesh=mesh)
+    host = {"a": np.arange(16.0, dtype=np.float32).reshape(8, 2),
+            "b": np.ones((8, 3, 2), np.float32)}
+    placed = mpx.shard_global(host, comm)
+    devices = list(mesh.devices.flat)  # row-major = rank order
+    for name, arr in placed.items():
+        assert len(arr.sharding.device_set) == 8
+        assert np.array_equal(np.asarray(arr), host[name])
+        for shard in arr.addressable_shards:
+            r = devices.index(shard.device)
+            assert np.array_equal(np.asarray(shard.data), host[name][r:r + 1])
+
+    @mpx.spmd(comm=comm)
+    def f(xl):
+        return mpx.allreduce(xl, op=mpx.SUM)[0]
+
+    assert np.array_equal(np.asarray(f(placed["a"])),
+                          np.asarray(f(jnp.asarray(host["a"]))))
+
+
 def test_comm_multi_axis_allreduce():
     mesh = mpx.make_world_mesh((4, 2), ("y", "x"))
     comm = mpx.Comm(("y", "x"), mesh=mesh)

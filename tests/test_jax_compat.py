@@ -6,8 +6,7 @@ import warnings
 import pytest
 
 from mpi4jax_tpu.utils.jax_compat import (
-    LATEST_JAX_VERSION,
-    MIN_JAX_VERSION,
+    SUPPORTED_JAX_VERSION,
     check_jax_version,
     versiontuple,
 )
@@ -26,13 +25,10 @@ def test_versiontuple(raw, expected):
     assert versiontuple(raw) == expected
 
 
-def test_in_range_version_passes_silently():
-    # explicit in-range version: keeps CI green when a newer jax ships
-    # (the advisory for the *installed* jax is informational, not an error)
+def test_supported_version_passes_silently():
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        check_jax_version(LATEST_JAX_VERSION)
-        check_jax_version(MIN_JAX_VERSION)
+        check_jax_version(SUPPORTED_JAX_VERSION)
 
 
 def test_newer_jax_warns():
@@ -51,6 +47,5 @@ def test_too_old_jax_raises():
     with pytest.raises(RuntimeError, match="requires jax>="):
         check_jax_version("0.4.24")
 
-
-def test_bounds_are_ordered():
-    assert versiontuple(MIN_JAX_VERSION) <= versiontuple(LATEST_JAX_VERSION)
+    with pytest.raises(RuntimeError, match="requires jax>="):
+        check_jax_version("0.8.2")

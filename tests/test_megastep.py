@@ -12,20 +12,17 @@ CPU mesh.
   ``mpx.analyze`` and the ambient error mode;
 - the elastic 8 -> 7 shrink drill with a megastep step function:
   commit/retry at megastep granularity, resuming from the last commit;
-- the C++ fast-path dispatch: graceful fallback when jaxlib support is
-  missing (or ``MPI4JAX_TPU_CPP_DISPATCH=false``), no staleness on the
-  dispatch-only flag;
 - the whole-megastep watchdog bracket (deadline scaled by N) and the
   events-tier megastep bracket + synthesized per-step estimate;
 - the cache-warming CLI end to end against a manifest.
 
-The pure half (MPX130 checker matrix, fastpath fakes, manifest parsing,
-alignment helpers) runs under any JAX in tests/test_megastep_pure.py
-via the isolated loader.
+The pure half (MPX130 checker matrix, manifest parsing, alignment
+helpers) is tests/test_megastep_pure.py, via the isolated loader.
 """
 
 import json
 import textwrap
+import time
 
 import numpy as np
 import pytest
@@ -317,59 +314,12 @@ def test_mpx130_env_error_fires_at_trace():
 
 
 # ---------------------------------------------------------------------------
-# the C++ fast-path dispatch
-# ---------------------------------------------------------------------------
-
-
-def test_fast_path_fallback_on_missing_jaxlib_support(monkeypatch):
-    from mpi4jax_tpu.aot import fastpath
-
-    # simulate a jaxlib without create_cpp_call: every pin must fall
-    # back to the Python Compiled call and still execute correctly
-    monkeypatch.setattr(fastpath, "cpp_call_for", lambda c: (c, False))
-    comm = _world_comm()
-    k = comm.Get_size()
-    x = jnp.ones((k, 4), jnp.float32)
-    pinned = mpx.compile(_step_plain, x, comm=comm)
-    assert pinned.fast_path is False
-    out = np.asarray(pinned(x))
-    np.testing.assert_allclose(out, np.full((k, 4), k * 0.25 + 0.5),
-                               rtol=1e-6)
-    assert mpx.cache_stats()["aot"]["fast_path_pins"] == 0
-
-
-def test_fast_path_flag_off_and_no_staleness(monkeypatch):
-    comm = _world_comm()
-    k = comm.Get_size()
-    x = jnp.ones((k, 4), jnp.float32)
-    pinned = mpx.compile(_step_plain, x, comm=comm)
-    want = np.asarray(pinned(x))
-    # flipping the dispatch-only flag must NOT stale the live pin
-    monkeypatch.setenv("MPI4JAX_TPU_CPP_DISPATCH", "false")
-    assert not pinned.is_stale()
-    np.testing.assert_array_equal(want, np.asarray(pinned(x)))
-    # and new pins under the off flag take the Python path
-    fresh = mpx.compile(_step_plain, x, comm=comm)
-    assert fresh.fast_path is False
-    np.testing.assert_array_equal(want, np.asarray(fresh(x)))
-
-
-def test_fast_path_result_matches_python_path(monkeypatch):
-    comm = _world_comm()
-    k = comm.Get_size()
-    x = jnp.arange(k * 4, dtype=jnp.float32).reshape(k, 4)
-    fast = mpx.compile(_step_plain, x, comm=comm)
-    monkeypatch.setenv("MPI4JAX_TPU_CPP_DISPATCH", "false")
-    slow = mpx.compile(_step_plain, x, comm=comm)
-    np.testing.assert_array_equal(np.asarray(fast(x)), np.asarray(slow(x)))
-
-
-# ---------------------------------------------------------------------------
 # watchdog: whole-megastep bracket, deadline scaled by N
 # ---------------------------------------------------------------------------
 
 
 def test_watchdog_brackets_megastep_with_scaled_deadline(monkeypatch):
+    from mpi4jax_tpu import native
     from mpi4jax_tpu.resilience import watchdog
 
     armed = []
@@ -380,6 +330,8 @@ def test_watchdog_brackets_megastep_with_scaled_deadline(monkeypatch):
         return real_arm(mpi_name, call_id, comm, rank, timeout)
 
     monkeypatch.setattr(watchdog, "arm_in_graph", spy)
+    # the Python registry, so the test can see that nothing stays armed
+    monkeypatch.setattr(native, "watchdog_supported", lambda: False)
     mpx.set_watchdog_timeout(5.0)
     try:
         comm = _world_comm()
@@ -389,6 +341,11 @@ def test_watchdog_brackets_megastep_with_scaled_deadline(monkeypatch):
         jax.block_until_ready(pinned(x))
     finally:
         resilience_runtime.reset_overrides()
+    # an in-loop arm left in flight would abort the whole process 5 s on
+    deadline = time.monotonic() + 5.0
+    while not watchdog.registry_empty() and time.monotonic() < deadline:
+        time.sleep(0.05)  # callbacks may trail block_until_ready
+    assert watchdog.registry_empty(), watchdog.inflight_snapshot()
     mega = [(n, t) for n, t in armed if n.startswith("MPI_Megastep")]
     assert len(mega) == 1, armed
     assert mega[0][1] == pytest.approx(5.0 * UNROLL)
@@ -549,11 +506,7 @@ def test_elastic_run_budget_must_align():
 
 
 def test_warm_cli_populates_cache(monkeypatch, tmp_path):
-    from mpi4jax_tpu.aot import serialization
     from mpi4jax_tpu.aot.warm import EXIT_OK, warm_from_manifest
-
-    if not serialization.supported():
-        pytest.skip("this jax cannot serialize compiled executables")
 
     target = tmp_path / "warmtarget.py"
     target.write_text(textwrap.dedent("""

@@ -7,15 +7,12 @@ core is verified under any JAX version:
 
 - the MPX130 span-straddle checker on hand-built graphs, the MPX128
   loop-body exemption, and both catalog rows;
-- the C++ fast-path installer (aot/fastpath.py) against fake Compiled
-  objects: probe order, tree fallback, factory failure -> graceful
-  Python-path fallback;
 - the cache-warming manifest parser (aot/warm.py): schema validation,
   static/template splitting, exit-code mapping, the disabled-tier
   refusal;
 - megastep granularity plumbing: ``validate_unroll``,
-  ``elastic.align_commit_every``, the ``elastic.run`` budget/stride
-  validation, and the world-stamp exemption of the dispatch-only flag;
+  ``elastic.align_commit_every``, and the ``elastic.run``
+  budget/stride validation;
 - the journal's synthesized per-step latency estimate (megastep bracket
   latency / unroll -> the ``megastep_step`` histogram).
 
@@ -61,7 +58,6 @@ def _load_isolated():
         "telemetry.journal",
         "resilience.elastic",
         "aot.invalidation",
-        "aot.fastpath",
         "aot.warm",
         "parallel.megastep",
     ):
@@ -78,7 +74,6 @@ tcore = ISO.telemetry.core
 journal = ISO.telemetry.journal
 elastic = ISO.resilience.elastic
 inv = ISO.aot.invalidation
-fastpath = ISO.aot.fastpath
 warm = ISO.aot.warm
 megastep = ISO.parallel.megastep
 
@@ -189,97 +184,6 @@ def test_mpx128_advisory_recommends_unroll():
     (finding,) = checkers.check_unpinned_hot_loop(graph)
     assert "unroll=" in finding.suggestion
     assert "megastep" in finding.suggestion
-
-
-# ---------------------------------------------------------------------------
-# the C++ fast-path installer (aot/fastpath.py)
-# ---------------------------------------------------------------------------
-
-
-class _FakeExe:
-    def __init__(self, result="fastcall", raises=False):
-        self.result = result
-        self.raises = raises
-        self.calls = []
-
-    def create_cpp_call(self, no_kwargs, in_tree, out_tree):
-        self.calls.append((no_kwargs, in_tree, out_tree))
-        if self.raises:
-            raise RuntimeError("jaxlib said no")
-        if self.result == "fastcall":
-            return lambda *a: ("fast", a)
-        return self.result
-
-
-class _FakeCompiled:
-    def __init__(self, exe, in_tree="IT", out_tree="OT"):
-        self._executable = exe
-        if in_tree is not None:
-            self.in_tree = in_tree
-        if out_tree is not None:
-            self.out_tree = out_tree
-
-    def __call__(self, *a):
-        return ("python", a)
-
-
-def test_fastpath_installs_cpp_call():
-    exe = _FakeExe()
-    compiled = _FakeCompiled(exe)
-    call, used = fastpath.cpp_call_for(compiled)
-    assert used and call is not compiled
-    assert call(1, 2) == ("fast", (1, 2))
-    # the factory was asked for the positional-only (no_kwargs) form
-    assert exe.calls == [(True, "IT", "OT")]
-    assert fastpath.supported(compiled)
-
-
-def test_fastpath_missing_executable_falls_back():
-    class Bare:
-        pass
-
-    bare = Bare()
-    call, used = fastpath.cpp_call_for(bare)
-    assert call is bare and not used
-    assert not fastpath.supported(bare)
-
-
-def test_fastpath_missing_factory_falls_back():
-    class Exe:
-        pass
-
-    compiled = _FakeCompiled(Exe())
-    call, used = fastpath.cpp_call_for(compiled)
-    assert call is compiled and not used
-
-
-def test_fastpath_factory_raise_falls_back():
-    compiled = _FakeCompiled(_FakeExe(raises=True))
-    call, used = fastpath.cpp_call_for(compiled)
-    assert call is compiled and not used
-    assert call(3) == ("python", (3,))
-
-
-def test_fastpath_non_callable_result_falls_back():
-    compiled = _FakeCompiled(_FakeExe(result=None))
-    call, used = fastpath.cpp_call_for(compiled)
-    assert call is compiled and not used
-
-
-def test_fastpath_missing_trees_falls_back_then_params():
-    compiled = _FakeCompiled(_FakeExe(), in_tree=None, out_tree=None)
-    call, used = fastpath.cpp_call_for(compiled)
-    assert call is compiled and not used
-
-    class Params:
-        in_tree = "PIT"
-        out_tree = "POT"
-
-    exe = _FakeExe()
-    older = _FakeCompiled(exe, in_tree=None, out_tree=None)
-    older._params = Params()
-    call, used = fastpath.cpp_call_for(older)
-    assert used and exe.calls == [(True, "PIT", "POT")]
 
 
 # ---------------------------------------------------------------------------
@@ -398,15 +302,6 @@ def test_elastic_run_rejects_misaligned_budget():
         elastic.run(MegaStep(), None, None, steps=10)
 
 
-def test_dispatch_only_flag_never_stales_pins(monkeypatch):
-    ws = inv.WorldStamp.capture()
-    monkeypatch.setenv("MPI4JAX_TPU_CPP_DISPATCH", "false")
-    assert ws.is_current()
-    ws.check()  # no raise
-    for name in inv.DISPATCH_ONLY_FLAGS:
-        assert name in config.FLAGS  # exemption list stays declared
-
-
 def test_unroll_default_flag_stales_pins(monkeypatch):
     ws = inv.WorldStamp.capture()
     monkeypatch.setenv("MPI4JAX_TPU_UNROLL_DEFAULT", "8")
@@ -416,17 +311,12 @@ def test_unroll_default_flag_stales_pins(monkeypatch):
 
 def test_new_flags_declared_and_parsed(monkeypatch):
     assert "MPI4JAX_TPU_UNROLL_DEFAULT" in config.FLAGS
-    assert "MPI4JAX_TPU_CPP_DISPATCH" in config.FLAGS
     assert config.unroll_default() == 1
     monkeypatch.setenv("MPI4JAX_TPU_UNROLL_DEFAULT", "16")
     assert config.unroll_default() == 16
     monkeypatch.setenv("MPI4JAX_TPU_UNROLL_DEFAULT", "0")
     with pytest.raises(ValueError):
         config.unroll_default()
-    monkeypatch.delenv("MPI4JAX_TPU_UNROLL_DEFAULT")
-    assert config.cpp_dispatch() is True
-    monkeypatch.setenv("MPI4JAX_TPU_CPP_DISPATCH", "false")
-    assert config.cpp_dispatch() is False
 
 
 # ---------------------------------------------------------------------------

@@ -243,7 +243,6 @@ def test_bench_dispatch_unroll_schema():
     for r in du["rows"]:
         assert r["megastep_us"] > 0 and r["per_step_us"] > 0
         assert r["per_step_host_us"] >= 0
-        assert isinstance(r["fast_path"], bool)
     # amortization direction: per-step host cost must not grow with N
     assert (du["rows"][1]["per_step_host_us"]
             <= du["rows"][0]["per_step_host_us"] + 1e-9)

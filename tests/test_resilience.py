@@ -352,6 +352,18 @@ def test_watchdog_registry_fifo_and_snapshot():
     assert reg.empty()
 
 
+def test_watchdog_disarm_without_entry_does_not_cancel_the_next_arm():
+    """A disarm that finds nothing (its arm was drained with a revoked
+    world, or by a claimed expiry) is dropped: the next collective under
+    that key must still be watched."""
+    reg = wd._Registry(on_timeout=lambda entries, expired: None)
+    reg.arm("MPI_Allreduce", "aabbccdd", 0, "('i',)", timeout=60.0)
+    assert reg.drain() == 1
+    reg.disarm("aabbccdd", 0)  # the late disarm of the drained arm
+    reg.arm("MPI_Allreduce", "aabbccdd", 0, "('i',)", timeout=60.0)
+    assert len(reg.snapshot()) == 1
+
+
 def test_watchdog_expiry_with_injected_clock():
     now = [100.0]
     reg = wd._Registry(on_timeout=lambda entries, expired: None,
@@ -850,7 +862,7 @@ def test_watchdog_brackets_collective_cleanly():
     import numpy as np
 
     import mpi4jax_tpu as mpx
-    from mpi4jax_tpu import resilience
+    from mpi4jax_tpu import native, resilience
     from mpi4jax_tpu.resilience import watchdog as real_wd
 
     @mpx.spmd
@@ -866,7 +878,7 @@ def test_watchdog_brackets_collective_cleanly():
     resilience.set_watchdog_timeout(60)
     try:
         with unittest.mock.patch.object(
-            mpx.native, "watchdog_supported", lambda: False
+            native, "watchdog_supported", lambda: False
         ):
             out = np.asarray(f(jnp.arange(8.0)[:, None]))
     finally:
@@ -876,6 +888,65 @@ def test_watchdog_brackets_collective_cleanly():
     while not real_wd.registry_empty() and time.monotonic() < deadline:
         time.sleep(0.05)  # disarm callbacks may trail block_until_ready
     assert real_wd.registry_empty(), real_wd.inflight_snapshot()
+
+
+@needs_mpx
+def test_watchdog_disarm_never_runs_before_its_arm(monkeypatch):
+    """Arm, collective and disarm are ordered by real data dependence.
+    XLA:CPU expands ``optimization_barrier`` ties away before it schedules;
+    with only those, every disarm inside a ``fori_loop`` body ran ahead of
+    its arm, and the arm left in flight killed the process at its timeout."""
+    import jax
+    import jax.numpy as jnp
+
+    import mpi4jax_tpu as mpx
+    from mpi4jax_tpu import native, resilience
+    from mpi4jax_tpu.resilience import watchdog as real_wd
+
+    events = []
+    reg = real_wd._registry
+    real_arm, real_disarm = reg.arm, reg.disarm
+
+    def arm(opname, call_id, rank, axes, timeout):
+        events.append((1, call_id, rank))
+        real_arm(opname, call_id, rank, axes, timeout)
+
+    def disarm(call_id, rank):
+        events.append((-1, call_id, rank))
+        real_disarm(call_id, rank)
+
+    monkeypatch.setattr(reg, "arm", arm)
+    monkeypatch.setattr(reg, "disarm", disarm)
+    monkeypatch.setattr(native, "watchdog_supported", lambda: False)
+
+    def step(v):
+        s, _ = mpx.allreduce(v, op=mpx.SUM)
+        return mpx.varying(s * 0.25 + v * 0.5)
+
+    unroll = 4
+    resilience.set_watchdog_timeout(60)
+    try:
+        mesh = mpx.make_world_mesh()
+        comm = mpx.Comm(mesh.axis_names[0], mesh=mesh)
+        k = comm.Get_size()
+        x = jnp.ones((k, 4), jnp.float32)
+        jax.block_until_ready(
+            mpx.compile(step, x, comm=comm, unroll=unroll)(x))
+        # array-less: ordered through its token
+        jax.block_until_ready(mpx.barrier(comm=comm).value)
+    finally:
+        resilience.reset_overrides()
+    deadline = time.monotonic() + 5.0
+    while not real_wd.registry_empty() and time.monotonic() < deadline:
+        time.sleep(0.05)  # disarm callbacks may trail block_until_ready
+    assert real_wd.registry_empty(), real_wd.inflight_snapshot()
+    # megastep bracket + one in-loop allreduce per step + the barrier
+    assert len(events) == 2 * k * (1 + unroll + 1), len(events)
+    in_flight = {}
+    for delta, call_id, rank in events:
+        depth = in_flight.get((call_id, rank), 0) + delta
+        assert depth >= 0, f"disarm before arm: call {call_id} rank {rank}"
+        in_flight[(call_id, rank)] = depth
 
 
 @needs_mpx

@@ -200,6 +200,62 @@ def test_pinned_prefill_matches_spmd_reference():
     _trees_equal(pinned, ref)
 
 
+def _chip_smoke():
+    """``chip_smoke.py`` (repo root) as a module: it owns the unsharded
+    reference forward, written independently of serving/model.py."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_tensor_parallel_prefill_matches_unsharded_forward():
+    """The 8-way tensor-parallel prefill reproduces chip_smoke.py's
+    plain-jnp forward of the unsharded master weights (the check its
+    stage C makes on the chip), and the logits it samples from are the
+    ones that pick prefill_step's first token."""
+    comm = _world_comm()
+    cfg = _tiny_cfg()
+    engine = ServingEngine(cfg, comm)
+    bucket, prompts_g, plens_g, slots_g = _manual_args(engine, cfg, comm)
+    # the engine's state sits on the mesh once, a row per device
+    for arr in engine._state:
+        assert len(arr.sharding.device_set) == comm.Get_size()
+    args = engine._state + (prompts_g, plens_g, slots_g)
+    logits = np.asarray(
+        mpx.spmd(smodel.prefill_logits, comm=comm)(*args))
+    ref = np.asarray(_chip_smoke().reference_prefill_logits(
+        engine.master, np.asarray(prompts_g)[0], np.asarray(plens_g)[0]))
+    # f32 on the CPU: only the order of the contractions differs
+    np.testing.assert_allclose(logits, np.tile(ref[None], (8, 1, 1)),
+                               rtol=0, atol=1e-5 * np.abs(ref).max())
+    first = np.asarray(mpx.spmd(smodel.prefill_step, comm=comm)(*args)[3])
+    np.testing.assert_array_equal(first, logits.argmax(-1))
+
+
+def test_engine_warm_pins_every_program_before_serving():
+    from mpi4jax_tpu.aot import pinning
+
+    pinning.reset_stats()
+    comm = _world_comm()
+    cfg = _tiny_cfg()
+    engine = ServingEngine(cfg, comm)
+    assert engine.warm() > 0
+    warmed = pinning.stats()
+    assert warmed["pins"] == 2 * len(engine.table.buckets)
+    out = engine.run(_tiny_trace(), scheduler="continuous")
+    assert out["failed"] == 0
+    served = pinning.stats()
+    # nothing was built inside the serving loop
+    assert served["pins"] == warmed["pins"]
+    assert served["compiles"] == warmed["compiles"]
+
+
 def test_decode_megastep_matches_stepwise_reference():
     """One pinned decode megastep (unroll=N) == N sequential un-bucketed
     single-step spmd calls, bit for bit."""
