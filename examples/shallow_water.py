@@ -874,6 +874,13 @@ def _blocked_specs(ny: int, nx: int, mrg: int):
     return grid, main, prev, nxt
 
 
+def _kernel_name(kind: str, nsteps: int, first_step: bool) -> str:
+    """The name a Pallas kernel carries into HLO and the profiler's trace
+    (``pallas_call(name=)``): the kind, the steps one call advances, and
+    ``_euler`` for the first-step variant, as ``sw_steps_x2``."""
+    return f"{kind}_x{nsteps}" + ("_euler" if first_step else "")
+
+
 def _tpu_compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
@@ -963,6 +970,7 @@ def model_step_pallas(state: State, cfg: Config, comm: mpx.Comm,
     ] * 6
     outs = pl.pallas_call(
         lambda *refs: _sw_steps_kernel(cfg, first_step, ny, mrg, nsteps, refs),
+        name=_kernel_name("sw_steps", nsteps, first_step),
         grid=grid,
         in_specs=in_specs,
         out_specs=[main_spec for _ in range(6)],
@@ -1043,10 +1051,11 @@ def _sw_phase_kernel(cfg: Config, mrg: int, nfields: int, window, refs):
         o[:] = f[sl]
 
 
-def _phase_pallas_call(cfg: Config, window, meta, fields, n_out: int,
-                       out_vma):
+def _phase_pallas_call(cfg: Config, name: str, window, meta, fields,
+                       n_out: int, out_vma):
     """Run ``window`` (a ``_phase*_window`` closure) as a compiled blocked
-    Pallas kernel over the rank-local arrays in ``fields``."""
+    Pallas kernel called ``name`` over the rank-local arrays in
+    ``fields``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1065,6 +1074,7 @@ def _phase_pallas_call(cfg: Config, window, meta, fields, n_out: int,
     ] * n_out
     return pl.pallas_call(
         lambda *refs: _sw_phase_kernel(cfg, mrg, len(fields), window, refs),
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=[main_spec for _ in range(n_out)],
@@ -1120,7 +1130,7 @@ def model_step_pallas_halo(state: State, cfg: Config, comm: mpx.Comm,
         )
     else:
         outs = _phase_pallas_call(
-            cfg,
+            cfg, _kernel_name("sw_phase1", 1, first_step),
             lambda iy, ix, giy, gix, fs: _phase1_window(
                 cfg, first_step, iy, ix, giy, gix, fs, _pltpu_roll()
             ),
@@ -1137,7 +1147,7 @@ def model_step_pallas_halo(state: State, cfg: Config, comm: mpx.Comm,
             u1, v1 = _phase2_window(cfg, iy, ix, giy, gix, u1, v1, jnp.roll)
         else:
             u1, v1 = _phase_pallas_call(
-                cfg,
+                cfg, "sw_phase2",
                 lambda iy, ix, giy, gix, fs: _phase2_window(
                     cfg, iy, ix, giy, gix, fs[0], fs[1], _pltpu_roll()
                 ),
@@ -1376,6 +1386,7 @@ def _wide_kernel_call(wfields, cfg: Config, first_step: bool, nsteps: int,
     ] * 6
     return pl.pallas_call(
         lambda *refs: _sw_wide_kernel(cfg, first_step, m, nsteps, refs),
+        name=_kernel_name("sw_wide", nsteps, first_step),
         grid=grid,
         in_specs=in_specs,
         out_specs=[main_spec for _ in range(6)],

@@ -39,6 +39,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils.profiling import span as _span, tracing as _tracing
 from . import diskcache, keys, serialization
 from .invalidation import StaleProgramError, WorldStamp
 
@@ -226,8 +227,10 @@ def _pin_executable(jitted, mesh, avals, label: str,
     dispatch per call, so the MPX128 hot-loop advisory must keep firing
     for them — only a true ``mpx.compile`` pin is exempt.
     """
-    with (_pinned_trace_scope() if mark_pinned else _null_scope()):
-        traced = jitted.trace(*avals)
+    with (_pinned_trace_scope() if mark_pinned else _null_scope()), \
+            _span("mpx.pin", program=label):
+        with _span("mpx.pin.trace"):
+            traced = jitted.trace(*avals)
         key = None
         if diskcache.enabled():
             key = keys.derive_key(
@@ -241,8 +244,10 @@ def _pin_executable(jitted, mesh, avals, label: str,
             payload = diskcache.get(key)
             if payload is not None:
                 _stats.disk_loads += 1
-                return serialization.loads(payload), key, True
-        compiled = traced.lower().compile()
+                with _span("mpx.pin.load"):
+                    return serialization.loads(payload), key, True
+        with _span("mpx.pin.compile"):
+            compiled = traced.lower().compile()
         _stats.compiles += 1
         if key is not None:
             diskcache.put(key, serialization.dumps(compiled))
@@ -272,7 +277,8 @@ def through_disk_cache(jitted, c, label: str = "fn"):
             call, _, _ = _pin_executable(jitted, mesh, _abstract(args),
                                          label, mark_pinned=False)
             memo[sig] = call
-        return call(*args)
+        with _span("mpx.launch"):
+            return call(*args)
 
     return cached_call
 
@@ -327,6 +333,19 @@ class PinnedProgram:
             else tuple(donate_argnums)
 
     def __call__(self, *args):
+        if _tracing():
+            # a profiler session runs: the same call under spans.
+            # ``mpx.call`` less ``mpx.launch`` is what this class adds
+            with _span("mpx.call", program=self.fn_name):
+                launch = self._prepare(args)
+                with _span("mpx.launch"):
+                    return launch(*args)
+        return self._prepare(args)(*args)
+
+    def _prepare(self, args):
+        """All the library does on a call before jax takes over: the
+        world stamp, the call count, the hazard notes.  Returns what to
+        launch."""
         world = self._world
         if not world.is_current():
             self._stats.stale_raises += 1
@@ -339,8 +358,8 @@ class PinnedProgram:
         if self._donate_call:
             _note_donation(self, args)
         if self._traceable is not None and _analysis_recording():
-            return self._traceable(*args)
-        return self._call(*args)
+            return self._traceable
+        return self._call
 
     def is_stale(self) -> bool:
         """Non-raising probe: would the next call raise MPX129?"""
@@ -482,7 +501,9 @@ def compile(fn, *abstract_args, comm=None, donate_argnums=(),
         from ..utils.config import unroll_default
 
         n_unroll = unroll_default()
-    name = getattr(inner, "__name__", "fn")
+    # the wrapper's name first: a caller may rename an spmd-decorated
+    # function after decorating it (one body, many programs)
+    name = getattr(fn, "__name__", None) or getattr(inner, "__name__", "fn")
 
     donate = _normalize_statics(donate_argnums, len(abstract_args)) \
         if donate_argnums else ()
@@ -561,7 +582,7 @@ def compile(fn, *abstract_args, comm=None, donate_argnums=(),
         body = make_region_body(
             inner, c, statics, static_vals, (), len(dyn_args),
             squeeze_in=in_specs is None, squeeze_out=out_specs is None,
-            unroll=n_unroll,
+            unroll=n_unroll, name=name,
         )
         sm = jax.shard_map(body, mesh=c.mesh, in_specs=ispecs,
                            out_specs=ospecs)

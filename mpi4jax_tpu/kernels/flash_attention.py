@@ -251,6 +251,7 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp):
             operands.append(_pad_to(mask, 1, tk_pad))
     o_bht, m_f, l_f = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=(q_spec, ml_spec, ml_spec),
@@ -449,6 +450,7 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
             _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
             tk=tk, n_kt=n_kt, has_mask=maskf is not None,
         ),
+        name="flash_attention_bwd_dq",
         grid=(b * h, n_qt),
         in_specs=dq_in_specs,
         out_specs=tile_spec,
@@ -480,6 +482,7 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
             _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
             tq=tq, tk=tk, n_qt=n_qt, has_mask=maskf is not None,
         ),
+        name="flash_attention_bwd_dkv",
         grid=(b * h, n_kt),
         in_specs=dkv_in_specs,
         out_specs=(ktile_spec, ktile_spec),

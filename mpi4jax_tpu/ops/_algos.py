@@ -44,6 +44,7 @@ multi-device mesh.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -336,6 +337,12 @@ def vdg_scatter_pairs(groups, root, w, K):
 # ---------------------------------------------------------------------------
 
 
+# One ``jax.named_scope`` per phase, under the calling op's own
+# ``mpi4jax_tpu.<op>`` scope (utils/debug.op_scope): a ring allreduce reads
+# ``mpi4jax_tpu.allreduce/ring_reduce_scatter/...`` then ``.../ring_allgather``
+# in HLO metadata and in xprof.  Metadata only: the operations are the same.
+
+@jax.named_scope("ring_reduce_scatter")
 def apply_ring_reduce_scatter(blocks, op, comm, k: int):
     """Ring reduce-scatter of ``blocks`` (shape ``(k, *s)``) over ``comm``:
     group position ``p`` receives ``fold_j blocks_j[p]`` in ascending group
@@ -375,6 +382,7 @@ def apply_ring_reduce_scatter(blocks, op, comm, k: int):
     return acc
 
 
+@jax.named_scope("ring_allgather")
 def apply_ring_allgather(v, comm, k: int, pos):
     """Ring allgather: position ``pos`` contributes ``v`` (shape ``(*s,)``)
     as chunk ``pos``; every position receives ``(k, *s)`` in group order.
@@ -427,6 +435,7 @@ def apply_ring_allreduce(x, op, comm, k=None):
     return full.reshape(-1)[:n].reshape(shape)
 
 
+@jax.named_scope("pairwise_exchange")
 def apply_pairwise_alltoall(blocks, comm, k: int):
     """Pairwise-exchange alltoall of ``blocks`` (shape ``(k, *s)``,
     block ``i`` addressed to group position ``i``) over ``comm``:
@@ -462,6 +471,7 @@ def apply_pairwise_alltoall(blocks, comm, k: int):
     return out
 
 
+@jax.named_scope("binomial_scatter")
 def apply_binomial_scatter(buf, groups, root: int, axis, relpos, K: int):
     """The binomial-halving scatter phase shared by ``apply_vdg_bcast``
     (over the whole comm) and the hierarchical broadcast (over the

@@ -35,6 +35,7 @@ from ..parallel.region import (
 )
 from ..utils.debug import get_logging, get_runtime_tracing, op_scope
 from ..utils.dtypes import check_dtype
+from ..utils.profiling import span as _span, tracing as _tracing
 
 # the trace-time collective verifier and the telemetry layer ride the same
 # single dispatch point as resilience and the algorithm selector (imported
@@ -695,6 +696,28 @@ def dispatch(opname: str, comm: Optional[Comm], body, arrays, token,
                 _analysis.end_event(evt, out)
             return out
 
+    if _tracing() and not any(isinstance(a, jax.core.Tracer)
+                              for a in arrays):
+        # a profiler session runs: the eager call under a span, whose
+        # self time is the checks and the cache probe.  Under an outer
+        # trace the op runs once, at trace time, and ``op_scope`` names it
+        with _span("mpx.eager." + opname):
+            return _dispatch_eager(opname, comm, body, arrays, token,
+                                   static_key, ana, bare)
+    return _dispatch_eager(opname, comm, body, arrays, token, static_key,
+                           ana, bare)
+
+
+def _launch(sm, arrays, token):
+    """One call of an eager op's jitted program, under ``mpx.launch``."""
+    with _span("mpx.launch"):
+        return sm(tuple(arrays), token)
+
+
+def _dispatch_eager(opname: str, comm: Comm, body, arrays, token,
+                    static_key, ana, bare):
+    """The eager half of :func:`dispatch`: a one-op jitted ``shard_map``
+    over the comm's mesh, from the eager cache where it can be."""
     if comm.mesh is None:
         raise RuntimeError(
             f"{opname}: called outside a parallel region with an unbound "
@@ -727,7 +750,7 @@ def dispatch(opname: str, comm: Optional[Comm], body, arrays, token,
             _bump_cache_stat("hits", telemetry_off)
             sm_hit, tele_cell = cached
             if telemetry_off:
-                results, tok_out = sm_hit(tuple(arrays), token)
+                results, tok_out = _launch(sm_hit, arrays, token)
                 return (*results, tok_out)
             # dispatch runs per call even on a hit, so the eager tier
             # counts per call — from the entry's stash for THIS call's
@@ -735,7 +758,7 @@ def dispatch(opname: str, comm: Optional[Comm], body, arrays, token,
             # its records under its own signature inside capture_eager)
             sig = _telemetry.call_signature(arrays)
             with _telemetry.capture_eager(tele_cell, sig):
-                results, tok_out = sm_hit(tuple(arrays), token)
+                results, tok_out = _launch(sm_hit, arrays, token)
             _telemetry.count_eager_call(tele_cell, sig)
             return (*results, tok_out)
         _bump_cache_stat("misses", telemetry_off)
@@ -786,11 +809,11 @@ def dispatch(opname: str, comm: Optional[Comm], body, arrays, token,
     # trace/compile failure must not leave a broken entry to be replayed
     tele_cell = _telemetry.EagerCell()
     if telemetry_off:
-        results, tok_out = sm(tuple(arrays), token)
+        results, tok_out = _launch(sm, arrays, token)
     else:
         sig = _telemetry.call_signature(arrays)
         with _telemetry.capture_eager(tele_cell, sig):
-            results, tok_out = sm(tuple(arrays), token)
+            results, tok_out = _launch(sm, arrays, token)
         _telemetry.count_eager_call(tele_cell, sig)
     if cache_key is not None:
         _eager_cache[cache_key] = (sm, tele_cell)

@@ -29,6 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
+from ..utils.profiling import span as _span, tracing as _tracing
 from .comm import Comm
 from .mesh import DEFAULT_AXIS, get_default_mesh
 
@@ -124,7 +125,8 @@ def shard_global(tree, comm: Optional[Comm] = None):
 
 
 def make_region_body(f, c: Comm, statics, static_vals, kw_names, n_dyn,
-                     squeeze_in: bool, squeeze_out: bool, unroll: int = 1):
+                     squeeze_in: bool, squeeze_out: bool, unroll: int = 1,
+                     name: Optional[str] = None):
     """Build the per-rank region body ``spmd`` traces: argument
     re-interleaving, the region context push/pop, fusion drain, pending
     tokenless-barrier tie-in, and the trace-time verifier hooks.
@@ -142,6 +144,10 @@ def make_region_body(f, c: Comm, statics, static_vals, kw_names, n_dyn,
     docs/aot.md "Megastep execution").  ``unroll == 1`` keeps the exact
     single-step body — trace and HLO byte-identical to before the
     megastep layer existed.
+
+    The body is called ``name`` (default: ``f``'s own name), so the
+    jitted program is the module ``jit_<name>`` in HLO and in a profiler
+    trace, not ``jit_body`` like every other.
     """
 
     def body(*a):
@@ -214,7 +220,19 @@ def make_region_body(f, c: Comm, statics, static_vals, kw_names, n_dyn,
         finally:
             _region_stack.pop()
 
+    body.__name__ = body.__qualname__ = name or getattr(f, "__name__", "fn")
     return body
+
+
+def _launched(program):
+    """``program`` with its calls under the span ``mpx.launch``: jax's
+    compiled call until it returns (its first call traces and compiles)."""
+
+    def launch(*args):
+        with _span("mpx.launch"):
+            return program(*args)
+
+    return launch
 
 
 def spmd(
@@ -262,8 +280,11 @@ def spmd(
         else:
             statics_raw = tuple(static_argnums)
 
-        @functools.wraps(f)
-        def wrapped(*args, **kwargs):
+        name = getattr(f, "__name__", "fn")
+
+        def program_for(args, kwargs):
+            """The compiled program of this call and its arguments: all
+            the library does on a call before jax takes over."""
             c = resolve_comm(comm)
             if c.mesh is None:
                 raise RuntimeError(
@@ -378,9 +399,7 @@ def spmd(
                 # flag flapping per step, or unhashed static args) shows
                 # up as a climbing recompiles.spmd.<name> count
                 _telemetry.meter("spmd_cache.misses")
-                _telemetry.meter(
-                    f"recompiles.spmd.{getattr(f, '__name__', 'fn')}"
-                )
+                _telemetry.meter(f"recompiles.spmd.{name}")
             if sm is None:
                 axes_spec = region_axes_spec(c)
                 ispecs = in_specs if in_specs is not None else axes_spec
@@ -414,10 +433,22 @@ def spmd(
                     if compile_cache_dir():
                         from ..aot import pinning as _pinning
 
-                        sm = _pinning.through_disk_cache(
-                            sm, c, label=getattr(f, "__name__", "fn"))
+                        sm = _pinning.through_disk_cache(sm, c, label=name)
+                    else:
+                        sm = _launched(sm)
                 program_cache[key] = sm
-            return sm(*dyn_args, *(kwargs[k] for k in kw_names))
+            return sm, (*dyn_args, *(kwargs[k] for k in kw_names))
+
+        @functools.wraps(f)
+        def wrapped(*args, **kwargs):
+            if _tracing():
+                # a profiler session runs: the same call under a span,
+                # whose self time is the flag stamp and the cache probe
+                with _span("mpx.region_call", program=name):
+                    sm, call_args = program_for(args, kwargs)
+                    return sm(*call_args)
+            sm, call_args = program_for(args, kwargs)
+            return sm(*call_args)
 
         # breadcrumbs for mpx.analyze: it rebuilds an UN-jitted twin from
         # the underlying per-rank function, because jit's trace cache
