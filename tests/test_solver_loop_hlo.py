@@ -1,0 +1,140 @@
+"""The solver's whole-run program, compiled by the TPU's compiler for a
+described (not attached) v5e: its loop copies no field.
+
+``_run_steps`` advances two chunk-kernel calls per loop iteration so that
+XLA's while loop needs no copy of the carry (six fields an iteration
+otherwise: 28.6 % of device time at 3600 x 28800, PERF.md).  On the CPU the
+copies cost nothing and show nowhere, so a later jax, or a later edit of the
+loop, could bring them back unseen; the compiled program shows them.
+
+Nothing runs here and no time is read.  The width is narrow (a compile takes
+seconds; at the benchmark's 3600 it takes two minutes) but the six fields
+(201 MB) are still far beyond what the chip holds on chip.
+"""
+
+import os
+import re
+import sys
+from functools import partial
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "examples"))
+
+NX, NY = 1022, 8190
+FIELD = rf"f32\[(1,)?{NY + 2},{NX + 2}\]"
+SIX_FIELDS = 6 * 4 * (NY + 2) * (NX + 2)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compile_leg(topo):
+    """The Euler step and ``steps`` more in one region, as ``solve_fused``
+    builds it, pinned for one described chip."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    import mpi4jax_tpu as mpx
+    import shallow_water as sw
+
+    cfg = sw.Config(nx=NX, ny=NY, nproc_y=1, nproc_x=1)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=topo.devices[:1])
+    field = jax.ShapeDtypeStruct(
+        (1, NY + 2, NX + 2), jnp.float32,
+        sharding=NamedSharding(comm.mesh,
+                               PartitionSpec(comm.mesh.axis_names)))
+
+    def compile_leg(mode, steps):
+        step, chunk, chunk_size = sw.select_steps(mode, cfg)
+
+        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+        def fused(state, total):
+            state = step(state, cfg, comm, first_step=True)
+            return sw._run_steps(state, total, cfg, comm, step, chunk,
+                                 chunk_size)
+
+        cache = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            return mpx.compile(fused, sw.State(*[field] * 6), steps)._call
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache)
+
+    return compile_leg
+
+
+def _computation(text, name):
+    """The instructions of the computation ``name`` of an HLO module."""
+    m = re.search(rf"^{re.escape(name)} \(.*?\{{\n(.*?)^\}}", text,
+                  re.M | re.S)
+    assert m, f"no computation {name}"
+    return m.group(1).splitlines()
+
+
+def _loop_bodies(text):
+    return [_computation(text, body) for body
+            in re.findall(r" while\(.*?body=(%[\w.\-]+)", text)]
+
+
+def _kernel_calls(lines, steps_a_call=2):
+    return [ln for ln in lines if re.match(
+        rf"\s*%sw_steps_x{steps_a_call}[.\d]* = .* custom-call\(", ln)]
+
+
+def _field_copies(lines):
+    return [ln.split(" copy(")[0].strip() for ln in lines
+            if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FIELD}\S* copy\(", ln)]
+
+
+def test_the_loop_of_the_compiled_leg_copies_no_field(compile_leg):
+    """What ``auto`` picks on one chip, at the benchmark's 70 steps: 35
+    two-step chunks, 17 pairs in the loop and one chunk after it."""
+    leg = compile_leg("auto", 70)
+    (body,) = _loop_bodies(leg.as_text())
+    assert len(_kernel_calls(body)) == 2, [
+        ln.split(" = ")[0].strip() for ln in body]
+    assert not _field_copies(body)
+    # one spare set of six fields and no more: the second call of an
+    # iteration writes where the first has read
+    temp = leg.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1 * SIX_FIELDS, (temp, SIX_FIELDS)
+
+
+@pytest.mark.parametrize("mode,steps,loops,in_line", [
+    ("pallas2", 6, 0, 3),    # three chunks: a loop of one trip, inlined
+    ("pallas3", 17, 1, 1),   # five chunks (two pairs, one after) + 2 steps
+])
+def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
+                                                loops, in_line):
+    """The cost of the loop having one form, held where it was measured.
+    Up to three chunks the loop is one trip, which XLA inlines; kernel
+    calls in line are given one spare set of fields or two by their count,
+    where the parent's loop held one (402,782,208 B against 202,520,576 B
+    at six steps here).  The same happens to a three-step kernel's odd
+    chunk count with two steps after it: three calls in line behind the
+    loop.  Over every count from 1 to 23 steps it was never more than two
+    sets, and the pair kernel from four chunks on always holds one."""
+    leg = compile_leg(mode, steps)
+    text = leg.as_text()
+    bodies = _loop_bodies(text)
+    assert len(bodies) == loops
+    for body in bodies:
+        assert len(_kernel_calls(body, int(mode[-1]))) == 2
+    body_lines = {ln for body in bodies for ln in body}
+    entry = [ln for ln in text.splitlines() if ln not in body_lines]
+    assert len(_kernel_calls(entry, int(mode[-1]))) == in_line
+    assert not _field_copies(text.splitlines())
+    temp = leg.memory_analysis().temp_size_in_bytes
+    assert temp < 2.1 * SIX_FIELDS, (temp, SIX_FIELDS)
