@@ -16,7 +16,10 @@ fails or a fallback taken makes the exit code non-zero:
   (3600 x 1800) through ``solve_fused(fast="auto", pinned=True)``, the path
   ``bench.py`` times: the Mosaic-compiled Pallas kernel (``pallas2`` on one
   device, the ``(2, 2)`` wide-halo ``wide2`` on four), the pinned artifact,
-  the final state against the plain ``jnp`` step on the same devices.
+  the final state against the plain ``jnp`` step on the same devices.  On
+  one device the closed basin (``periodic_x=False``) follows at the same
+  size: ``auto`` gives it ``wide2`` on the carried widened frame, the path
+  of every decomposed run, here without its permutes.
 - **C — a server that answers a few requests**: ``mpx.serving.ServingEngine``
   with the ``bench`` preset of ``examples/serving/serve.py``, tensor-parallel
   over all devices, a dozen requests, continuous scheduler; every program
@@ -276,6 +279,16 @@ def stage_a(ctx):
 
 
 def stage_b(ctx):
+    """The periodic domain on every device count; on one device also the
+    closed basin, which ``auto`` sends down the wide-halo path that every
+    decomposed run takes."""
+    info = _flagship(ctx, periodic_x=True)
+    if ctx["n"] == 1:
+        info = {"periodic": info, "walled": _flagship(ctx, periodic_x=False)}
+    return info
+
+
+def _flagship(ctx, periodic_x):
     import mpi4jax_tpu as mpx
     import shallow_water as sw
 
@@ -284,17 +297,20 @@ def stage_b(ctx):
     if ctx["rehearse"]:
         # the smallest interiors the wide-halo pair kernel takes
         cfg = sw.Config(nproc_y=nproc_y, nproc_x=nproc_x,
-                        nx=16 * nproc_x, ny=16 * nproc_y)
+                        nx=16 * nproc_x, ny=16 * nproc_y,
+                        periodic_x=periodic_x)
         multisteps, n_iters = 2, 2
     else:
-        cfg = sw.Config(nproc_y=nproc_y, nproc_x=nproc_x, nx=3600, ny=1800)
+        cfg = sw.Config(nproc_y=nproc_y, nproc_x=nproc_x, nx=3600, ny=1800,
+                        periodic_x=periodic_x)
         multisteps, n_iters = 10, 2
     t1 = cfg.dt * (1 + multisteps * n_iters)
     n_want = 1 + multisteps * n_iters
 
     _, comm = sw.make_mesh_and_comm(cfg, devices=ctx["devices"])
     single, chunk, _ = sw.select_steps("auto", cfg)
-    want_kernel = (sw.model_step2_pallas if n == 1 else sw.model_step2_wide)
+    want_kernel = (sw.model_step2_pallas if n == 1 and periodic_x
+                   else sw.model_step2_wide)
     check(chunk is want_kernel,
           f"'auto' chose {getattr(chunk, '__name__', chunk)} on {n} "
           f"device(s), not {want_kernel.__name__}")
@@ -344,6 +360,7 @@ def stage_b(ctx):
     check(not bad, f"field(s) {bad} differ from the jnp step "
                    f"(max error/bound): {worst}")
     return {"grid": [nproc_y, nproc_x], "interior": [cfg.ny, cfg.nx],
+            "periodic_x": periodic_x,
             "kernel": want_kernel.__name__, "interpret": interpret,
             "steps": n_steps, "timed_run_s": round(wall, 4),
             "max_err/bound": worst}

@@ -1471,6 +1471,18 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
     )
 
 
+def _wide_schedule(num_steps: int, chunk_size: int, euler_first: bool):
+    """``(head, trips, rem)`` of ``_wide_run``'s kernel calls after the
+    optional Euler call: ``head`` chunk call (0 or 1) straight off the
+    just-built frame, ``trips`` loop iterations of one band refresh and
+    one chunk call, ``rem`` single-step calls.  Shared with ``leg_plan``."""
+    nchunks, rem = divmod(num_steps - int(euler_first), chunk_size)
+    # the margins are still the just-exchanged ones until a kernel call
+    # invalidates them, so the first call after the build needs no refresh
+    head = int(bool(nchunks) and not euler_first)
+    return head, nchunks - head, rem
+
+
 def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
               chunk_size: int, m: int, interpret: bool,
               euler_first: bool) -> State:
@@ -1480,7 +1492,7 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
     (``_wide_refresh``), crop once at the end.  ``euler_first`` makes the
     first advanced step the forward-Euler one (a 1-step kernel call).
     This is the hot path behind every wide-mode driver (``make_stepper``
-    and ``solve_fused``)."""
+    and ``fused_runner``)."""
     if cfg.ny_local - 2 < m or cfg.nx_local - 2 < m:
         raise ValueError(
             "wide-halo path: local interior must be >= the exchange depth "
@@ -1490,31 +1502,22 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
         return state
     token = mpx.create_token()
     wf, token = _wide_exchange(tuple(state), cfg, comm, m, token)
-    rest = num_steps
-    # `fresh` tracks whether the margins are still the just-exchanged ones
-    # (a kernel call invalidates them); the first call after the build can
-    # then skip its redundant refresh
-    fresh = True
+    head, trips, rem = _wide_schedule(num_steps, chunk_size, euler_first)
     if euler_first:
         wf = _wide_kernel_call(wf, cfg, True, 1, m, interpret)
-        rest -= 1
-        fresh = False
-    nchunks, rem = divmod(rest, chunk_size)
 
     def body(_, wf):
         wf = _wide_refresh(wf, cfg, comm, m, token)
         return _wide_kernel_call(wf, cfg, False, chunk_size, m, interpret)
 
-    if nchunks and fresh:
+    if head:
         wf = _wide_kernel_call(wf, cfg, False, chunk_size, m, interpret)
-        nchunks -= 1
-        fresh = False
-    if nchunks:  # fori_loop(0, 0) would still trace the chunk kernel
-        wf = jax.lax.fori_loop(0, nchunks, body, tuple(wf))
-    for _ in range(rem):
-        if fresh:
-            fresh = False
-        else:
+    if trips:  # fori_loop(0, 0) would still trace the chunk kernel
+        wf = jax.lax.fori_loop(0, trips, body, tuple(wf))
+    # no kernel call yet: the margins are still the just-exchanged ones
+    fresh = not (euler_first or head or trips)
+    for i in range(rem):
+        if i or not fresh:
             wf = _wide_refresh(wf, cfg, comm, m, token)
         wf = _wide_kernel_call(wf, cfg, False, 1, m, interpret)
     return _wide_crop(wf, cfg, m)
@@ -1651,6 +1654,12 @@ def make_stepper(cfg: Config, comm: mpx.Comm, *, fast=True):
     return first_step, multistep
 
 
+def _steps_schedule(num_steps: int, chunk, chunk_size: int):
+    """``(chunk calls, single-step calls)`` of ``_run_steps``; shared with
+    ``leg_plan``."""
+    return divmod(num_steps, chunk_size) if chunk is not None else (0, num_steps)
+
+
 def _run_steps(state: State, num_steps: int, cfg, comm, step, chunk,
                chunk_size: int) -> State:
     """Advance ``num_steps`` non-first steps, using the chunk kernel for
@@ -1664,8 +1673,8 @@ def _run_steps(state: State, num_steps: int, cfg, comm, step, chunk,
     carry's buffers (28.6 % of device time at 3600 x 28800 on a v5e), with
     two the second call writes into the buffers the first has just read and
     no field is copied (tests/test_solver_loop_hlo.py)."""
+    nchunks, rem = _steps_schedule(num_steps, chunk, chunk_size)
     if chunk is not None:
-        nchunks, rem = divmod(num_steps, chunk_size)
         if nchunks:  # fori_loop(0, 0) would still trace the chunk kernel
             state = jax.lax.fori_loop(
                 0, nchunks, lambda _, s: chunk(s, cfg, comm, False), state,
@@ -1675,7 +1684,7 @@ def _run_steps(state: State, num_steps: int, cfg, comm, step, chunk,
             state = step(state, cfg, comm, False)
         return state
     return jax.lax.fori_loop(
-        0, num_steps, lambda _, s: step(s, cfg, comm, False), state
+        0, rem, lambda _, s: step(s, cfg, comm, False), state
     )
 
 
@@ -1731,6 +1740,70 @@ def solve(cfg: Config, t1: float, *, num_multisteps: int = 10, devices=None,
     return snapshots, wall, n_steps
 
 
+def fused_runner(cfg: Config, comm: mpx.Comm, fast="auto"):
+    """The whole-run region behind ``solve_fused``, for every caller that
+    times or pins it: ``(fused, chunk_size)``.
+
+    ``fused(state, total)`` is an ``mpx.spmd`` function (``total`` static)
+    that advances the forward-Euler first step and ``total`` Adams-Bashforth
+    steps in one program — a *leg* of ``total + 1`` steps.  Where
+    ``select_steps(fast, cfg)`` gives a wide mode it is ``_wide_run`` on the
+    carried widened frame (the frame built once, a margin-band refresh and
+    one kernel call per chunk, one crop at the end); otherwise the first
+    step and ``_run_steps``.  ``chunk_size`` is the number of steps one call
+    of the loop's kernel advances.  Pin it with ``mpx.compile(fused, state,
+    total)``; ``leg_plan`` says what the leg is made of."""
+    step, chunk, chunk_size = select_steps(fast, cfg)
+
+    if step is model_step_wide:
+        m = _margin_rows(chunk_size)
+        interpret = _resolve_interpret(comm)
+
+        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+        def fused(state: State, total: int) -> State:
+            return _wide_run(state, total + 1, cfg, comm, chunk_size, m,
+                             interpret, euler_first=True)
+
+    else:
+        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+        def fused(state: State, total: int) -> State:
+            state = step(state, cfg, comm, first_step=True)
+            return _run_steps(state, total, cfg, comm, step, chunk,
+                              chunk_size)
+
+    return fused, chunk_size
+
+
+def leg_plan(cfg: Config, fast, steps: int) -> dict:
+    """What one leg of ``steps`` model steps (the Euler step included) of
+    ``fused_runner(cfg, comm, fast)`` is made of, as counts per rank, by
+    the schedule the leg itself is built from (``_wide_schedule`` for the
+    wide modes, ``_steps_schedule`` otherwise):
+
+    - ``euler_calls``: calls of the first-step kernel (or step function);
+    - ``chunk_calls``: calls of the ``steps_per_kernel_call``-step kernel;
+    - ``single_step_calls``: one-step calls for what the chunks leave over
+      (every step after the first where the mode has no chunk kernel);
+    - ``frames_built``, ``band_refreshes``, ``crops``: the widened frame's
+      life in the wide modes (``_wide_exchange`` once, ``_wide_refresh``
+      before every call after the Euler one, ``_wide_crop`` once); 0
+      elsewhere."""
+    if steps < 1:
+        raise ValueError(f"a leg has at least its Euler step, got {steps}")
+    step, chunk, chunk_size = select_steps(fast, cfg)
+    wide = step is model_step_wide
+    if wide:
+        head, trips, rem = _wide_schedule(steps, chunk_size, True)
+        nchunks, refreshes = head + trips, trips + rem
+    else:
+        nchunks, rem = _steps_schedule(steps - 1, chunk, chunk_size)
+        refreshes = 0
+    return {"steps": steps, "steps_per_kernel_call": chunk_size,
+            "euler_calls": 1, "chunk_calls": nchunks,
+            "single_step_calls": rem, "frames_built": int(wide),
+            "band_refreshes": refreshes, "crops": int(wide)}
+
+
 def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
                 devices=None, fast=True, return_state=False,
                 pinned: bool = False, unroll: int = 0):
@@ -1765,23 +1838,7 @@ def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
     mesh, comm = make_mesh_and_comm(cfg, devices=devices)
     n_iters = max(0, math.ceil((t1 - cfg.dt) / (cfg.dt * num_multisteps)))
     n_steps = 1 + n_iters * num_multisteps
-    step, chunk, chunk_size = select_steps(fast, cfg)
-
-    if step is model_step_wide:
-        m = _margin_rows(chunk_size)
-        interpret = _resolve_interpret(comm)
-
-        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
-        def fused(state: State, total: int) -> State:
-            return _wide_run(state, total + 1, cfg, comm, chunk_size, m,
-                             interpret, euler_first=True)
-
-    else:
-        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
-        def fused(state: State, total: int) -> State:
-            state = step(state, cfg, comm, first_step=True)
-            return _run_steps(state, total, cfg, comm, step, chunk,
-                              chunk_size)
+    fused, _ = fused_runner(cfg, comm, fast)
 
     state = initial_state(cfg, comm)
     total = n_steps - 1
@@ -1792,10 +1849,13 @@ def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
         # each — the configuration that exposes per-dispatch host cost.
         # The Euler first step runs through the whole-run program at
         # total=0.
+        step, chunk, chunk_size = select_steps(fast, cfg)
+
         def one_step(state: State) -> State:
             if step is model_step_wide:
-                return _wide_run(state, 1, cfg, comm, chunk_size, m,
-                                 interpret, euler_first=False)
+                return _wide_run(state, 1, cfg, comm, chunk_size,
+                                 _margin_rows(chunk_size),
+                                 _resolve_interpret(comm), euler_first=False)
             return _run_steps(state, 1, cfg, comm, step, chunk, chunk_size)
 
         n_mega, tail = divmod(total, unroll)

@@ -1,0 +1,150 @@
+"""``fused_runner`` and ``leg_plan``: the whole-run region of the
+shallow-water solver as a public function, and the program's own count of
+what one leg of it is made of.
+
+``fused_runner`` is what ``solve_fused`` runs and what the benchmark pins
+(``chipbench/drivers/solver_runner.py``), so the two must give the same
+bits; ``leg_plan`` is held against the calls a leg really makes, counted
+while it is traced.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "examples"))
+
+import mpi4jax_tpu as mpx  # noqa: E402
+import shallow_water as sw  # noqa: E402
+
+STEPS = 12  # the Euler step, five pairs and one step over
+
+
+def _config(mesh, periodic_x):
+    return sw.Config(nproc_y=mesh[0], nproc_x=mesh[1], nx=64, ny=32,
+                     periodic_x=periodic_x)
+
+
+@pytest.mark.parametrize("fast,mesh,periodic_x", [
+    ("pallas2", (1, 1), True),
+    ("wide2", (1, 1), True),
+    ("wide2", (1, 1), False),
+    ("wide2", (2, 2), True),
+    ("wide2", (2, 2), False),
+    ("auto", (1, 1), False),
+])
+def test_fused_runner_gives_what_solve_fused_gives(fast, mesh, periodic_x):
+    """Bit for bit, called as a region and pinned with ``mpx.compile``.  The
+    stepper's two programs (another route through the same kernels: the
+    frame built twice, cropped twice) agree with it to rounding: XLA fuses
+    two programs' arithmetic differently, an ulp here and there."""
+    cfg = _config(mesh, periodic_x)
+    devices = jax.devices()[: cfg.nproc]
+    _, n_steps, want = sw.solve_fused(
+        cfg, STEPS * cfg.dt, num_multisteps=1, devices=devices, fast=fast,
+        return_state=True)
+    assert n_steps == STEPS
+
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=devices)
+    fused, chunk_size = sw.fused_runner(cfg, comm, fast)
+    assert chunk_size == sw.leg_plan(cfg, fast, STEPS)[
+        "steps_per_kernel_call"] == 2
+    state = sw.initial_state(cfg, comm)
+    first_step, multistep = sw.make_stepper(cfg, comm, fast=fast)
+    runs = {"region": fused(state, STEPS - 1),
+            "pinned": mpx.compile(fused, state, STEPS - 1)(state),
+            "stepper": multistep(first_step(state), STEPS - 1)}
+    for how, got in runs.items():
+        for name, a, b in zip(want._fields, got, want):
+            a, b = np.asarray(a), np.asarray(b)
+            if how == "stepper":
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                                           err_msg=name)
+            else:
+                np.testing.assert_array_equal(a, b, f"{how}: {name}")
+
+
+class _Tally:
+    """Counts the calls a leg makes while it is traced: a call inside a
+    ``fori_loop``'s body counts once per trip."""
+
+    def __init__(self, monkeypatch):
+        self.counts = {}
+        self.trips = []
+        self.times = 1
+        fori_loop = jax.lax.fori_loop
+
+        def counting_loop(lower, upper, body, init, **kw):
+            self.trips.append(upper - lower)
+            before, self.times = self.times, self.times * (upper - lower)
+            try:
+                return fori_loop(lower, upper, body, init, **kw)
+            finally:
+                self.times = before
+
+        monkeypatch.setattr(jax.lax, "fori_loop", counting_loop)
+        self.monkeypatch = monkeypatch
+
+    def count(self, name, key=lambda *a, **k: ""):
+        inner = getattr(sw, name)
+
+        def counted(*args, **kwargs):
+            label = name + key(*args, **kwargs)
+            self.counts[label] = self.counts.get(label, 0) + self.times
+            return inner(*args, **kwargs)
+
+        self.monkeypatch.setattr(sw, name, counted)
+
+
+@pytest.mark.parametrize("steps", range(1, 24))
+@pytest.mark.parametrize("fast,periodic_x", [("wide2", False),
+                                             ("pallas2", True)])
+def test_leg_plan_counts_the_calls_a_leg_makes(monkeypatch, fast,
+                                               periodic_x, steps):
+    cfg = _config((1, 1), periodic_x)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    tally = _Tally(monkeypatch)
+    # first_step, nsteps: positional in _wide_kernel_call, by keyword or
+    # default in model_step_pallas
+    tally.count("_wide_kernel_call",
+                lambda wf, cfg, first, nsteps, *a: f"/{first}/{nsteps}")
+    tally.count("model_step_pallas",
+                lambda s, cfg, comm, first_step, interpret=None, nsteps=1:
+                f"/{first_step}/{nsteps}")
+    for name in ("_wide_exchange", "_wide_refresh", "_wide_crop"):
+        tally.count(name)
+    fused, _ = sw.fused_runner(cfg, comm, fast)
+    state = sw.initial_state(cfg, comm)
+    jax.eval_shape(lambda s: fused(s, steps - 1), state)
+
+    plan = sw.leg_plan(cfg, fast, steps)
+    kernel = "_wide_kernel_call" if fast == "wide2" else "model_step_pallas"
+    got = tally.counts
+    assert plan["steps"] == steps == (
+        plan["euler_calls"] + 2 * plan["chunk_calls"]
+        + plan["single_step_calls"])
+    assert got.get(f"{kernel}/True/1", 0) == plan["euler_calls"] == 1
+    assert got.get(f"{kernel}/False/2", 0) == plan["chunk_calls"]
+    assert got.get(f"{kernel}/False/1", 0) == plan["single_step_calls"]
+    assert got.get("_wide_exchange", 0) == plan["frames_built"]
+    assert got.get("_wide_refresh", 0) == plan["band_refreshes"]
+    assert got.get("_wide_crop", 0) == plan["crops"]
+    # one loop, over the chunk calls (two a trip where ``_run_steps``
+    # unrolls, which is its own to say), and none where there is no chunk
+    assert tally.trips == ([plan["chunk_calls"]] if plan["chunk_calls"]
+                           else [])
+
+
+def test_leg_plan_of_a_mode_without_a_chunk_kernel():
+    cfg = _config((1, 1), True)
+    plan = sw.leg_plan(cfg, True, 7)
+    assert (plan["euler_calls"], plan["chunk_calls"],
+            plan["single_step_calls"]) == (1, 0, 6)
+    assert not (plan["frames_built"] or plan["band_refreshes"]
+                or plan["crops"])
+    with pytest.raises(ValueError, match="Euler"):
+        sw.leg_plan(cfg, "auto", 0)

@@ -15,7 +15,6 @@ seconds; at the benchmark's 3600 it takes two minutes) but the six fields
 import os
 import re
 import sys
-from functools import partial
 
 import pytest
 
@@ -42,29 +41,22 @@ def topo():
 
 @pytest.fixture(scope="module")
 def compile_leg(topo):
-    """The Euler step and ``steps`` more in one region, as ``solve_fused``
-    builds it, pinned for one described chip."""
+    """The Euler step and ``steps`` more in one region, the program's own
+    ``fused_runner``, pinned for one described chip."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     import mpi4jax_tpu as mpx
     import shallow_water as sw
 
-    cfg = sw.Config(nx=NX, ny=NY, nproc_y=1, nproc_x=1)
-    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=topo.devices[:1])
-    field = jax.ShapeDtypeStruct(
-        (1, NY + 2, NX + 2), jnp.float32,
-        sharding=NamedSharding(comm.mesh,
-                               PartitionSpec(comm.mesh.axis_names)))
-
-    def compile_leg(mode, steps):
-        step, chunk, chunk_size = sw.select_steps(mode, cfg)
-
-        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
-        def fused(state, total):
-            state = step(state, cfg, comm, first_step=True)
-            return sw._run_steps(state, total, cfg, comm, step, chunk,
-                                 chunk_size)
-
+    def compile_leg(mode, steps, periodic_x=True):
+        cfg = sw.Config(nx=NX, ny=NY, nproc_y=1, nproc_x=1,
+                        periodic_x=periodic_x)
+        _mesh, comm = sw.make_mesh_and_comm(cfg, devices=topo.devices[:1])
+        field = jax.ShapeDtypeStruct(
+            (1, NY + 2, NX + 2), jnp.float32,
+            sharding=NamedSharding(comm.mesh,
+                                   PartitionSpec(comm.mesh.axis_names)))
+        fused, _ = sw.fused_runner(cfg, comm, mode)
         cache = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
@@ -88,9 +80,21 @@ def _loop_bodies(text):
             in re.findall(r" while\(.*?body=(%[\w.\-]+)", text)]
 
 
-def _kernel_calls(lines, steps_a_call=2):
+def _kernel_calls(lines, steps_a_call=2, kind="sw_steps", euler=False):
+    name = f"{kind}_x{steps_a_call}" + ("_euler" if euler else "")
     return [ln for ln in lines if re.match(
-        rf"\s*%sw_steps_x{steps_a_call}[.\d]* = .* custom-call\(", ln)]
+        rf"\s*%{name}[.\d]* = .* custom-call\(", ln)]
+
+
+def _trip_counts(text):
+    """The trip count of each ``while`` of an HLO module: the constant its
+    condition compares the counter with."""
+    counts = []
+    for cond in re.findall(r" while\(.*?condition=(%[\w.\-]+)", text):
+        (n,) = re.findall(r" constant\((\d+)\)",
+                          "\n".join(_computation(text, cond)))
+        counts.append(int(n))
+    return counts
 
 
 def _field_copies(lines):
@@ -138,3 +142,50 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
     assert not _field_copies(text.splitlines())
     temp = leg.memory_analysis().temp_size_in_bytes
     assert temp < 2.1 * SIX_FIELDS, (temp, SIX_FIELDS)
+
+
+FRAME = rf"f32\[{NY + 32},{NX + 32}\]"  # the widened frame: 15 cells a side
+
+
+def test_the_walled_leg_builds_one_frame_and_crops_once(compile_leg):
+    """A closed basin on one chip, what ``auto`` gives every deployment but
+    the single periodic chip: the wide-halo pair kernel on the carried
+    widened frame.  70 steps after the Euler one: the frame is built before
+    the loop and cropped after it, the loop's body holds one kernel call
+    and neither, and the whole is what ``leg_plan`` says.  (The body also
+    holds six whole-frame copies of the carry today, 2.27 sets of
+    temporaries with them: PERF.md; the PR that removes them asserts it.)"""
+    import shallow_water as sw
+
+    leg = compile_leg("auto", 70, periodic_x=False)
+    text = leg.as_text()
+    (body,) = _loop_bodies(text)
+    body_lines = set(body)
+    outside = [ln for ln in text.splitlines() if ln not in body_lines]
+    plan = sw.leg_plan(sw.Config(nx=NX, ny=NY, periodic_x=False), "auto", 71)
+    assert plan == {"steps": 71, "steps_per_kernel_call": 2,
+                    "euler_calls": 1, "chunk_calls": 35,
+                    "single_step_calls": 0, "frames_built": 1,
+                    "band_refreshes": 35, "crops": 1}
+
+    assert len(_kernel_calls(body, 2, "sw_wide")) == 1
+    assert _trip_counts(text) == [plan["chunk_calls"]]  # one call a trip
+    assert not _kernel_calls(outside, 2, "sw_wide")
+    assert len(_kernel_calls(outside, 1, "sw_wide", euler=True)) == 1
+    assert not _kernel_calls(text.splitlines())  # no whole-step kernel here
+
+    def frames_made(lines):  # a field widened to the frame
+        return [ln for ln in lines
+                if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FRAME}\S* concatenate\(",
+                            ln)]
+
+    def crops(lines):  # a frame cut back to a field
+        return [ln for ln in lines
+                if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FIELD}\S* slice\(",
+                            ln)]
+
+    assert len(frames_made(outside)) == 6 * plan["frames_built"]
+    assert len(crops(outside)) == 6 * plan["crops"]
+    assert not frames_made(body) and not crops(body)
+    temp = leg.memory_analysis().temp_size_in_bytes
+    assert temp < 2.5 * SIX_FIELDS, (temp, SIX_FIELDS)
