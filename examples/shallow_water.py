@@ -1419,11 +1419,11 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
     is valid but the ``m - 1``-deep margins are recompute garbage.  The
     carried-frame driver (``solve_fused`` wide modes) therefore never
     crops between calls: it exchanges just the margin bands — four
-    messages of ``(6, ·, m-1)`` — and updates them in place with
-    ``.at[].set`` (inside the ``fori_loop`` XLA updates the carried
-    buffers without copying the untouched interior), so the full-array
-    concat/crop copies of ``model_step_pallas_wide`` happen once per RUN
-    instead of once per pair of steps.
+    messages of ``(6, ·, m-1)`` — and writes them over the margins with
+    ``.at[].set``, which XLA does in place (a ``dynamic-update-slice`` of
+    the band alone, the interior untouched), so the full-array concat/crop
+    copies of ``model_step_pallas_wide`` happen once per RUN instead of
+    once per pair of steps.
 
     Two-phase for corners: x bands first (their corner rows are the
     sender's own garbage y-margins), then y bands at full widened width —
@@ -1474,8 +1474,9 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
 def _wide_schedule(num_steps: int, chunk_size: int, euler_first: bool):
     """``(head, trips, rem)`` of ``_wide_run``'s kernel calls after the
     optional Euler call: ``head`` chunk call (0 or 1) straight off the
-    just-built frame, ``trips`` loop iterations of one band refresh and
-    one chunk call, ``rem`` single-step calls.  Shared with ``leg_plan``."""
+    just-built frame, ``trips`` rounds of one band refresh and one chunk
+    call (``_wide_run``'s loop runs two rounds an iteration), ``rem``
+    single-step calls.  Shared with ``leg_plan``."""
     nchunks, rem = divmod(num_steps - int(euler_first), chunk_size)
     # the margins are still the just-exchanged ones until a kernel call
     # invalidates them, so the first call after the build needs no refresh
@@ -1492,7 +1493,17 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
     (``_wide_refresh``), crop once at the end.  ``euler_first`` makes the
     first advanced step the forward-Euler one (a 1-step kernel call).
     This is the hot path behind every wide-mode driver (``make_stepper``
-    and ``fused_runner``)."""
+    and ``fused_runner``).
+
+    The chunk loop advances two refresh-and-call rounds per iteration (an
+    odd count's last round follows the loop), as ``_run_steps``' does and
+    for its reason.  The kernel reads each frame through three overlapping
+    block specs and so cannot write in place; with one call per iteration
+    XLA's while loop copies all six new frames back into the carry's
+    buffers (26.4 % of device time at 3600 x 28800, walled, on a v5e), with
+    two the second call writes into the buffers the first has just read and
+    no frame is copied, on one chip or on a mesh
+    (tests/test_solver_loop_hlo.py)."""
     if cfg.ny_local - 2 < m or cfg.nx_local - 2 < m:
         raise ValueError(
             "wide-halo path: local interior must be >= the exchange depth "
@@ -1513,7 +1524,7 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
     if head:
         wf = _wide_kernel_call(wf, cfg, False, chunk_size, m, interpret)
     if trips:  # fori_loop(0, 0) would still trace the chunk kernel
-        wf = jax.lax.fori_loop(0, trips, body, tuple(wf))
+        wf = jax.lax.fori_loop(0, trips, body, tuple(wf), unroll=2)
     # no kernel call yet: the margins are still the just-exchanged ones
     fresh = not (euler_first or head or trips)
     for i in range(rem):

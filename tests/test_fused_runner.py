@@ -29,19 +29,34 @@ def _config(mesh, periodic_x):
                      periodic_x=periodic_x)
 
 
-@pytest.mark.parametrize("fast,mesh,periodic_x", [
+CASES = [
     ("pallas2", (1, 1), True),
     ("wide2", (1, 1), True),
     ("wide2", (1, 1), False),
     ("wide2", (2, 2), True),
     ("wide2", (2, 2), False),
     ("auto", (1, 1), False),
-])
+]
+# Smallest ``atol`` beside ``rtol=1e-5`` that the stepper's two programs
+# pass at against the leg, 12 steps at 64 x 32 on the CPU, largest over the
+# six fields (the field), before / after ``_wide_run``'s loop ran two rounds
+# an iteration: pallas2 0 / 0; wide2 periodic (1, 1) 0 / 0; wide2 walled
+# (1, 1) and auto 3.2e-10 (dh) / 1.19e-6 (u; v 9.0e-7); wide2 periodic
+# (2, 2) 2.9e-7 (v) / the same; wide2 walled (2, 2) 9.8e-7 (v) / the same.
+# One step fewer reads 5.5e-1 on u.  With XLA's fusion pass off every one
+# reads 0 (the test below), so what is read here is LLVM contracting
+# multiply-adds across the instructions XLA fused, and which ones it fuses
+# changes with the program around the interpreted kernel.
+STEPPER_ATOL = {("wide2", (1, 1), False): 2e-6, ("auto", (1, 1), False): 2e-6}
+
+
+@pytest.mark.parametrize("fast,mesh,periodic_x", CASES)
 def test_fused_runner_gives_what_solve_fused_gives(fast, mesh, periodic_x):
     """Bit for bit, called as a region and pinned with ``mpx.compile``.  The
     stepper's two programs (another route through the same kernels: the
     frame built twice, cropped twice) agree with it to rounding: XLA fuses
-    two programs' arithmetic differently, an ulp here and there."""
+    two programs' arithmetic differently, an ulp here and there
+    (``STEPPER_ATOL``: 1e-6 but on the walled single rank)."""
     cfg = _config(mesh, periodic_x)
     devices = jax.devices()[: cfg.nproc]
     _, n_steps, want = sw.solve_fused(
@@ -58,14 +73,62 @@ def test_fused_runner_gives_what_solve_fused_gives(fast, mesh, periodic_x):
     runs = {"region": fused(state, STEPS - 1),
             "pinned": mpx.compile(fused, state, STEPS - 1)(state),
             "stepper": multistep(first_step(state), STEPS - 1)}
+    atol = STEPPER_ATOL.get((fast, mesh, periodic_x), 1e-6)
     for how, got in runs.items():
         for name, a, b in zip(want._fields, got, want):
             a, b = np.asarray(a), np.asarray(b)
             if how == "stepper":
-                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=atol,
                                            err_msg=name)
             else:
                 np.testing.assert_array_equal(a, b, f"{how}: {name}")
+
+
+def _unfused(fn, *args):
+    """``fn(*args)`` compiled with XLA's fusion pass off: every instruction
+    is a loop of its own, LLVM contracts no multiply-add across two of them,
+    and the arithmetic is the program's whatever stands around it."""
+    compiled = jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_disable_hlo_passes": "fusion"})
+    return compiled(*args)
+
+
+@pytest.mark.parametrize("fast,mesh,periodic_x", CASES)
+def test_the_leg_is_its_rounds_bit_for_bit_with_fusion_off(
+        monkeypatch, fast, mesh, periodic_x):
+    """The same kernels on the same operands in the same order, whatever
+    the loop's form: with XLA's fusion pass off the leg gives, bit for
+    bit, what the stepper's two programs give over the same rounds (the
+    frame built twice, a call off the fresh frame, the loop split
+    elsewhere), and what the leg gives with every loop run one call an
+    iteration, the form before the carry was ping-ponged."""
+    cfg = _config(mesh, periodic_x)
+    _mesh, comm = sw.make_mesh_and_comm(cfg,
+                                        devices=jax.devices()[: cfg.nproc])
+    state = sw.initial_state(cfg, comm)
+    fused, _ = sw.fused_runner(cfg, comm, fast)
+    want = _unfused(lambda s: fused(s, STEPS - 1), state)
+
+    first_step, multistep = sw.make_stepper(cfg, comm, fast=fast)
+    runs = {"stepper": _unfused(lambda s: multistep(s, STEPS - 1),
+                                _unfused(first_step, state))}
+
+    fori_loop, unrolled = jax.lax.fori_loop, []
+
+    def one_call_an_iteration(lower, upper, body, init, unroll=None):
+        unrolled.append(unroll)
+        return fori_loop(lower, upper, body, init)
+
+    monkeypatch.setattr(jax.lax, "fori_loop", one_call_an_iteration)
+    plain, _ = sw.fused_runner(cfg, comm, fast)  # traced anew
+    runs["one call an iteration"] = _unfused(
+        lambda s: plain(s, STEPS - 1), state)
+    assert 2 in unrolled  # the leg's own loop was among those rewritten
+
+    for how, got in runs.items():
+        for name, a, b in zip(want._fields, got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          f"{how}: {name}")
 
 
 class _Tally:

@@ -3,7 +3,9 @@ described (not attached) v5e: its loop copies no field.
 
 ``_run_steps`` advances two chunk-kernel calls per loop iteration so that
 XLA's while loop needs no copy of the carry (six fields an iteration
-otherwise: 28.6 % of device time at 3600 x 28800, PERF.md).  On the CPU the
+otherwise: 28.6 % of device time at 3600 x 28800, PERF.md), and ``_wide_run``
+two refresh-and-call rounds on the carried widened frame (six frames an
+iteration otherwise: 26.4 % of the walled domain's).  On the CPU the
 copies cost nothing and show nowhere, so a later jax, or a later edit of the
 loop, could bring them back unseen; the compiled program shows them.
 
@@ -42,25 +44,33 @@ def topo():
 @pytest.fixture(scope="module")
 def compile_leg(topo):
     """The Euler step and ``steps`` more in one region, the program's own
-    ``fused_runner``, pinned for one described chip."""
+    ``fused_runner``, pinned for one described chip — or for the 2 x 2 with
+    ``mesh=(2, 2)``, every chip holding the same local field; with
+    ``multistep=True`` the ``steps``-step program of ``make_stepper``."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     import mpi4jax_tpu as mpx
     import shallow_water as sw
 
-    def compile_leg(mode, steps, periodic_x=True):
-        cfg = sw.Config(nx=NX, ny=NY, nproc_y=1, nproc_x=1,
+    def compile_leg(mode, steps, periodic_x=True, mesh=(1, 1),
+                    multistep=False):
+        py, px = mesh
+        cfg = sw.Config(nx=NX * px, ny=NY * py, nproc_y=py, nproc_x=px,
                         periodic_x=periodic_x)
-        _mesh, comm = sw.make_mesh_and_comm(cfg, devices=topo.devices[:1])
+        _mesh, comm = sw.make_mesh_and_comm(cfg,
+                                            devices=topo.devices[:py * px])
         field = jax.ShapeDtypeStruct(
-            (1, NY + 2, NX + 2), jnp.float32,
+            (py * px, NY + 2, NX + 2), jnp.float32,
             sharding=NamedSharding(comm.mesh,
                                    PartitionSpec(comm.mesh.axis_names)))
-        fused, _ = sw.fused_runner(cfg, comm, mode)
+        if multistep:
+            _first_step, program = sw.make_stepper(cfg, comm, fast=mode)
+        else:
+            program, _ = sw.fused_runner(cfg, comm, mode)
         cache = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
-            return mpx.compile(fused, sw.State(*[field] * 6), steps)._call
+            return mpx.compile(program, sw.State(*[field] * 6), steps)._call
         finally:
             jax.config.update("jax_enable_compilation_cache", cache)
 
@@ -78,6 +88,13 @@ def _computation(text, name):
 def _loop_bodies(text):
     return [_computation(text, body) for body
             in re.findall(r" while\(.*?body=(%[\w.\-]+)", text)]
+
+
+def _split_at_loops(text):
+    """``(loop bodies, every line outside them)`` of an HLO module."""
+    bodies = _loop_bodies(text)
+    body_lines = {ln for body in bodies for ln in body}
+    return bodies, [ln for ln in text.splitlines() if ln not in body_lines]
 
 
 def _kernel_calls(lines, steps_a_call=2, kind="sw_steps", euler=False):
@@ -132,12 +149,10 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
     sets, and the pair kernel from four chunks on always holds one."""
     leg = compile_leg(mode, steps)
     text = leg.as_text()
-    bodies = _loop_bodies(text)
+    bodies, entry = _split_at_loops(text)
     assert len(bodies) == loops
     for body in bodies:
         assert len(_kernel_calls(body, int(mode[-1]))) == 2
-    body_lines = {ln for body in bodies for ln in body}
-    entry = [ln for ln in text.splitlines() if ln not in body_lines]
     assert len(_kernel_calls(entry, int(mode[-1]))) == in_line
     assert not _field_copies(text.splitlines())
     temp = leg.memory_analysis().temp_size_in_bytes
@@ -147,30 +162,38 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
 FRAME = rf"f32\[{NY + 32},{NX + 32}\]"  # the widened frame: 15 cells a side
 
 
+def _frame_copies(lines, layout=""):
+    """Whole-frame copies; ``layout="{1,0"`` keeps the row-major ones (the
+    carry's), ``"{0,1"`` the transposing ones."""
+    return [ln.split(" copy(")[0].strip() for ln in lines
+            if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FRAME}{re.escape(layout)}"
+                        r"\S* copy\(", ln)]
+
+
 def test_the_walled_leg_builds_one_frame_and_crops_once(compile_leg):
     """A closed basin on one chip, what ``auto`` gives every deployment but
     the single periodic chip: the wide-halo pair kernel on the carried
     widened frame.  70 steps after the Euler one: the frame is built before
-    the loop and cropped after it, the loop's body holds one kernel call
-    and neither, and the whole is what ``leg_plan`` says.  (The body also
-    holds six whole-frame copies of the carry today, 2.27 sets of
-    temporaries with them: PERF.md; the PR that removes them asserts it.)"""
+    the loop and cropped after it; the loop's body holds two kernel calls,
+    no whole-frame copy and neither build nor crop; 35 chunk calls are 17
+    trips and one call behind the loop; and the whole is what ``leg_plan``
+    says."""
     import shallow_water as sw
 
     leg = compile_leg("auto", 70, periodic_x=False)
     text = leg.as_text()
-    (body,) = _loop_bodies(text)
-    body_lines = set(body)
-    outside = [ln for ln in text.splitlines() if ln not in body_lines]
+    (body,), outside = _split_at_loops(text)
     plan = sw.leg_plan(sw.Config(nx=NX, ny=NY, periodic_x=False), "auto", 71)
     assert plan == {"steps": 71, "steps_per_kernel_call": 2,
                     "euler_calls": 1, "chunk_calls": 35,
                     "single_step_calls": 0, "frames_built": 1,
                     "band_refreshes": 35, "crops": 1}
 
-    assert len(_kernel_calls(body, 2, "sw_wide")) == 1
-    assert _trip_counts(text) == [plan["chunk_calls"]]  # one call a trip
-    assert not _kernel_calls(outside, 2, "sw_wide")
+    assert len(_kernel_calls(body, 2, "sw_wide")) == 2
+    assert not _frame_copies(body)
+    assert _trip_counts(text) == [plan["chunk_calls"] // 2]  # a pair a trip
+    assert (len(_kernel_calls(outside, 2, "sw_wide"))
+            == plan["chunk_calls"] % 2)  # the odd one, behind the loop
     assert len(_kernel_calls(outside, 1, "sw_wide", euler=True)) == 1
     assert not _kernel_calls(text.splitlines())  # no whole-step kernel here
 
@@ -187,5 +210,56 @@ def test_the_walled_leg_builds_one_frame_and_crops_once(compile_leg):
     assert len(frames_made(outside)) == 6 * plan["frames_built"]
     assert len(crops(outside)) == 6 * plan["crops"]
     assert not frames_made(body) and not crops(body)
+    # the carry's frames, one spare set of them and the bands (2.26 read,
+    # 2.27 with the copies): the second call writes where the first read
     temp = leg.memory_analysis().temp_size_in_bytes
-    assert temp < 2.5 * SIX_FIELDS, (temp, SIX_FIELDS)
+    assert temp < 2.4 * SIX_FIELDS, (temp, SIX_FIELDS)
+
+
+def test_the_walled_leg_on_a_2x2_copies_no_carried_frame(compile_leg):
+    """The same leg on the described 2 x 2 (every chip the one-chip case's
+    local field): the body holds two rounds — two kernel calls, two band
+    refreshes of four permutes each, every exchange on the caller's token
+    and ordered by data alone — and no row-major whole-frame copy, which
+    is what the carry's copies were.  (The transposing ones that cut the x
+    bands out of a frame are another matter: PERF.md section 7.)"""
+    leg = compile_leg("auto", 70, periodic_x=False, mesh=(2, 2))
+    text = leg.as_text()
+    (body,), outside = _split_at_loops(text)
+    assert len(_kernel_calls(body, 2, "sw_wide")) == 2
+    assert not _frame_copies(body, "{1,0")
+    assert sum(" collective-permute-start(" in ln for ln in body) == 2 * 4
+    assert _trip_counts(text) == [17]
+    assert len(_kernel_calls(outside, 2, "sw_wide")) == 1
+    temp = leg.memory_analysis().temp_size_in_bytes
+    assert temp < 2.4 * SIX_FIELDS, (temp, SIX_FIELDS)
+
+
+@pytest.mark.parametrize("multistep,steps,periodic_x,loops,in_line", [
+    (False, 6, False, 0, 3),   # Euler + 3 rounds: one odd trip, inlined
+    (True, 6, True, 0, 3),     # a call off the frame + 2 rounds, inlined
+    (False, 10, False, 1, 1),  # Euler + 5 rounds: two pairs, one behind
+    (True, 10, True, 1, 1),    # solve()'s default: 1 + 4, two pairs
+])
+def test_short_wide_runs_hold_no_more_than_the_loop(compile_leg, multistep,
+                                                    steps, periodic_x, loops,
+                                                    in_line):
+    """``wide2`` below the benchmark's length, a leg (walled) and a
+    ``multistep`` (periodic, one rank) each with an even and an odd count
+    of rounds.  Up to three rounds the loop is one trip, which XLA
+    inlines; from four on it is a loop of pairs.  Either way no whole
+    frame is copied, and — unlike ``pallas2``'s calls in line — the
+    temporaries stay where the loop's are: over every count from 1 to 23
+    steps, walled and periodic, leg and ``multistep``, 2.262–2.268 sets of
+    fields (the parent's 2.262–2.272 with its copies)."""
+    leg = compile_leg("wide2", steps, periodic_x=periodic_x,
+                      multistep=multistep)
+    text = leg.as_text()
+    bodies, outside = _split_at_loops(text)
+    assert len(bodies) == loops
+    for body in bodies:
+        assert len(_kernel_calls(body, 2, "sw_wide")) == 2
+    assert len(_kernel_calls(outside, 2, "sw_wide")) == in_line
+    assert not _frame_copies(text.splitlines())
+    temp = leg.memory_analysis().temp_size_in_bytes
+    assert temp < 2.4 * SIX_FIELDS, (temp, SIX_FIELDS)
