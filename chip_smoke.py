@@ -13,8 +13,8 @@ fails or a fallback taken makes the exit code non-zero:
   HLO searched for the collective ops and every input and output checked to
   be laid out over all devices.
 - **B — the flagship**: ``examples/shallow_water.py`` at benchmark width
-  (3600 x 1800) through ``solve_fused(fast="auto", pinned=True)``, the path
-  ``bench.py`` times: the Mosaic-compiled Pallas kernel (``pallas2`` on one
+  (3600 x 1800) through ``solve_fused(fast="auto", pinned=True)``, the
+  region the benchmark times: the Mosaic-compiled Pallas kernel (``pallas2`` on one
   device, the ``(2, 2)`` wide-halo ``wide2`` on four), the pinned artifact,
   the final state against the plain ``jnp`` step on the same devices.  On
   one device the closed basin (``periodic_x=False``) follows at the same

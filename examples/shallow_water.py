@@ -378,9 +378,9 @@ def model_step_fast(state: State, cfg: Config, comm: mpx.Comm,
 
     Why ``model_step`` is slow on TPU: every derived field is built as
     ``zeros_like(x).at[inner].set(expr)`` (a misaligned interior
-    dynamic-update-slice — measured ~3.7x slower than an aligned
-    full-field op on v5e) and is halo-exchanged (13 exchange rounds per
-    step), splitting the step into ~13 tiny fusion regions.
+    dynamic-update-slice where an aligned full-field op would do) and is
+    halo-exchanged (13 exchange rounds per step), splitting the step into
+    ~13 tiny fusion regions.
 
     This version exploits an algebraic fact: with *coherent halos* on the
     inputs (each halo cell holds exactly its neighbor's current interior
@@ -560,8 +560,8 @@ def _margin_rows(nsteps: int) -> int:
     ``_PBLK`` (the block-margin index maps need ``mrg | _PBLK``).  The
     single source of this invariant for both the whole-step chunk kernels
     and the wide-halo path."""
-    if not 1 <= nsteps <= 3:  # deeper fusion exceeds VMEM/compiler
-        raise ValueError(f"fused step windows support 1..3 steps, got {nsteps}")
+    if not 1 <= nsteps <= 2:  # deeper fusion exceeds VMEM/compiler
+        raise ValueError(f"fused step windows support 1..2 steps, got {nsteps}")
     m = 8 * nsteps
     while _PBLK % m:
         m += 8
@@ -600,7 +600,7 @@ def _window_masks(cfg: Config, iy, ix, giy, gix, wide=False):
     would leave untouched; ``u_wall``/``wall_v`` are the no-flow wall
     masks; ``interior`` is the update mask.
 
-    ``wide`` selects the wide-halo frame (``model_step_pallas_wide``):
+    ``wide`` selects the wide-halo frame (``_wide_run``):
     there every cell is computed exactly as its *owning* rank computes it,
     so the update mask tests DOMAIN-GLOBAL interiority (a seam cell is
     some rank's interior and is updated in place — the recomputed value is
@@ -914,7 +914,7 @@ def model_step_pallas(state: State, cfg: Config, comm: mpx.Comm,
     Equality with
     the jnp step is pinned by
     tests/test_examples.py::test_pallas_step_matches_fast_step and
-    ::test_pallas_pair_step_matches_fast_steps (interpret mode on CPU,
+    ::test_pallas_chunk_step_matches_fast_steps (interpret mode on CPU,
     compiled on TPU).
 
     ``interpret=None`` resolves at trace time to "the comm's mesh is not
@@ -930,8 +930,8 @@ def model_step_pallas(state: State, cfg: Config, comm: mpx.Comm,
     # one sublane tile of validity per fused step, rounded up to a divisor
     # of _PBLK — the prev/next margin index maps address mrg-row blocks as
     # i * (_PBLK // mrg), which only lands on block starts when mrg
-    # divides _PBLK (nsteps=3: 24 -> 32); nsteps=4 exceeds the chip's
-    # VMEM/compiler limits at benchmark width (checked in _margin_rows)
+    # divides _PBLK; more than two steps exceed the chip's VMEM/compiler
+    # limits at benchmark width (checked in _margin_rows)
     mrg = _margin_rows(nsteps)
     import jax.experimental.pallas as pl
 
@@ -992,24 +992,10 @@ def model_step2_pallas(state: State, cfg: Config, comm: mpx.Comm,
                        first_step: bool, interpret=None) -> State:
     """TWO model steps in one Pallas kernel call (``model_step_pallas``
     with ``nsteps=2``): halves the per-step HBM traffic and the grid
-    dispatch count.  Amortized (dispatch-constant-cancelled) measurement:
-    992 -> 870 µs/step over the single-step kernel."""
+    dispatch count.  The chunk kernel of ``"pallas2"``: what it reaches on
+    the chip is in PERF.md (``sw_steps_x2``)."""
     return model_step_pallas(state, cfg, comm, first_step,
                              interpret=interpret, nsteps=2)
-
-
-def model_step3_pallas(state: State, cfg: Config, comm: mpx.Comm,
-                       first_step: bool, interpret=None) -> State:
-    """THREE model steps per kernel call.  NOT the shipped depth: the
-    margin must divide ``_PBLK`` so three steps need 32 margin rows (not
-    24), and the measured margin-recompute overhead (192-row windows per
-    128 stored rows) outweighs the HBM saving — narrower blocks
-    (96 + 2·24) measured 859 µs/step vs the pair kernel's 870, within
-    noise, and the 192-row window fails to compile at benchmark width.
-    Kept as an explicit mode because the depth generalization is tested
-    and useful at smaller widths; ``"auto"`` ships the pair."""
-    return model_step_pallas(state, cfg, comm, first_step,
-                             interpret=interpret, nsteps=3)
 
 
 # ---------------------------------------------------------------------------
@@ -1183,9 +1169,9 @@ def _strip_exch(payload, route, c, token):
 
 
 def _wide_exchange(fields, cfg: Config, comm: mpx.Comm, m: int, token):
-    """Build the widened frame for ``model_step_pallas_wide``: every side
-    gains ``m - 1`` rows/cols of neighbor data beyond the existing 1-cell
-    halo, so ``nsteps`` whole model steps can be recomputed locally with no
+    """Build the widened frame for ``_wide_run``: every side gains
+    ``m - 1`` rows/cols of neighbor data beyond the existing 1-cell halo,
+    so ``nsteps`` whole model steps can be recomputed locally with no
     further exchange (a communication-avoiding halo exchange).
 
     Exchanges ``m``-deep strips of all six fields, batched as ONE
@@ -1308,50 +1294,6 @@ def _sw_wide_kernel(cfg: Config, first_step: bool, mrg: int, nsteps: int,
         o[:] = f[sl]
 
 
-def model_step_pallas_wide(state: State, cfg: Config, comm: mpx.Comm,
-                           first_step: bool, interpret=None,
-                           nsteps: int = 2) -> State:
-    """``nsteps`` whole model steps on ANY mesh as ONE fused Pallas kernel
-    between communication-avoiding wide halo exchanges.
-
-    Where ``model_step_pallas_halo`` splices a real 1-cell exchange
-    between the two phase kernels of every step (5 exchange rounds and two
-    state HBM round-trips per step), this path exchanges ``8 * nsteps``
-    -deep strips of all six fields ONCE (4 batched messages), then runs
-    the whole multi-step chain in VMEM: every halo value a step would have
-    received is instead *recomputed locally* from the widened margins —
-    bit-identical to the exchange, because the seam cell is computed by
-    the identical expression tree on the identical operand values its
-    owning rank uses (``_window_masks(wide=True)``).  The cropped result
-    therefore equals ``model_step_fast`` exactly, which
-    tests/test_examples.py pins on (1,1) and (2,4) meshes in both
-    boundary modes.
-
-    This brings the single-rank pair kernel's economics (state reads HBM
-    once per ``nsteps``, all intermediates in VMEM) to multi-rank meshes:
-    the reference's scaling story (ref docs/shallow-water.rst:56-94) with
-    the fused-kernel per-chip speed.  Requires a local interior of at
-    least ``8 * nsteps`` cells per dimension (strips must come from the
-    immediate neighbor only); ``select_steps("auto")`` falls back to the
-    split-phase path below that.
-    """
-    m = _margin_rows(nsteps)
-    if cfg.ny_local - 2 < m or cfg.nx_local - 2 < m:
-        # ValueError, not assert: user-facing eligibility that must
-        # survive `python -O` (an undersized interior would silently
-        # exchange out-of-range strips)
-        raise ValueError(
-            "model_step_pallas_wide: local interior must be >= the exchange "
-            f"depth ({m}) in both dimensions; use model_step_pallas_halo"
-        )
-    if interpret is None:
-        interpret = _resolve_interpret(comm)
-    token = mpx.create_token()
-    wfields, token = _wide_exchange(tuple(state), cfg, comm, m, token)
-    outs = _wide_kernel_call(wfields, cfg, first_step, nsteps, m, interpret)
-    return _wide_crop(outs, cfg, m)
-
-
 def _wide_kernel_call(wfields, cfg: Config, first_step: bool, nsteps: int,
                       m: int, interpret: bool):
     """``nsteps`` step windows on the widened frame: the compiled blocked
@@ -1416,14 +1358,13 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
     multi-step kernel calls.
 
     After a kernel call the local frame (crop region, halo ring included)
-    is valid but the ``m - 1``-deep margins are recompute garbage.  The
-    carried-frame driver (``solve_fused`` wide modes) therefore never
-    crops between calls: it exchanges just the margin bands — four
-    messages of ``(6, ·, m-1)`` — and writes them over the margins with
-    ``.at[].set``, which XLA does in place (a ``dynamic-update-slice`` of
-    the band alone, the interior untouched), so the full-array concat/crop
-    copies of ``model_step_pallas_wide`` happen once per RUN instead of
-    once per pair of steps.
+    is valid but the ``m - 1``-deep margins are recompute garbage.
+    ``_wide_run`` therefore never crops between calls: it exchanges just
+    the margin bands — four messages of ``(6, ·, m-1)`` — and writes them
+    over the margins with ``.at[].set``, which XLA does in place (a
+    ``dynamic-update-slice`` of the band alone, the interior untouched),
+    so the full-array concat/crop copies of building and cropping the
+    frame happen once per RUN instead of once per pair of steps.
 
     Two-phase for corners: x bands first (their corner rows are the
     sender's own garbage y-margins), then y bands at full widened width —
@@ -1487,13 +1428,29 @@ def _wide_schedule(num_steps: int, chunk_size: int, euler_first: bool):
 def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
               chunk_size: int, m: int, interpret: bool,
               euler_first: bool) -> State:
-    """Advance ``num_steps`` model steps on the CARRIED widened frame:
-    build the frame once (``_wide_exchange``), run ``chunk_size``-step
-    kernel calls with only a margin-band refresh between them
-    (``_wide_refresh``), crop once at the end.  ``euler_first`` makes the
-    first advanced step the forward-Euler one (a 1-step kernel call).
-    This is the hot path behind every wide-mode driver (``make_stepper``
-    and ``fused_runner``).
+    """Advance ``num_steps`` model steps on ANY mesh on the CARRIED
+    widened frame: build the frame once (``_wide_exchange``), run
+    ``chunk_size``-step kernel calls with only a margin-band refresh
+    between them (``_wide_refresh``), crop once at the end.
+    ``euler_first`` makes the first advanced step the forward-Euler one (a
+    1-step kernel call).  This is the one wide-halo path: every driver
+    (``make_stepper``, ``fused_runner``) and the standalone steps
+    ``model_step_wide`` / ``model_step2_wide`` go through it.
+
+    Where ``model_step_pallas_halo`` splices a real 1-cell exchange
+    between the two phase kernels of every step (5 exchange rounds and two
+    state HBM round-trips per step), here every halo value a step would
+    have received is *recomputed locally* from the widened margins,
+    bit-identical to the exchange (``_window_masks(wide=True)``), so the
+    cropped result equals ``model_step_fast``, which tests/test_examples.py
+    pins on (1,1), (2,2) and (2,4) meshes in both boundary modes.  This
+    brings the single-rank pair kernel's economics (state reads HBM once
+    per ``chunk_size`` steps, all intermediates in VMEM) to multi-rank
+    meshes: the reference's scaling story (ref
+    docs/shallow-water.rst:56-94) with the fused-kernel per-chip speed.
+    Requires a local interior of at least ``m`` cells per dimension (strips
+    must come from the immediate neighbor only); ``select_steps("auto")``
+    falls back to the split-phase path below that.
 
     The chunk loop advances two refresh-and-call rounds per iteration (an
     odd count's last round follows the loop), as ``_run_steps``' does and
@@ -1505,6 +1462,9 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
     no frame is copied, on one chip or on a mesh
     (tests/test_solver_loop_hlo.py)."""
     if cfg.ny_local - 2 < m or cfg.nx_local - 2 < m:
+        # ValueError, not assert: user-facing eligibility that must
+        # survive `python -O` (an undersized interior would silently
+        # exchange out-of-range strips)
         raise ValueError(
             "wide-halo path: local interior must be >= the exchange depth "
             f"({m}) in both dimensions; use model_step_pallas_halo"
@@ -1535,17 +1495,20 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
 
 
 def model_step_wide(state: State, cfg: Config, comm: mpx.Comm,
-                    first_step: bool, interpret=None) -> State:
-    """One model step via the wide-halo kernel (``nsteps=1``)."""
-    return model_step_pallas_wide(state, cfg, comm, first_step,
-                                  interpret=interpret, nsteps=1)
+                    first_step: bool) -> State:
+    """One model step on the wide-halo path, standalone: ``_wide_run`` for
+    one step at its own exchange depth (8) — frame, one kernel call, crop."""
+    return _wide_run(state, 1, cfg, comm, 1, _margin_rows(1),
+                     _resolve_interpret(comm), euler_first=first_step)
 
 
 def model_step2_wide(state: State, cfg: Config, comm: mpx.Comm,
-                     first_step: bool, interpret=None) -> State:
-    """TWO model steps per wide-halo kernel call + exchange round."""
-    return model_step_pallas_wide(state, cfg, comm, first_step,
-                                  interpret=interpret, nsteps=2)
+                     first_step: bool) -> State:
+    """TWO model steps on the wide-halo path, standalone: ``_wide_run``
+    for one two-step chunk (exchange depth 16); as a first step, the Euler
+    call and a one-step call."""
+    return _wide_run(state, 2, cfg, comm, 2, _margin_rows(2),
+                     _resolve_interpret(comm), euler_first=first_step)
 
 
 def _pltpu_roll():
@@ -1554,69 +1517,112 @@ def _pltpu_roll():
     return pltpu.roll
 
 
-def select_step(fast, cfg: Config = None):
-    """The model-step implementation behind ``fast``: the single source of
-    truth for every driver (make_stepper, solve_fused, bench.py).
-
-    ``fast`` is one of:
-
-    - ``False`` — the reference-structured step (parity oracle);
-    - ``True`` — ``model_step_fast`` (works on any mesh);
-    - ``"pallas"`` / ``"pallas2"`` / ``"pallas3"`` — the fused whole-step
-      Pallas kernel (single-rank periodic-x only; raises otherwise);
-      ``"pallas2"``/``"pallas3"`` additionally fuse 2/3 steps per kernel
-      call (see ``select_steps``);
-    - ``"pallas_halo"`` — the split-phase Pallas kernels with real halo
-      exchanges between them (any mesh, ``model_step_pallas_halo``);
-    - ``"wide"`` / ``"wide2"`` — the communication-avoiding wide-halo
-      kernel (any mesh with local interior >= 8/16 cells per dimension,
-      ``model_step_pallas_wide``); ``"wide2"`` fuses 2 steps per exchange;
-    - ``"auto"`` — ``"pallas2"`` when ``cfg`` is a single-rank periodic-x
-      decomposition (the benchmark configuration); else ``"wide2"`` when
-      the local interior fits its exchange depth; else ``"pallas_halo"``.
-
-    Returns the SINGLE-step callable; drivers that can batch steps use
-    ``select_steps`` to also obtain the multi-step chunk kernel.
-    """
-    return select_steps(fast, cfg)[0]
-
-
-def select_steps(fast, cfg: Config = None):
-    """``(single_step, chunk_step_or_None, chunk_size)`` behind ``fast``
-    (see ``select_step`` for the mode table).  ``chunk_step`` advances
-    ``chunk_size`` model steps per call and is only offered for the fused
-    Pallas chunk modes; callers use it for whole chunks and fall back to
-    ``single_step`` for the first (Euler) step and remainders."""
+def _resolve_mode(fast, cfg: Config = None):
+    """``fast`` as one of the five concrete modes of ``select_steps``:
+    ``"auto"`` decided from ``cfg``, an unknown name refused."""
     if fast == "auto":
         if cfg is None:
             raise ValueError(
-                "select_step('auto') needs the Config to decide kernel "
+                "select_steps('auto') needs the Config to decide kernel "
                 "eligibility — pass cfg"
             )
         # whole-step kernel where eligible (no exchanges at all); the
         # wide-halo pair kernel everywhere else (multi-rank meshes, walls)
         # unless the local interior is smaller than its exchange depth.
-        # Pair depth: deeper fusion measured no better (see
-        # model_step3_pallas) and fails to compile at benchmark width.
+        # Pair depth: three steps a call do not compile at benchmark width.
         if cfg.nproc == 1 and cfg.periodic_x:
-            fast = "pallas2"
-        elif min(cfg.ny_local, cfg.nx_local) - 2 >= _margin_rows(2):
-            fast = "wide2"
-        else:
-            fast = "pallas_halo"
-    if fast == "wide2":
+            return "pallas2"
+        if min(cfg.ny_local, cfg.nx_local) - 2 >= _margin_rows(2):
+            return "wide2"
+        return "pallas_halo"
+    if isinstance(fast, str) and fast not in ("pallas2", "wide2",
+                                              "pallas_halo"):
+        raise ValueError(
+            f"unknown step mode {fast!r}: one of False, True, 'pallas2', "
+            "'wide2', 'pallas_halo', 'auto'"
+        )
+    return fast
+
+
+def select_steps(fast, cfg: Config = None):
+    """``(single_step, chunk_step_or_None, chunk_size)`` behind ``fast``:
+    the single source of truth for every driver (``make_stepper``,
+    ``fused_runner``, the benchmark's).  ``chunk_step`` advances
+    ``chunk_size`` model steps per call and is only offered for the fused
+    Pallas chunk modes; callers use it for whole chunks and fall back to
+    ``single_step`` for the first (Euler) step and remainders.
+
+    ``fast`` is one of (anything else raises ``ValueError``):
+
+    - ``False`` — the reference-structured step (parity oracle);
+    - ``True`` — ``model_step_fast`` (works on any mesh);
+    - ``"pallas2"`` — the fused whole-step Pallas kernel, two steps per
+      kernel call (single-rank periodic-x only; raises otherwise);
+    - ``"wide2"`` — the communication-avoiding wide-halo kernel, two steps
+      per exchange (any mesh with a local interior of 16 cells or more per
+      dimension, ``_wide_run``);
+    - ``"pallas_halo"`` — the split-phase Pallas kernels with real halo
+      exchanges between them (any mesh, ``model_step_pallas_halo``);
+    - ``"auto"`` — ``"pallas2"`` when ``cfg`` is a single-rank periodic-x
+      decomposition (the benchmark configuration); else ``"wide2"`` when
+      the local interior fits its exchange depth; else ``"pallas_halo"``.
+    """
+    mode = _resolve_mode(fast, cfg)
+    if mode == "wide2":
         return model_step_wide, model_step2_wide, 2
-    if fast == "wide":
-        return model_step_wide, None, 1
-    if fast == "pallas3":
-        return model_step_pallas, model_step3_pallas, 3
-    if fast == "pallas2":
+    if mode == "pallas2":
         return model_step_pallas, model_step2_pallas, 2
-    if fast == "pallas":
-        return model_step_pallas, None, 1
-    if fast == "pallas_halo":
+    if mode == "pallas_halo":
         return model_step_pallas_halo, None, 1
-    return (model_step_fast if fast else model_step), None, 1
+    return (model_step_fast if mode else model_step), None, 1
+
+
+def _advancer(cfg: Config, comm: mpx.Comm, fast):
+    """``(advance, schedule, chunk_size)`` behind ``fast``: the one place
+    that knows the wide-halo mode from the others.
+
+    ``advance(state, num_steps, euler_first=...)`` traces ``num_steps``
+    model steps inside a region, the first of them the forward-Euler step
+    where ``euler_first``.  In ``"wide2"`` it is ``_wide_run`` on the
+    carried widened frame (a margin-band refresh between kernel calls
+    instead of a crop and a re-widening per call); otherwise the step
+    function for the Euler step and ``_run_steps`` for the rest.
+    ``schedule(num_steps)`` is what a leg of ``num_steps`` steps (the Euler
+    step first) is made of after its Euler call, by the schedule
+    ``advance`` itself follows: ``(chunk calls, single-step calls, band
+    refreshes, wide)``.  ``leg_plan`` has no mesh and passes ``comm=None``:
+    it takes the schedule and never calls ``advance``."""
+    mode = _resolve_mode(fast, cfg)
+    step, chunk, chunk_size = select_steps(mode, cfg)
+
+    if mode == "wide2":
+        # a partial, not a nested function: it binds the arguments without
+        # a Python frame of its own between the region and ``_wide_run``.
+        # With a nested function here the trace of the walled 71-step leg
+        # took 0.6-0.8 s longer in the benchmark's process on the chip's
+        # host (PERF.md section 6, PR 30): the same program, a tenth more
+        # of its ``setup_s``.
+        advance = partial(
+            _wide_run, cfg=cfg, comm=comm, chunk_size=chunk_size,
+            m=_margin_rows(chunk_size),
+            interpret=comm is not None and _resolve_interpret(comm))
+
+        def schedule(num_steps):
+            head, trips, rem = _wide_schedule(num_steps, chunk_size, True)
+            return head + trips, rem, trips + rem, True
+
+    else:
+        def advance(state, num_steps, euler_first):
+            if euler_first:
+                state = step(state, cfg, comm, first_step=True)
+            return _run_steps(state, num_steps - int(euler_first), cfg,
+                              comm, step, chunk, chunk_size)
+
+        def schedule(num_steps):
+            nchunks, rem = _steps_schedule(num_steps - 1, chunk, chunk_size)
+            return nchunks, rem, 0, False
+
+    return advance, schedule, chunk_size
 
 
 def make_stepper(cfg: Config, comm: mpx.Comm, *, fast=True):
@@ -1626,41 +1632,21 @@ def make_stepper(cfg: Config, comm: mpx.Comm, *, fast=True):
 
     ``fast`` selects the TPU-restructured step (``model_step_fast``,
     default); ``fast=False`` keeps the reference-structured step;
-    ``"pallas"``/``"pallas2"``/``"pallas3"``/``"auto"`` select the fused
-    whole-step kernel (see ``select_steps``) — all verified equal in
+    ``"pallas2"``/``"wide2"``/``"pallas_halo"``/``"auto"`` select the
+    Pallas kernels (see ``select_steps``) — all verified equal in
     tests/test_examples.py.  ``multistep`` advances exactly ``num_steps``
     steps in every mode (the chunk kernel handles whole chunks; the
     remainder falls back to single-step calls).
     """
-    step, chunk, chunk_size = select_steps(fast, cfg)
-
-    if step is model_step_wide:
-        # wide modes run on the carried widened frame (margin-band refresh
-        # between kernel calls instead of crop + re-widen per call)
-        m = _margin_rows(chunk_size)
-        interpret = _resolve_interpret(comm)
-
-        @partial(mpx.spmd, comm=comm)
-        def first_step(state: State) -> State:
-            return _wide_run(state, 1, cfg, comm, chunk_size, m, interpret,
-                             euler_first=True)
-
-        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
-        def multistep(state: State, num_steps: int) -> State:
-            return _wide_run(state, num_steps, cfg, comm, chunk_size, m,
-                             interpret, euler_first=False)
-
-        return first_step, multistep
+    advance, _, _ = _advancer(cfg, comm, fast)
 
     @partial(mpx.spmd, comm=comm)
     def first_step(state: State) -> State:
-        return step(state, cfg, comm, first_step=True)
+        return advance(state, 1, euler_first=True)
 
     @partial(mpx.spmd, comm=comm, static_argnums=(1,))
     def multistep(state: State, num_steps: int) -> State:
-        state = _run_steps(state, num_steps, cfg, comm, step, chunk,
-                           chunk_size)
-        return state
+        return advance(state, num_steps, euler_first=False)
 
     return first_step, multistep
 
@@ -1693,6 +1679,8 @@ def _run_steps(state: State, num_steps: int, cfg, comm, step, chunk,
             )
         for _ in range(rem):
             state = step(state, cfg, comm, False)
+        return state
+    if not rem:  # fori_loop(0, 0) would still trace the step
         return state
     return jax.lax.fori_loop(
         0, rem, lambda _, s: step(s, cfg, comm, False), state
@@ -1757,30 +1745,18 @@ def fused_runner(cfg: Config, comm: mpx.Comm, fast="auto"):
 
     ``fused(state, total)`` is an ``mpx.spmd`` function (``total`` static)
     that advances the forward-Euler first step and ``total`` Adams-Bashforth
-    steps in one program — a *leg* of ``total + 1`` steps.  Where
-    ``select_steps(fast, cfg)`` gives a wide mode it is ``_wide_run`` on the
-    carried widened frame (the frame built once, a margin-band refresh and
-    one kernel call per chunk, one crop at the end); otherwise the first
-    step and ``_run_steps``.  ``chunk_size`` is the number of steps one call
-    of the loop's kernel advances.  Pin it with ``mpx.compile(fused, state,
+    steps in one program — a *leg* of ``total + 1`` steps.  In
+    ``"wide2"`` (``_advancer`` decides) it is ``_wide_run`` on the carried
+    widened frame (the frame built once, a margin-band refresh and one
+    kernel call per chunk, one crop at the end); otherwise the first step
+    and ``_run_steps``.  ``chunk_size`` is the number of steps one call of
+    the loop's kernel advances.  Pin it with ``mpx.compile(fused, state,
     total)``; ``leg_plan`` says what the leg is made of."""
-    step, chunk, chunk_size = select_steps(fast, cfg)
+    advance, _, chunk_size = _advancer(cfg, comm, fast)
 
-    if step is model_step_wide:
-        m = _margin_rows(chunk_size)
-        interpret = _resolve_interpret(comm)
-
-        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
-        def fused(state: State, total: int) -> State:
-            return _wide_run(state, total + 1, cfg, comm, chunk_size, m,
-                             interpret, euler_first=True)
-
-    else:
-        @partial(mpx.spmd, comm=comm, static_argnums=(1,))
-        def fused(state: State, total: int) -> State:
-            state = step(state, cfg, comm, first_step=True)
-            return _run_steps(state, total, cfg, comm, step, chunk,
-                              chunk_size)
+    @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+    def fused(state: State, total: int) -> State:
+        return advance(state, total + 1, euler_first=True)
 
     return fused, chunk_size
 
@@ -1788,27 +1764,21 @@ def fused_runner(cfg: Config, comm: mpx.Comm, fast="auto"):
 def leg_plan(cfg: Config, fast, steps: int) -> dict:
     """What one leg of ``steps`` model steps (the Euler step included) of
     ``fused_runner(cfg, comm, fast)`` is made of, as counts per rank, by
-    the schedule the leg itself is built from (``_wide_schedule`` for the
-    wide modes, ``_steps_schedule`` otherwise):
+    the schedule the leg itself is built from (``_advancer``'s:
+    ``_wide_schedule`` in ``"wide2"``, ``_steps_schedule`` otherwise):
 
     - ``euler_calls``: calls of the first-step kernel (or step function);
     - ``chunk_calls``: calls of the ``steps_per_kernel_call``-step kernel;
     - ``single_step_calls``: one-step calls for what the chunks leave over
       (every step after the first where the mode has no chunk kernel);
     - ``frames_built``, ``band_refreshes``, ``crops``: the widened frame's
-      life in the wide modes (``_wide_exchange`` once, ``_wide_refresh``
+      life in ``"wide2"`` (``_wide_exchange`` once, ``_wide_refresh``
       before every call after the Euler one, ``_wide_crop`` once); 0
       elsewhere."""
     if steps < 1:
         raise ValueError(f"a leg has at least its Euler step, got {steps}")
-    step, chunk, chunk_size = select_steps(fast, cfg)
-    wide = step is model_step_wide
-    if wide:
-        head, trips, rem = _wide_schedule(steps, chunk_size, True)
-        nchunks, refreshes = head + trips, trips + rem
-    else:
-        nchunks, rem = _steps_schedule(steps - 1, chunk, chunk_size)
-        refreshes = 0
+    _, schedule, chunk_size = _advancer(cfg, None, fast)
+    nchunks, rem, refreshes, wide = schedule(steps)
     return {"steps": steps, "steps_per_kernel_call": chunk_size,
             "euler_calls": 1, "chunk_calls": nchunks,
             "single_step_calls": rem, "frames_built": int(wide),
@@ -1817,7 +1787,7 @@ def leg_plan(cfg: Config, fast, steps: int) -> dict:
 
 def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
                 devices=None, fast=True, return_state=False,
-                pinned: bool = False, unroll: int = 0):
+                pinned: bool = False):
     """Benchmark-mode solve: the ENTIRE simulation is one XLA program
     (first Euler step + a ``fori_loop`` over all remaining steps), so the
     host dispatches once instead of once per multistep.  Runs the same
@@ -1826,21 +1796,12 @@ def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
     ref examples/shallow_water.py:449-450), plus the final stacked state
     when ``return_state`` is set (equality tests).
 
-    The wide-halo modes get a dedicated fused program that carries the
-    state in WIDENED form across the whole run: the widened frame is built
-    once, each pair of steps exchanges only the thin margin bands
-    (``_wide_refresh``) before its kernel call, and the crop back to the
-    local layout happens once at the end — per pair this costs four
-    band messages and zero full-array copies, where cropping and
-    re-widening every call costs two extra full-state HBM round-trips.
+    The program is ``fused_runner``'s (in ``"wide2"`` the state is
+    carried in WIDENED form across the whole run: ``_wide_run``).
 
     ``pinned=True`` runs the timed calls through an ``mpx.compile``-pinned
-    artifact of the whole-run program (docs/aot.md).  ``unroll=N`` (> 0)
-    switches to megastep mode: the run becomes ``ceil((n_steps - 1)/N)``
-    pinned megastep dispatches of N device-resident steps each
-    (``mpx.compile(..., unroll=N)``, docs/aot.md "Megastep execution") —
-    unroll implies pinning.  A pin or megastep build that fails raises:
-    the run never quietly continues on another program.
+    artifact of the whole-run program (docs/aot.md).  A pin that fails
+    raises: the run never quietly continues on another program.
 
     The state is committed to the mesh once and re-passed to the warm-up
     and the timed run; every wait is ``jax.block_until_ready`` on the
@@ -1853,37 +1814,7 @@ def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
 
     state = initial_state(cfg, comm)
     total = n_steps - 1
-    if unroll > 0:
-        # Megastep mode (docs/aot.md "Megastep execution"): instead of
-        # one whole-run program, the run is ceil((n_steps - 1)/unroll)
-        # pinned megastep dispatches of `unroll` device-resident steps
-        # each — the configuration that exposes per-dispatch host cost.
-        # The Euler first step runs through the whole-run program at
-        # total=0.
-        step, chunk, chunk_size = select_steps(fast, cfg)
-
-        def one_step(state: State) -> State:
-            if step is model_step_wide:
-                return _wide_run(state, 1, cfg, comm, chunk_size,
-                                 _margin_rows(chunk_size),
-                                 _resolve_interpret(comm), euler_first=False)
-            return _run_steps(state, 1, cfg, comm, step, chunk, chunk_size)
-
-        n_mega, tail = divmod(total, unroll)
-        mega = (mpx.compile(one_step, state, comm=comm, unroll=unroll)
-                if n_mega else None)
-        tail_pp = (mpx.compile(one_step, state, comm=comm, unroll=tail)
-                   if tail else None)
-
-        def runner(s):
-            s = fused(s, 0)
-            for _ in range(n_mega):
-                s = mega(s)
-            if tail_pp is not None:
-                s = tail_pp(s)
-            return s
-
-    elif pinned:
+    if pinned:
         # AOT-pin the whole-run program (docs/aot.md): the timed call
         # executes a compiled artifact with no per-call key work.  The
         # step-count static folds at pin time.

@@ -17,10 +17,9 @@ examples/long_context_attention.py and documented in docs/long_context.md:
   the permutes (XLA pipelines the unrolled steps).
   Causal runs compute only the visible blocks (fully-masked ring
   steps are skipped per rank via ``lax.cond``; fully-visible blocks skip
-  masking) — n(n+1)/2 blocks of MXU work instead of n², measured 2.10×
-  end-to-end on the 8-rank test mesh — and the diagonal block uses the
-  key-tile-skipping causal kernel (1.66× that block on TPU, see
-  kernels/flash_attention.py).
+  masking) — n(n+1)/2 blocks of MXU work instead of n² — and the diagonal
+  block uses the key-tile-skipping causal kernel (see
+  kernels/flash_attention.py; neither has a reading on the chip: PERF.md).
 - **Ulysses-style attention** (``alltoall`` head exchange; Jacobs et al.
   2023): two all-to-alls re-shard from sequence-parallel to head-parallel
   and back, with full-sequence local attention in between.

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives for this checkout.
 
 Every entry point that compiles for a device (``chip_smoke.py``,
-``bench.py``, ``examples/shallow_water.py``, ``examples/serving/serve.py``,
+``examples/shallow_water.py``, ``examples/serving/serve.py``,
 ``benchmarks/micro.py``) calls :func:`ensure_compile_cache` before its
 first compile, so a cold process pays XLA/Mosaic compilation once per
 program and every later process deserializes it.

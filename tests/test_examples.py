@@ -159,16 +159,13 @@ def _pallas_grid_cases():
 
 @pytest.mark.parametrize("mode,steps", [
     ("pallas2", (10, 11)),  # whole pairs; pair + odd single remainder
-    ("pallas3", (9, 11)),   # whole triples; triples + 2-single remainder
     ("pallas2", (70,)),     # 35 pairs: the loop's odd chunk count
-    ("pallas3", (21,)),     # 7 triples, likewise
 ])
 @pytest.mark.slow
 @pytest.mark.parametrize("ny,nx", _pallas_grid_cases())
 def test_pallas_chunk_step_matches_fast_steps(ny, nx, mode, steps):
-    """The chunk kernels (2 or 3 fused steps per call; margins of 8 rows
-    per fused step rounded up to a divisor of _PBLK — 16 for pairs, 32
-    for triples) must reproduce model_step_fast over runs that mix the
+    """The chunk kernel (2 fused steps per call; margins of 8 rows per
+    fused step: 16) must reproduce model_step_fast over runs that mix the
     single first step, whole chunk calls, and single-step remainders."""
     from shallow_water import make_mesh_and_comm, make_stepper
 
@@ -188,10 +185,10 @@ def test_pallas_chunk_step_matches_fast_steps(ny, nx, mode, steps):
             # rows, not block-boundary-concentrated): observed max 7.6e-6
             # (h, scale 1e2) / 2.2e-6 (v, scale 5e-2) after 11 steps
             # The rounding grows with the steps: over the four grids the
-            # largest gap (v) read 0.56 of this bound after 11 steps, 0.94
-            # (4.8e-6) after 21 and 1.85 (9.4e-6) after 70, so the longer
-            # runs get 1.5 x their own reading.
-            room = {21: 1.4, 70: 2.8}.get(nsteps, 1.0)
+            # largest gap (v) read 0.56 of this bound after 11 steps and
+            # 1.85 (9.4e-6) after 70, so the longer run gets 1.5 x its own
+            # reading.
+            room = {70: 2.8}.get(nsteps, 1.0)
             bound = (5e-6 + 1e-6 * np.abs(a).max()) * room
             assert np.abs(a - b).max() <= bound, (
                 f"field {name} diverged (ny={ny}, nx={nx}, nsteps={nsteps}): "
@@ -209,17 +206,31 @@ def test_pallas_step_matches_fast_step(ny, nx):
     the only divergence is fusion-order rounding (~1 ulp/step — observed
     max 1.1e-6 after 11 steps), far below the 1e-4 freshness band of the
     fast-vs-reference test."""
-    from shallow_water import make_mesh_and_comm, make_stepper
+    from functools import partial
+
+    import mpi4jax_tpu as mpx
+    from shallow_water import (
+        _run_steps,
+        make_mesh_and_comm,
+        make_stepper,
+        model_step_pallas,
+    )
 
     cfg = Config(nproc_y=1, nproc_x=1, nx=nx, ny=ny)
     devices = jax.devices()[:1]
     _, comm = make_mesh_and_comm(cfg, devices=devices)
     first_fast, multi_fast = make_stepper(cfg, comm, fast=True)
-    first_pal, multi_pal = make_stepper(cfg, comm, fast="pallas")
+
+    # the one-step kernel alone, step after step: in "pallas2" it runs the
+    # Euler step and an odd count's last step only
+    @partial(mpx.spmd, comm=comm)
+    def eleven_single_steps(state):
+        state = model_step_pallas(state, cfg, comm, first_step=True)
+        return _run_steps(state, 10, cfg, comm, model_step_pallas, None, 1)
 
     s0 = initial_state(cfg)
     fast = multi_fast(first_fast(s0), 10)
-    pal = multi_pal(first_pal(s0), 10)
+    pal = eleven_single_steps(s0)
     for name, a, b in zip(fast._fields, fast, pal):
         a, b = np.asarray(a), np.asarray(b)
         np.testing.assert_allclose(
@@ -269,7 +280,7 @@ def test_pallas_step_rejects_multirank_config():
     cfg = Config(nproc_y=2, nproc_x=4, nx=48, ny=24)
     _, comm = make_mesh_and_comm(cfg)
     with pytest.raises(ValueError, match="single-rank periodic-x"):
-        first, _ = make_stepper(cfg, comm, fast="pallas")
+        first, _ = make_stepper(cfg, comm, fast="pallas2")
         first(initial_state(cfg))
 
 
@@ -280,8 +291,11 @@ def test_select_step_auto_picks_kernel_by_mesh():
         model_step_pallas,
         model_step_pallas_halo,
         model_step_wide,
-        select_step,
+        select_steps,
     )
+
+    def select_step(fast, cfg):
+        return select_steps(fast, cfg)[0]
 
     # whole-step kernel only where every refresh is an in-register periodic
     # fix; the wide-halo kernel everywhere else, unless the local interior
@@ -297,6 +311,21 @@ def test_select_step_auto_picks_kernel_by_mesh():
     small_walls = replace(Config(nproc_y=1, nproc_x=1, nx=48, ny=12),
                           periodic_x=False)
     assert select_step("auto", small_walls) is model_step_pallas_halo
+
+
+@pytest.mark.parametrize("name", ["pallas3", "pallas", "wide", "fast"])
+def test_select_steps_refuses_a_name_that_is_no_mode(name):
+    """The modes are what ``auto`` can pick and the two references: a mode
+    that has gone, or any other name, is refused with the modes named, and
+    not read as ``True`` (``model_step_fast``) as an unknown string was."""
+    from shallow_water import make_mesh_and_comm, make_stepper, select_steps
+
+    cfg = Config(nproc_y=1, nproc_x=1, nx=48, ny=24)
+    with pytest.raises(ValueError, match="'pallas2', 'wide2'"):
+        select_steps(name, cfg)
+    _, comm = make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match=repr(name)):
+        make_stepper(cfg, comm, fast=name)
 
 
 @pytest.mark.slow

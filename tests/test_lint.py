@@ -29,8 +29,7 @@ SOURCES = sorted(
     for d in ("mpi4jax_tpu", "tests", "examples", "benchmarks")
     for p in (REPO / d).rglob("*.py")
     if "__pycache__" not in p.parts
-) + [REPO / "bench.py", REPO / "chip_smoke.py",
-     REPO / "__graft_entry__.py"]
+) + [REPO / "chip_smoke.py", REPO / "__graft_entry__.py"]
 
 
 def _imported_names(tree, src_lines):

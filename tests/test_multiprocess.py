@@ -132,8 +132,8 @@ WORKER = textwrap.dedent(
         nx=16 * (size // nproc_y), ny=16 * nproc_y,
     )
     _, comm_w = make_mesh_and_comm(cfg_w)
-    from shallow_water import model_step_wide, select_step
-    assert select_step("auto", cfg_w) is model_step_wide
+    from shallow_water import model_step_wide, select_steps
+    assert select_steps("auto", cfg_w)[0] is model_step_wide
     first_w, multi_w = make_stepper(cfg_w, comm_w, fast="auto")
     state_w = multi_w(first_w(initial_state(cfg_w)), 3)
     for s in state_w.h.addressable_shards:

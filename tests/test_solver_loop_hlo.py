@@ -135,7 +135,7 @@ def test_the_loop_of_the_compiled_leg_copies_no_field(compile_leg):
 
 @pytest.mark.parametrize("mode,steps,loops,in_line", [
     ("pallas2", 6, 0, 3),    # three chunks: a loop of one trip, inlined
-    ("pallas3", 17, 1, 1),   # five chunks (two pairs, one after) + 2 steps
+    ("pallas2", 11, 1, 1),   # five chunks (two pairs, one after) + 1 step
 ])
 def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
                                                 loops, in_line):
@@ -143,10 +143,10 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
     Up to three chunks the loop is one trip, which XLA inlines; kernel
     calls in line are given one spare set of fields or two by their count,
     where the parent's loop held one (402,782,208 B against 202,520,576 B
-    at six steps here).  The same happens to a three-step kernel's odd
-    chunk count with two steps after it: three calls in line behind the
-    loop.  Over every count from 1 to 23 steps it was never more than two
-    sets, and the pair kernel from four chunks on always holds one."""
+    at six steps here).  The same happens to an odd chunk count of five
+    or more with a step after it: the odd chunk and the one-step call in
+    line behind the loop.  Over every count from 1 to 23 steps it was never
+    more than two sets."""
     leg = compile_leg(mode, steps)
     text = leg.as_text()
     bodies, entry = _split_at_loops(text)
@@ -154,6 +154,8 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
     for body in bodies:
         assert len(_kernel_calls(body, int(mode[-1]))) == 2
     assert len(_kernel_calls(entry, int(mode[-1]))) == in_line
+    # the steps the chunks leave over, each a one-step call in line
+    assert len(_kernel_calls(entry, 1)) == steps % int(mode[-1])
     assert not _field_copies(text.splitlines())
     temp = leg.memory_analysis().temp_size_in_bytes
     assert temp < 2.1 * SIX_FIELDS, (temp, SIX_FIELDS)

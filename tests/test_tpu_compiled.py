@@ -23,8 +23,8 @@ needs it, and none writes a record file.
 
 Which devices each test means is said in the test.  The kernel tests
 build a ONE-device mesh on the first chip (they check a kernel against
-the jnp step, not how work spreads); ``test_bench_on_chip`` and
-``test_butterfly_rounds_on_multi_device_chip`` use every chip the host
+the jnp step, not how work spreads);
+``test_butterfly_rounds_on_multi_device_chip`` uses every chip the host
 has.  ``chip_smoke.py`` is the check that work is spread over all chips.
 
 Each test compares a compiled kernel path against the fast jnp step on
@@ -92,10 +92,10 @@ def _assert_fields_close(a, b, what):
 def test_whole_step_pair_kernel_compiled():
     """The benchmark path: the fused whole-step pair kernel, Mosaic-
     compiled (multi-block grid: ny_local = 2 x _PBLK)."""
-    from shallow_water import Config, model_step_pallas, select_step
+    from shallow_water import Config, model_step_pallas, select_steps
 
     cfg = Config(nproc_y=1, nproc_x=1, nx=512, ny=254)
-    assert select_step("auto", cfg) is model_step_pallas
+    assert select_steps("auto", cfg)[0] is model_step_pallas
     _assert_fields_close(
         _run(cfg, "pallas2", 7), _run(cfg, True, 7), "pallas2"
     )
@@ -107,10 +107,10 @@ def test_wide_halo_kernel_compiled():
     config, which 'auto' routes to the wide path.  (The same kernel with
     real halo permutes between chips is chip_smoke.py stage B on a
     four-chip host.)"""
-    from shallow_water import Config, model_step_wide, select_step
+    from shallow_water import Config, model_step_wide, select_steps
 
     cfg = Config(nproc_y=1, nproc_x=1, nx=512, ny=254, periodic_x=False)
-    assert select_step("auto", cfg) is model_step_wide
+    assert select_steps("auto", cfg)[0] is model_step_wide
     _assert_fields_close(_run(cfg, "auto", 7), _run(cfg, True, 7), "wide")
 
 
@@ -326,31 +326,6 @@ def test_profile_ops_on_chip(tmp_path):
     with mpx.profile_ops(logdir):
         step(x)
     assert glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True), logdir
-
-
-def test_bench_on_chip(capsys):
-    """bench.py (the benchmark entry point) on EVERY chip of the host,
-    called in this process — a chip belongs to one process, so a child
-    started from here could not have it.  It must print its one-line JSON
-    with a positive rate, name the device it ran on, and have run the
-    pinned artifact."""
-    import json
-
-    import bench
-
-    bench.main([])
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("{")][-1]
-    res = json.loads(line)
-    assert res["unit"] == "steps/s/chip"
-    assert res["value"] > 0
-    assert res["device"] == {
-        "platform": "tpu",
-        "kind": jax.devices()[0].device_kind,
-        "count": jax.device_count(),
-    }
-    # the warm-up and the timed run both went through the one pin
-    assert res["pins"] >= 1 and res["pinned_calls"] >= 2, res
 
 
 def test_flash_attention_backward_compiled():
