@@ -67,6 +67,9 @@ class CollectiveEvent:
     # exact lowerings (docs/compression.md) — prices the inter-host leg
     # at wire bytes in the cost model and gates the MPX138 advisory
     codec: Optional[str] = None
+    # view of the rank-local block a gather-family op handed to the
+    # AllGather HLO (ops/_base.all_gather_blocks): "lanes" | "block"
+    view: Optional[str] = None
     # communication epoch the comm was built in (parallel/comm.py stamp;
     # resilience/elastic.py revocation) — compared against the CURRENT
     # epoch in graph.meta by the MPX126 checker

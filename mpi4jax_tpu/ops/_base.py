@@ -614,14 +614,56 @@ def clear_caches() -> None:
     _aot_reset()
 
 
+# lanes of a TPU vector register: the minor extent of every tiled layout
+_LANES = 128
+
+
+def gather_view(xl) -> str:
+    """Which view of a rank-local block :func:`all_gather_blocks` hands
+    to the AllGather HLO: ``"lanes"`` for a 1-D block whose length is a
+    multiple of 128, ``"block"`` (the block as it is) for every other."""
+    lanes = xl.ndim == 1 and xl.size > 0 and xl.size % _LANES == 0
+    return "lanes" if lanes else "block"
+
+
+def all_gather_blocks(comm: Comm, xl):
+    """One AllGather HLO over the comm's full mesh axes: output
+    ``(size, *xl.shape)``, ranks in row-major order (axis tuples are
+    supported natively by the AllGather lowering).
+
+    The result of a 1-D block has the rank axis second-minor, which
+    XLA:TPU tiles onto the sublanes (``(4, N)`` as ``T(4,128)``): no
+    all-gather can write that, so the gather lands flat and a loop of
+    partial-tile ``dynamic-update-slice``s rebuilds the result block by
+    block.  Such a block is gathered as ``(N // 128, 128)`` instead —
+    the same bytes, the rank axis major and untiled, the gather
+    contiguous — and the closing reshape is left to XLA, which moves it
+    through an elementwise or reducing consumer (one that needs the
+    ``(size, N)`` array itself pays one relayout, as the plain line's
+    did).  Pure movement either way: values, JVP / transpose and ``vmap``
+    are ``lax.all_gather``'s.
+    The view taken is recorded on the open analysis event and telemetry
+    record (``view``; meter ``view.<op>.<view>``)."""
+    view = gather_view(xl)
+    _analysis.annotate(view=view)
+    _telemetry.annotate(view=view)
+    if view == "lanes":
+        full = lax.all_gather(xl.reshape(-1, _LANES), comm.axes, axis=0,
+                              tiled=False)
+        return full.reshape(-1, *xl.shape)
+    return lax.all_gather(xl, comm.axes, axis=0, tiled=False)
+
+
 def group_select_gather(comm: Comm, xl):
-    """AllGather over the comm's FULL mesh axes, then select this rank's
-    group members in group order: output ``(group_size, *xl.shape)``.
+    """AllGather over the comm's FULL mesh axes (:func:`all_gather_blocks`:
+    a 1-D block of a multiple of 128 elements through its lane-shaped
+    view), then select this rank's group members in group order: output
+    ``(group_size, *xl.shape)``.
 
     The shared first step of every gather-family group lowering on a
     color-split comm (uniform group sizes only — ``my_group_members``
     raises the clear error otherwise)."""
-    full = lax.all_gather(xl, comm.axes, axis=0, tiled=False)
+    full = all_gather_blocks(comm, xl)
     return jnp.take(full, comm.my_group_members(), axis=0)
 
 

@@ -13,12 +13,11 @@ schedule, so there is no latency cost over a rooted gather.
 
 from typing import Optional
 
-from jax import lax
-
 from ..parallel.comm import Comm
 from ..utils.debug import log_op
 from ..utils.validation import enforce_types
-from ._base import dispatch, group_select_gather
+from ._base import (all_gather_blocks, dispatch, gather_view,
+                    group_select_gather)
 from .token import Token, consume, produce
 
 
@@ -43,15 +42,15 @@ def gather(x, root: int, *, comm: Optional[Comm] = None,
             )
         xl = consume(token, xl)
         log_op("MPI_Gather", comm.Get_rank(),
-               f"sending {xl.size} items to root {root}")
+               f"sending {xl.size} items to root {root} "
+               f"({gather_view(xl)} view)")
         if comm.groups is not None:
             # color split (uniform): same uniform-shape divergence as the
             # whole-axes form, selected per group
             res = group_select_gather(comm, xl)
         else:
-            # multi-axis comms gather in row-major rank order (axis tuples
-            # are supported natively by the AllGather lowering)
-            res = lax.all_gather(xl, comm.axes, axis=0, tiled=False)
+            # multi-axis comms gather in row-major rank order
+            res = all_gather_blocks(comm, xl)
         return res, produce(token, res)
 
     return dispatch("gather", comm, body, (x,), token, static_key=(root,),

@@ -302,7 +302,9 @@ def open_op(opname: str, comm, arrays) -> Optional[OpRecord]:
 
 def annotate(**fields) -> None:
     """Record trace-time facts only the op body knows — the selected
-    algorithm, and the modeled per-link-class wire bytes
+    algorithm, the view a gather-family op took of its block
+    (``ops/_base.all_gather_blocks``), and the modeled per-link-class
+    wire bytes
     (``link_bytes=(intra_host, inter_host)``, see
     ``ops/_hierarchy.annotate_selection``).  No-op when nothing is open
     (safe to call unconditionally from op bodies, mirroring
@@ -314,6 +316,9 @@ def annotate(**fields) -> None:
     if algo is not None:
         rec.algo = algo
         meter(f"algo.{rec.op}.{algo}")
+    view = fields.get("view")
+    if view is not None:
+        meter(f"view.{rec.op}.{view}")
     link = fields.get("link_bytes")
     if link is not None:
         rec.intra_bytes, rec.inter_bytes = link
