@@ -19,7 +19,9 @@ fails or a fallback taken makes the exit code non-zero:
   the final state against the plain ``jnp`` step on the same devices.  On
   one device the closed basin (``periodic_x=False``) follows at the same
   size: ``auto`` gives it ``wide2`` on the carried widened frame, the path
-  of every decomposed run, here without its permutes.
+  of every decomposed run, here without its permutes — once as that pinned
+  region, once through the host loop ``solve()`` runs (``run_multisteps``
+  over ``make_stepper``'s two un-pinned programs, a call a multistep).
 - **C — a server that answers a few requests**: ``mpx.serving.ServingEngine``
   with the ``bench`` preset of ``examples/serving/serve.py``, tensor-parallel
   over all devices, a dozen requests, continuous scheduler; every program
@@ -281,14 +283,18 @@ def stage_a(ctx):
 def stage_b(ctx):
     """The periodic domain on every device count; on one device also the
     closed basin, which ``auto`` sends down the wide-halo path that every
-    decomposed run takes."""
+    decomposed run takes: as one pinned program, then through the host
+    loop ``solve()`` runs (``run_multisteps``: a call a multistep, the
+    frame built and cropped in each)."""
     info = _flagship(ctx, periodic_x=True)
     if ctx["n"] == 1:
-        info = {"periodic": info, "walled": _flagship(ctx, periodic_x=False)}
+        info = {"periodic": info, "walled": _flagship(ctx, periodic_x=False),
+                "walled_host_loop": _flagship(ctx, periodic_x=False,
+                                              host_loop=True)}
     return info
 
 
-def _flagship(ctx, periodic_x):
+def _flagship(ctx, periodic_x, host_loop=False):
     import mpi4jax_tpu as mpx
     import shallow_water as sw
 
@@ -318,17 +324,31 @@ def _flagship(ctx, periodic_x):
     check(interpret == (ctx["platform"] != "tpu"),
           f"Pallas interpret mode is {interpret} on {ctx['platform']}")
 
-    before = mpx.cache_stats()["aot"]
-    wall, n_steps, out = sw.solve_fused(
-        cfg, t1, num_multisteps=multisteps, devices=ctx["devices"],
-        fast="auto", pinned=True, return_state=True)
-    after = mpx.cache_stats()["aot"]
-    check(n_steps == n_want, f"ran {n_steps} steps, not {n_want}")
-    # the pinned artifact is what ran: one pin, compiled here, called for
-    # the warm-up and for the timed run
-    delta = {k: after[k] - before[k] for k in ("pins", "compiles", "calls")}
-    check(delta == {"pins": 1, "compiles": 1, "calls": 2},
-          f"pinned program accounting {delta}")
+    if host_loop:
+        first_step, multistep = sw.make_stepper(cfg, comm, fast="auto")
+        state = sw.initial_state(cfg, comm)
+        n_steps = sw.run_plan(cfg, "auto", n_iters, multisteps)["steps"]
+        # compile both programs, then the run, closed by one wait on the
+        # last state
+        jax.block_until_ready(multistep(first_step(state), multisteps))
+        start = time.perf_counter()
+        out = jax.block_until_ready(sw.run_multisteps(
+            first_step, multistep, state, n_iters, multisteps))
+        wall = time.perf_counter() - start
+        check(n_steps == n_want, f"planned {n_steps} steps, not {n_want}")
+    else:
+        before = mpx.cache_stats()["aot"]
+        wall, n_steps, out = sw.solve_fused(
+            cfg, t1, num_multisteps=multisteps, devices=ctx["devices"],
+            fast="auto", pinned=True, return_state=True)
+        after = mpx.cache_stats()["aot"]
+        check(n_steps == n_want, f"ran {n_steps} steps, not {n_want}")
+        # the pinned artifact is what ran: one pin, compiled here, called
+        # for the warm-up and for the timed run
+        delta = {k: after[k] - before[k]
+                 for k in ("pins", "compiles", "calls")}
+        check(delta == {"pins": 1, "compiles": 1, "calls": 2},
+              f"pinned program accounting {delta}")
 
     # the plain jnp step, same steps, same devices
     _, n_ref, ref = sw.solve_fused(

@@ -31,6 +31,7 @@ Usage:
 """
 
 import argparse
+import itertools
 import math
 import os
 import sys
@@ -1587,11 +1588,12 @@ def _advancer(cfg: Config, comm: mpx.Comm, fast):
     carried widened frame (a margin-band refresh between kernel calls
     instead of a crop and a re-widening per call); otherwise the step
     function for the Euler step and ``_run_steps`` for the rest.
-    ``schedule(num_steps)`` is what a leg of ``num_steps`` steps (the Euler
-    step first) is made of after its Euler call, by the schedule
-    ``advance`` itself follows: ``(chunk calls, single-step calls, band
-    refreshes, wide)``.  ``leg_plan`` has no mesh and passes ``comm=None``:
-    it takes the schedule and never calls ``advance``."""
+    ``schedule(num_steps, euler_first=True)`` is what a call of
+    ``num_steps`` steps is made of after its Euler call (if it has one), by
+    the schedule ``advance`` itself follows: ``(chunk calls, single-step
+    calls, band refreshes, wide)``.  ``leg_plan`` and ``run_plan`` have no
+    mesh and pass ``comm=None``: they take the schedule and never call
+    ``advance``."""
     mode = _resolve_mode(fast, cfg)
     step, chunk, chunk_size = select_steps(mode, cfg)
 
@@ -1607,9 +1609,12 @@ def _advancer(cfg: Config, comm: mpx.Comm, fast):
             m=_margin_rows(chunk_size),
             interpret=comm is not None and _resolve_interpret(comm))
 
-        def schedule(num_steps):
-            head, trips, rem = _wide_schedule(num_steps, chunk_size, True)
-            return head + trips, rem, trips + rem, True
+        def schedule(num_steps, euler_first=True):
+            head, trips, rem = _wide_schedule(num_steps, chunk_size,
+                                              euler_first)
+            # a refresh before every call but the first off the fresh frame
+            fresh = bool(rem) and not (euler_first or head or trips)
+            return head + trips, rem, trips + rem - int(fresh), True
 
     else:
         def advance(state, num_steps, euler_first):
@@ -1618,8 +1623,9 @@ def _advancer(cfg: Config, comm: mpx.Comm, fast):
             return _run_steps(state, num_steps - int(euler_first), cfg,
                               comm, step, chunk, chunk_size)
 
-        def schedule(num_steps):
-            nchunks, rem = _steps_schedule(num_steps - 1, chunk, chunk_size)
+        def schedule(num_steps, euler_first=True):
+            nchunks, rem = _steps_schedule(num_steps - int(euler_first),
+                                           chunk, chunk_size)
             return nchunks, rem, 0, False
 
     return advance, schedule, chunk_size
@@ -1692,40 +1698,118 @@ def _run_steps(state: State, num_steps: int, cfg, comm, step, chunk,
 # ---------------------------------------------------------------------------
 
 
+def n_multisteps(cfg: Config, t1: float, num_multisteps: int) -> int:
+    """How many ``num_multisteps``-step calls follow the first step of a run
+    to model time ``t1`` [s]: the first multiple that reaches it."""
+    return max(0, math.ceil((t1 - cfg.dt) / (cfg.dt * num_multisteps)))
+
+
+def run_multisteps(first_step, multistep, state: State, n_iters: int,
+                   num_multisteps: int, on_multistep=None) -> State:
+    """The documented driver's host loop (ref examples/shallow_water.py:
+    solve_shallow_water): one call of ``first_step``, then ``n_iters`` calls
+    of ``multistep(state, num_multisteps)``, every call's state the next
+    call's input.  ``first_step`` / ``multistep`` are ``make_stepper``'s.
+
+    The caller closes the run with ``jax.block_until_ready`` on what this
+    returns.  ``on_multistep(state)``, where given, is called after each of
+    the ``1 + n_iters`` calls (``solve`` takes its snapshots there; reading
+    a field on the host is what makes such a run wait call by call).
+
+    Two calls are in flight at the most: before call k + 1 is dispatched
+    the loop waits for call k - 1, so one call runs and one is queued
+    behind it, which is all the device needs to stay busy.  A third cannot
+    be allocated beside them at a chip-filling size: at 3600 x 28800 a
+    call holds 2.5 GB of input, 2.5 GB of results and 5.1 GB of
+    temporaries beside the caller's retained state, and with every call
+    dispatched at once the runtime allocated results until 77 MB of the
+    chip's 16.9 GB were left, held the host inside each launch until
+    memory came free, and in 3 of 16 windows one launch of 135 stalled
+    for 1.6-2.5 s (PERF.md section 6, PR 32).  The wait is on a state the
+    next call reads anyway, so it keeps nothing alive.
+
+    What a run costs beside a ``fused_runner`` leg of the same steps is
+    paid once a *call*: the region's entry and exit and, in ``"wide2"``,
+    the widened frame built and cropped (``run_plan`` counts them)."""
+    state = first_step(state)
+    if on_multistep is not None:
+        on_multistep(state)
+    before = None  # the newest call's input: the result of the call before
+    for _ in range(n_iters):
+        if before is not None:
+            jax.block_until_ready(before)
+        before = state
+        state = multistep(state, num_multisteps)
+        if on_multistep is not None:
+            on_multistep(state)
+    return state
+
+
+def run_plan(cfg: Config, fast, n_iters: int, num_multisteps: int = 10) -> dict:
+    """What one run of ``run_multisteps`` over ``make_stepper(cfg, comm,
+    fast=fast)`` is made of, by the schedule the two programs are built
+    from (``_advancer``'s, as ``leg_plan``): per run ``calls`` (region
+    calls) and ``steps``; ``steps_per_kernel_call``; and under
+    ``first_step`` and ``multistep`` what *one call* of each holds, in
+    ``leg_plan``'s keys (``steps``, ``euler_calls``, ``chunk_calls``,
+    ``single_step_calls``, ``frames_built``, ``band_refreshes``,
+    ``crops``) — a frame is built and cropped in every call of
+    ``"wide2"``, where a leg builds and crops one."""
+    if n_iters < 0 or num_multisteps < 1:
+        raise ValueError("a run is its first step and n_iters >= 0 calls of "
+                         f"num_multisteps >= 1 steps, got {n_iters} of "
+                         f"{num_multisteps}")
+    _, schedule, chunk_size = _advancer(cfg, None, fast)
+
+    def call_plan(steps, euler_first):
+        nchunks, rem, refreshes, wide = schedule(steps, euler_first)
+        return {"steps": steps, "euler_calls": int(euler_first),
+                "chunk_calls": nchunks, "single_step_calls": rem,
+                "frames_built": int(wide), "band_refreshes": refreshes,
+                "crops": int(wide)}
+
+    return {"calls": 1 + n_iters, "steps": 1 + n_iters * num_multisteps,
+            "steps_per_kernel_call": chunk_size,
+            "first_step": call_plan(1, True),
+            "multistep": call_plan(num_multisteps, False)}
+
+
 def solve(cfg: Config, t1: float, *, num_multisteps: int = 10, devices=None,
           collect: bool = True, verbose: bool = False, fast=True):
-    """Iterate the model to time ``t1`` [s].  Returns ``(snapshots,
-    wall_time_s, n_steps)``; ``snapshots`` is a list of stacked-block h
-    fields (empty when ``collect=False``)."""
+    """Iterate the model to time ``t1`` [s] by the host loop
+    ``run_multisteps``.  Returns ``(snapshots, wall_time_s, n_steps)``;
+    ``snapshots`` is a list of stacked-block h fields (empty when
+    ``collect=False``): the initial state, the state after the first step
+    and after every multistep, and the root-gathered final state."""
     mesh, comm = make_mesh_and_comm(cfg, devices=devices)
     first_step, multistep = make_stepper(cfg, comm, fast=fast)
+    n_iters = n_multisteps(cfg, t1, num_multisteps)
 
     state = initial_state(cfg, comm)
     snapshots = [np.asarray(state.h)] if collect else []
 
-    state = first_step(state)
-    if collect:
-        snapshots.append(np.asarray(state.h))
-    t = cfg.dt
+    # warm-up compile of both programs (excluded from timing, like the
+    # reference's pre-compilation at examples/shallow_water.py:449-450);
+    # dispatch is asynchronous, so wait for the device before the clock
+    # starts
+    jax.block_until_ready(multistep(first_step(state), num_multisteps))
 
-    # warm-up compile (excluded from timing, like the reference's
-    # pre-compilation at examples/shallow_water.py:449-450); dispatch is
-    # asynchronous, so wait for the device before the clock starts
-    jax.block_until_ready(multistep(state, num_multisteps))
+    multisteps_done = itertools.count()
 
-    n_steps = 1
-    start = time.perf_counter()
-    while t < t1:
-        state = multistep(state, num_multisteps)
+    def on_multistep(state):
         if collect:
             snapshots.append(np.asarray(state.h))  # device->host sync
-        t += cfg.dt * num_multisteps
-        n_steps += num_multisteps
         if verbose:
+            t = cfg.dt * (1 + next(multisteps_done) * num_multisteps)
             print(f"  t = {t / DAY_IN_SECONDS:.3f} days", end="\r")
-    if not collect:
-        # pipelined throughput mode: one sync at the end
-        jax.block_until_ready(state)
+
+    start = time.perf_counter()
+    state = run_multisteps(first_step, multistep, state, n_iters,
+                           num_multisteps,
+                           on_multistep if collect or verbose else None)
+    # without snapshots: pipelined throughput mode, two calls in flight and
+    # the run closed by one wait on the last state
+    jax.block_until_ready(state)
     wall = time.perf_counter() - start
 
     # collect the full solution at rank 0 — exercises the eager gather path
@@ -1736,7 +1820,7 @@ def solve(cfg: Config, t1: float, *, num_multisteps: int = 10, devices=None,
         gathered, _ = mpx.gather(state.h, root=0, comm=comm)
         snapshots.append(np.asarray(gathered[0]))
 
-    return snapshots, wall, n_steps
+    return snapshots, wall, 1 + n_iters * num_multisteps
 
 
 def fused_runner(cfg: Config, comm: mpx.Comm, fast="auto"):
@@ -1808,8 +1892,7 @@ def solve_fused(cfg: Config, t1: float, *, num_multisteps: int = 10,
     program's outputs.
     """
     mesh, comm = make_mesh_and_comm(cfg, devices=devices)
-    n_iters = max(0, math.ceil((t1 - cfg.dt) / (cfg.dt * num_multisteps)))
-    n_steps = 1 + n_iters * num_multisteps
+    n_steps = 1 + n_multisteps(cfg, t1, num_multisteps) * num_multisteps
     fused, _ = fused_runner(cfg, comm, fast)
 
     state = initial_state(cfg, comm)
