@@ -1416,27 +1416,44 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
 def _wide_schedule(num_steps: int, chunk_size: int, euler_first: bool):
     """``(head, trips, rem)`` of ``_wide_run``'s kernel calls after the
     optional Euler call: ``head`` chunk call (0 or 1) straight off the
-    just-built frame, ``trips`` rounds of one band refresh and one chunk
+    frame as it came, ``trips`` rounds of one band refresh and one chunk
     call (``_wide_run``'s loop runs two rounds an iteration), ``rem``
-    single-step calls.  Shared with ``leg_plan``."""
+    single-step calls.  Shared with ``leg_plan`` and ``run_plan``."""
     nchunks, rem = divmod(num_steps - int(euler_first), chunk_size)
-    # the margins are still the just-exchanged ones until a kernel call
-    # invalidates them, so the first call after the build needs no refresh
+    # the margins are the just-exchanged ones (or the ones the call before
+    # left refreshed) until a kernel call invalidates them, so the first
+    # call on them needs no refresh
     head = int(bool(nchunks) and not euler_first)
     return head, nchunks - head, rem
 
 
-def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
-              chunk_size: int, m: int, interpret: bool,
-              euler_first: bool) -> State:
+def _wide_run(state, num_steps: int, cfg: Config, comm: mpx.Comm,
+              chunk_size: int, m: int, interpret: bool, euler_first: bool,
+              carried_in: bool = False, handed_on: bool = False):
     """Advance ``num_steps`` model steps on ANY mesh on the CARRIED
     widened frame: build the frame once (``_wide_exchange``), run
     ``chunk_size``-step kernel calls with only a margin-band refresh
-    between them (``_wide_refresh``), crop once at the end.
-    ``euler_first`` makes the first advanced step the forward-Euler one (a
-    1-step kernel call).  This is the one wide-halo path: every driver
-    (``make_stepper``, ``fused_runner``) and the standalone steps
-    ``model_step_wide`` / ``model_step2_wide`` go through it.
+    between them (``_wide_refresh``), crop once at the end
+    (``_wide_crop``).  ``euler_first`` makes the first advanced step the
+    forward-Euler one (a 1-step kernel call).  This is the one wide-halo
+    path: every driver (``make_stepper``, ``fused_runner``) and the
+    standalone steps ``model_step_wide`` / ``model_step2_wide`` go through
+    it.
+
+    A host loop that carries the frame from call to call
+    (``run_multisteps``) runs the same three pieces a piece a call:
+    ``carried_in`` takes the six frames an earlier call handed on in the
+    ``State``'s place and builds nothing; ``handed_on`` returns the six
+    frames, their margins refreshed behind the last kernel call, and crops
+    nothing.  So a frame handed on has valid margins as a just-built one
+    has, and the next call's first kernel call runs straight off its
+    parameters — for what XLA:TPU makes of such a program at 3600 x 28800
+    (PERF.md section 6, PR 34): a refresh in place as the first instruction
+    on a parameter's row-major copy costs a third set of six frames (7.71
+    GB of temporaries for 5.14: the copy's buffers are then never reused).
+    The flags live here, not in a function of their own between the
+    region and the kernel calls: one Python frame more put 0.8 s on the
+    walled leg's warm ``setup_s`` (PERF.md section 6, PR 30 and PR 34).
 
     Where ``model_step_pallas_halo`` splices a real 1-cell exchange
     between the two phase kernels of every step (5 exchange rounds and two
@@ -1473,7 +1490,10 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
     if num_steps <= 0:
         return state
     token = mpx.create_token()
-    wf, token = _wide_exchange(tuple(state), cfg, comm, m, token)
+    if carried_in:
+        wf = tuple(state)
+    else:
+        wf, token = _wide_exchange(tuple(state), cfg, comm, m, token)
     head, trips, rem = _wide_schedule(num_steps, chunk_size, euler_first)
     if euler_first:
         wf = _wide_kernel_call(wf, cfg, True, 1, m, interpret)
@@ -1486,12 +1506,14 @@ def _wide_run(state: State, num_steps: int, cfg: Config, comm: mpx.Comm,
         wf = _wide_kernel_call(wf, cfg, False, chunk_size, m, interpret)
     if trips:  # fori_loop(0, 0) would still trace the chunk kernel
         wf = jax.lax.fori_loop(0, trips, body, tuple(wf), unroll=2)
-    # no kernel call yet: the margins are still the just-exchanged ones
+    # no kernel call yet: the margins are still the ones the frame came with
     fresh = not (euler_first or head or trips)
     for i in range(rem):
         if i or not fresh:
             wf = _wide_refresh(wf, cfg, comm, m, token)
         wf = _wide_kernel_call(wf, cfg, False, 1, m, interpret)
+    if handed_on:
+        return tuple(_wide_refresh(wf, cfg, comm, m, token))
     return _wide_crop(wf, cfg, m)
 
 
@@ -1579,8 +1601,8 @@ def select_steps(fast, cfg: Config = None):
 
 
 def _advancer(cfg: Config, comm: mpx.Comm, fast):
-    """``(advance, schedule, chunk_size)`` behind ``fast``: the one place
-    that knows the wide-halo mode from the others.
+    """``(advance, schedule, chunk_size, carried)`` behind ``fast``: the one
+    place that knows the wide-halo mode from the others.
 
     ``advance(state, num_steps, euler_first=...)`` traces ``num_steps``
     model steps inside a region, the first of them the forward-Euler step
@@ -1588,47 +1610,80 @@ def _advancer(cfg: Config, comm: mpx.Comm, fast):
     carried widened frame (a margin-band refresh between kernel calls
     instead of a crop and a re-widening per call); otherwise the step
     function for the Euler step and ``_run_steps`` for the rest.
-    ``schedule(num_steps, euler_first=True)`` is what a call of
-    ``num_steps`` steps is made of after its Euler call (if it has one), by
-    the schedule ``advance`` itself follows: ``(chunk calls, single-step
-    calls, band refreshes, wide)``.  ``leg_plan`` and ``run_plan`` have no
-    mesh and pass ``comm=None``: they take the schedule and never call
-    ``advance``."""
+    ``schedule(num_steps, euler_first=True, handed_on=False)`` is what a
+    call of ``num_steps`` steps is made of after its Euler call (if it has
+    one), by the schedule ``advance`` itself follows: ``(chunk calls,
+    single-step calls, band refreshes, wide)``; ``handed_on`` is the call
+    of a host loop that carries the frame on to the next.  ``carried`` is
+    what such a loop carries where it is not the ``State``: in ``"wide2"``
+    the three pieces of ``_wide_run`` as per-rank functions ``(start,
+    carry_on, crop)`` — ``State`` -> frames with the Euler step, frames ->
+    frames for ``num_steps``, frames -> ``State`` — and ``None`` in every
+    other mode, whose steps take and leave a ``State``.  ``leg_plan`` and ``run_plan``
+    have no mesh and pass ``comm=None``: they take the schedule and never
+    call ``advance``."""
     mode = _resolve_mode(fast, cfg)
     step, chunk, chunk_size = select_steps(mode, cfg)
 
     if mode == "wide2":
-        # a partial, not a nested function: it binds the arguments without
-        # a Python frame of its own between the region and ``_wide_run``.
+        # partials, not nested functions: they bind the arguments without
+        # a Python frame of their own between the region and ``_wide_run``.
         # With a nested function here the trace of the walled 71-step leg
         # took 0.6-0.8 s longer in the benchmark's process on the chip's
         # host (PERF.md section 6, PR 30): the same program, a tenth more
         # of its ``setup_s``.
-        advance = partial(
-            _wide_run, cfg=cfg, comm=comm, chunk_size=chunk_size,
-            m=_margin_rows(chunk_size),
-            interpret=comm is not None and _resolve_interpret(comm))
+        m = _margin_rows(chunk_size)
+        bound = dict(cfg=cfg, comm=comm, chunk_size=chunk_size, m=m,
+                     interpret=comm is not None and _resolve_interpret(comm))
+        advance = partial(_wide_run, **bound)
+        carried = (partial(_wide_run, num_steps=1, euler_first=True,
+                           handed_on=True, **bound),
+                   partial(_wide_run, euler_first=False, carried_in=True,
+                           handed_on=True, **bound),
+                   partial(_wide_crop, cfg=cfg, m=m))
 
-        def schedule(num_steps, euler_first=True):
+        def schedule(num_steps, euler_first=True, handed_on=False):
             head, trips, rem = _wide_schedule(num_steps, chunk_size,
                                               euler_first)
-            # a refresh before every call but the first off the fresh frame
+            # a refresh before every call but the first on the frame as it
+            # came, and one behind the last where the frame is handed on
             fresh = bool(rem) and not (euler_first or head or trips)
-            return head + trips, rem, trips + rem - int(fresh), True
+            return (head + trips, rem,
+                    trips + rem - int(fresh) + int(handed_on), True)
 
     else:
+        carried = None
+
         def advance(state, num_steps, euler_first):
             if euler_first:
                 state = step(state, cfg, comm, first_step=True)
             return _run_steps(state, num_steps - int(euler_first), cfg,
                               comm, step, chunk, chunk_size)
 
-        def schedule(num_steps, euler_first=True):
+        def schedule(num_steps, euler_first=True, handed_on=False):
             nchunks, rem = _steps_schedule(num_steps - int(euler_first),
                                            chunk, chunk_size)
             return nchunks, rem, 0, False
 
-    return advance, schedule, chunk_size
+    return advance, schedule, chunk_size, carried
+
+
+def _carried_stepper(start, carry_on, crop, comm: mpx.Comm):
+    """The regions of a run that carries the widened frame, under the names
+    of the two they stand in for (a trace reads ``first_step`` and
+    ``multistep`` whichever form ran), and the crop: a plain ``jax.jit``
+    over the stacked frames — slices and a ``where`` on axes no mesh axis
+    shards, so no collective and no region."""
+
+    @partial(mpx.spmd, comm=comm)
+    def first_step(state: State):
+        return start(state)
+
+    @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+    def multistep(frames, num_steps: int):
+        return carry_on(frames, num_steps)
+
+    return first_step, multistep, jax.jit(jax.vmap(crop))
 
 
 def make_stepper(cfg: Config, comm: mpx.Comm, *, fast=True):
@@ -1643,8 +1698,20 @@ def make_stepper(cfg: Config, comm: mpx.Comm, *, fast=True):
     tests/test_examples.py.  ``multistep`` advances exactly ``num_steps``
     steps in every mode (the chunk kernel handles whole chunks; the
     remainder falls back to single-step calls).
+
+    Both take and return a ``State``, for whoever steps by hand.  A host
+    loop that hands every call's result to the next call (``run_multisteps``)
+    finds beside them what it may carry instead:
+    ``first_step.carried(state) -> carry``, ``multistep.carried(carry,
+    num_steps) -> carry`` and ``multistep.crop(carry) -> State``.  In
+    ``"wide2"`` the carry is the widened frame (six stacked arrays), built
+    by the first, advanced with neither build nor crop by the second and
+    cropped by the third, where ``multistep(state, n)`` builds and crops a
+    frame in every call; in every other mode (``_advancer`` decides) the
+    carry is the ``State``, the carried forms are the two programs
+    themselves and ``crop`` returns what it is given.
     """
-    advance, _, _ = _advancer(cfg, comm, fast)
+    advance, _, _, carried = _advancer(cfg, comm, fast)
 
     @partial(mpx.spmd, comm=comm)
     def first_step(state: State) -> State:
@@ -1654,7 +1721,14 @@ def make_stepper(cfg: Config, comm: mpx.Comm, *, fast=True):
     def multistep(state: State, num_steps: int) -> State:
         return advance(state, num_steps, euler_first=False)
 
+    first_step.carried, multistep.carried, multistep.crop = (
+        (first_step, multistep, _same) if carried is None
+        else _carried_stepper(*carried, comm))
     return first_step, multistep
+
+
+def _same(state):
+    return state
 
 
 def _steps_schedule(num_steps: int, chunk, chunk_size: int):
@@ -1708,69 +1782,96 @@ def run_multisteps(first_step, multistep, state: State, n_iters: int,
                    num_multisteps: int, on_multistep=None) -> State:
     """The documented driver's host loop (ref examples/shallow_water.py:
     solve_shallow_water): one call of ``first_step``, then ``n_iters`` calls
-    of ``multistep(state, num_multisteps)``, every call's state the next
+    of ``multistep(state, num_multisteps)``, every call's result the next
     call's input.  ``first_step`` / ``multistep`` are ``make_stepper``'s.
 
-    The caller closes the run with ``jax.block_until_ready`` on what this
-    returns.  ``on_multistep(state)``, where given, is called after each of
-    the ``1 + n_iters`` calls (``solve`` takes its snapshots there; reading
-    a field on the host is what makes such a run wait call by call).
+    What goes from call to call is ``make_stepper``'s carry: in ``"wide2"``
+    the widened frame, built by the first call, advanced by every later
+    one, each leaving its margin bands refreshed, and cropped to the
+    ``State`` this returns once, after the last — the frame a crop and a
+    rebuild between two calls would have left, bit for bit, and what
+    ``fused_runner``'s leg carries through its loop; in every other mode
+    the ``State`` itself.  A pair without the
+    carried forms (a caller's own two functions) is called as it is.
 
-    Two calls are in flight at the most: before call k + 1 is dispatched
-    the loop waits for call k - 1, so one call runs and one is queued
-    behind it, which is all the device needs to stay busy.  A third cannot
-    be allocated beside them at a chip-filling size: at 3600 x 28800 a
-    call holds 2.5 GB of input, 2.5 GB of results and 5.1 GB of
-    temporaries beside the caller's retained state, and with every call
-    dispatched at once the runtime allocated results until 77 MB of the
-    chip's 16.9 GB were left, held the host inside each launch until
-    memory came free, and in 3 of 16 windows one launch of 135 stalled
-    for 1.6-2.5 s (PERF.md section 6, PR 32).  The wait is on a state the
-    next call reads anyway, so it keeps nothing alive.
+    The caller closes the run with ``jax.block_until_ready`` on what this
+    returns.  ``on_multistep(state)``, where given, is called with the
+    finished ``State`` after each of the ``1 + n_iters`` calls (``solve``
+    takes its snapshots there): a run with a hook waits call by call, as
+    reading a field on the host makes it anyway, and pays a crop of the
+    carry a call, dispatched only where the hook exists.
+
+    Two dispatches are in flight at the most: before call k + 1 goes (or
+    the crop behind the last call) the loop waits for call k - 1, so one
+    call runs and one is queued behind it, which is all the device needs
+    to stay busy.  A third cannot be allocated beside them at a
+    chip-filling size: at 3600 x 28800 a call holds 2.5 GB of input, 2.5 GB
+    of results and 5.1 GB of temporaries beside the caller's retained
+    state, and with every call dispatched at once the runtime allocated
+    results until 77 MB of the chip's 16.9 GB were left, held the host
+    inside each launch until memory came free, and in 3 of 16 windows one
+    launch of 135 stalled for 1.6-2.5 s (PERF.md section 6, PR 32).  The
+    wait is on a result the next call reads anyway, so it keeps nothing
+    alive.
 
     What a run costs beside a ``fused_runner`` leg of the same steps is
-    paid once a *call*: the region's entry and exit and, in ``"wide2"``,
-    the widened frame built and cropped (``run_plan`` counts them)."""
-    state = first_step(state)
+    the entry and exit of a region once a *call* (``run_plan`` counts
+    them)."""
+    forms = (getattr(first_step, "carried", None),
+             getattr(multistep, "carried", None),
+             getattr(multistep, "crop", None))
+    start, carry_on, crop = (forms if all(forms)
+                             else (first_step, multistep, _same))
+    carry = start(state)
     if on_multistep is not None:
-        on_multistep(state)
+        on_multistep(jax.block_until_ready(crop(carry)))
     before = None  # the newest call's input: the result of the call before
     for _ in range(n_iters):
         if before is not None:
             jax.block_until_ready(before)
-        before = state
-        state = multistep(state, num_multisteps)
+        before = carry
+        carry = carry_on(carry, num_multisteps)
         if on_multistep is not None:
-            on_multistep(state)
-    return state
+            on_multistep(jax.block_until_ready(crop(carry)))
+    if before is not None:
+        jax.block_until_ready(before)
+    return crop(carry)
 
 
 def run_plan(cfg: Config, fast, n_iters: int, num_multisteps: int = 10) -> dict:
     """What one run of ``run_multisteps`` over ``make_stepper(cfg, comm,
-    fast=fast)`` is made of, by the schedule the two programs are built
-    from (``_advancer``'s, as ``leg_plan``): per run ``calls`` (region
-    calls) and ``steps``; ``steps_per_kernel_call``; and under
-    ``first_step`` and ``multistep`` what *one call* of each holds, in
-    ``leg_plan``'s keys (``steps``, ``euler_calls``, ``chunk_calls``,
-    ``single_step_calls``, ``frames_built``, ``band_refreshes``,
-    ``crops``) — a frame is built and cropped in every call of
-    ``"wide2"``, where a leg builds and crops one."""
+    fast=fast)`` is made of, by the schedule its programs are built from
+    (``_advancer``'s, as ``leg_plan``): per run ``calls`` (region calls),
+    ``steps``, ``frames_built`` and ``crops`` (1 and 1 in ``"wide2"``, whose
+    run carries the frame from call to call and crops it once behind the
+    last, outside any region; 0 and 0 elsewhere); ``steps_per_kernel_call``;
+    and under ``first_step`` and ``multistep`` what *one call* of each
+    carried form holds, in ``leg_plan``'s keys (``steps``, ``euler_calls``,
+    ``chunk_calls``, ``single_step_calls``, ``frames_built``,
+    ``band_refreshes``, ``crops``): the first builds the frame, every call
+    refreshes the bands between its kernel calls and once more behind the
+    last, for the call that follows (one refresh a run more than a leg of
+    the same steps: the crop's), none crops."""
     if n_iters < 0 or num_multisteps < 1:
         raise ValueError("a run is its first step and n_iters >= 0 calls of "
                          f"num_multisteps >= 1 steps, got {n_iters} of "
                          f"{num_multisteps}")
-    _, schedule, chunk_size = _advancer(cfg, None, fast)
+    _, schedule, chunk_size, _ = _advancer(cfg, None, fast)
 
     def call_plan(steps, euler_first):
-        nchunks, rem, refreshes, wide = schedule(steps, euler_first)
+        nchunks, rem, refreshes, wide = schedule(steps, euler_first,
+                                                 handed_on=True)
         return {"steps": steps, "euler_calls": int(euler_first),
                 "chunk_calls": nchunks, "single_step_calls": rem,
-                "frames_built": int(wide), "band_refreshes": refreshes,
-                "crops": int(wide)}
+                "frames_built": int(wide and euler_first),
+                "band_refreshes": refreshes, "crops": 0}
 
+    first = call_plan(1, True)
+    framed = first["frames_built"]  # the run's one frame, and its one crop
     return {"calls": 1 + n_iters, "steps": 1 + n_iters * num_multisteps,
             "steps_per_kernel_call": chunk_size,
-            "first_step": call_plan(1, True),
+            "frames_built": framed, "crops": framed,
+            "first_step": first,
             "multistep": call_plan(num_multisteps, False)}
 
 
@@ -1788,11 +1889,12 @@ def solve(cfg: Config, t1: float, *, num_multisteps: int = 10, devices=None,
     state = initial_state(cfg, comm)
     snapshots = [np.asarray(state.h)] if collect else []
 
-    # warm-up compile of both programs (excluded from timing, like the
-    # reference's pre-compilation at examples/shallow_water.py:449-450);
-    # dispatch is asynchronous, so wait for the device before the clock
-    # starts
-    jax.block_until_ready(multistep(first_step(state), num_multisteps))
+    # warm-up compile of the programs the loop runs (excluded from timing,
+    # like the reference's pre-compilation at examples/shallow_water.py:
+    # 449-450): a run of one multistep; dispatch is asynchronous, so wait
+    # for the device before the clock starts
+    jax.block_until_ready(run_multisteps(first_step, multistep, state,
+                                         min(n_iters, 1), num_multisteps))
 
     multisteps_done = itertools.count()
 
@@ -1836,7 +1938,7 @@ def fused_runner(cfg: Config, comm: mpx.Comm, fast="auto"):
     and ``_run_steps``.  ``chunk_size`` is the number of steps one call of
     the loop's kernel advances.  Pin it with ``mpx.compile(fused, state,
     total)``; ``leg_plan`` says what the leg is made of."""
-    advance, _, chunk_size = _advancer(cfg, comm, fast)
+    advance, _, chunk_size, _ = _advancer(cfg, comm, fast)
 
     @partial(mpx.spmd, comm=comm, static_argnums=(1,))
     def fused(state: State, total: int) -> State:
@@ -1861,7 +1963,7 @@ def leg_plan(cfg: Config, fast, steps: int) -> dict:
       elsewhere."""
     if steps < 1:
         raise ValueError(f"a leg has at least its Euler step, got {steps}")
-    _, schedule, chunk_size = _advancer(cfg, None, fast)
+    _, schedule, chunk_size, _ = _advancer(cfg, None, fast)
     nchunks, rem, refreshes, wide = schedule(steps)
     return {"steps": steps, "steps_per_kernel_call": chunk_size,
             "euler_calls": 1, "chunk_calls": nchunks,

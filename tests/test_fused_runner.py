@@ -163,13 +163,9 @@ class _Tally:
         self.monkeypatch.setattr(sw, name, counted)
 
 
-@pytest.mark.parametrize("steps", range(1, 24))
-@pytest.mark.parametrize("fast,periodic_x", [("wide2", False),
-                                             ("pallas2", True)])
-def test_leg_plan_counts_the_calls_a_leg_makes(monkeypatch, fast,
-                                               periodic_x, steps):
-    cfg = _config((1, 1), periodic_x)
-    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+def _solver_tally(monkeypatch):
+    """A tally of the kernel calls by ``/first_step/nsteps`` and of the
+    widened frame's builds, refreshes and crops."""
     tally = _Tally(monkeypatch)
     # first_step, nsteps: positional in _wide_kernel_call, by keyword or
     # default in model_step_pallas
@@ -180,6 +176,17 @@ def test_leg_plan_counts_the_calls_a_leg_makes(monkeypatch, fast,
                 f"/{first_step}/{nsteps}")
     for name in ("_wide_exchange", "_wide_refresh", "_wide_crop"):
         tally.count(name)
+    return tally
+
+
+@pytest.mark.parametrize("steps", range(1, 24))
+@pytest.mark.parametrize("fast,periodic_x", [("wide2", False),
+                                             ("pallas2", True)])
+def test_leg_plan_counts_the_calls_a_leg_makes(monkeypatch, fast,
+                                               periodic_x, steps):
+    cfg = _config((1, 1), periodic_x)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    tally = _solver_tally(monkeypatch)
     fused, _ = sw.fused_runner(cfg, comm, fast)
     state = sw.initial_state(cfg, comm)
     jax.eval_shape(lambda s: fused(s, steps - 1), state)

@@ -4,10 +4,11 @@ of what one run of it is made of.
 
 ``run_multisteps`` is what ``solve()`` runs and what the benchmark times
 (``chipbench/drivers/solver_loop.py``): it must advance what a
-``fused_runner`` leg of the same steps advances, carry every call's state
-into the next, and leave ``solve()`` the snapshots it gave.  ``run_plan`` is
-held against the calls the two programs really make, counted while they are
-traced.
+``fused_runner`` leg of the same steps advances, carry every call's result
+into the next — in ``"wide2"`` the widened frame, built once a run and
+cropped once, where the same calls chained by hand build and crop one a
+call — and leave ``solve()`` the snapshots it gave.  ``run_plan`` is held
+against the calls the programs really make, counted while they are traced.
 """
 
 import math
@@ -29,7 +30,7 @@ for path in (os.path.dirname(HERE), os.path.join(HERE, "..", "examples"),
 import mpi4jax_tpu as mpx  # noqa: E402
 import shallow_water as sw  # noqa: E402
 from chipbench.reference import shallow_water_walls as walls_ref  # noqa: E402
-from test_fused_runner import _Tally, _config, _unfused  # noqa: E402
+from test_fused_runner import _config, _solver_tally, _unfused  # noqa: E402
 
 NUM = 10     # upstream's and solve()'s default num_multisteps
 N_ITERS = 2  # 1 + 2 x 10 steps
@@ -39,8 +40,11 @@ N_ITERS = 2  # 1 + 2 x 10 steps
 CASES = [
     ("auto", (1, 1), True),     # pallas2
     ("auto", (1, 1), False),    # wide2
+    ("wide2", (1, 1), True),
     ("wide2", (2, 2), True),
     ("wide2", (2, 2), False),
+    ("wide2", (2, 4), True),
+    ("wide2", (2, 4), False),
 ]
 # tests/test_fused_runner.py states ``rtol=1e-5, atol=1e-6`` for the
 # stepper's two programs against the leg (2e-6 on the walled single rank),
@@ -84,16 +88,98 @@ def test_a_run_advances_what_a_leg_of_the_same_steps_advances(
 def test_a_run_is_the_leg_bit_for_bit_with_fusion_off(fast, mesh,
                                                       periodic_x):
     """The same kernels on the same operands in the same order: with XLA's
-    fusion pass off (see tests/test_fused_runner.py) a run's three calls,
-    the frame built and cropped in each, give the leg's bits."""
+    fusion pass off (see tests/test_fused_runner.py) a run's three calls —
+    in ``"wide2"`` the frame built by the first, refreshed and advanced by
+    the others, cropped once behind the last — give in all six fields the
+    bits of the leg, and of the same three calls chained by hand through
+    the ``State -> State`` programs, a frame built and cropped in each."""
     cfg, comm, state, (first_step, multistep) = _stepper(fast, mesh,
                                                          periodic_x)
     fused, _ = sw.fused_runner(cfg, comm, fast)
     want = _unfused(lambda s: fused(s, N_ITERS * NUM), state)
-    got = _unfused(lambda s: sw.run_multisteps(first_step, multistep, s,
-                                               N_ITERS, NUM), state)
+    runs = {
+        "carried": _unfused(lambda s: sw.run_multisteps(
+            first_step, multistep, s, N_ITERS, NUM), state),
+        "by hand": _unfused(lambda s: multistep(multistep(
+            first_step(s), NUM), NUM), state)}
+    assert (multistep.carried is not multistep) == (
+        sw._resolve_mode(fast, cfg) == "wide2")
+    for how, got in runs.items():
+        assert isinstance(got, sw.State)
+        for name, a, b in zip(want._fields, got, want):
+            assert a.shape == state.h.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          f"{how}: {name}")
+
+
+@pytest.mark.parametrize("fast,mesh,periodic_x", CASES)
+def test_the_hook_gets_states_and_the_wait_is_on_the_carry(
+        monkeypatch, fast, mesh, periodic_x):
+    """``on_multistep`` is handed a finished ``State`` of the physical
+    shape after every call — in ``"wide2"`` a crop of the carried frame,
+    equal to what stepping by hand gives there — and without a hook two
+    dispatches stay in flight: before call k + 1 goes, and before the crop
+    behind the last call, the loop waits for call k - 1's result,
+    ``n_iters`` times a run, on what the loop carries (six frames in
+    ``"wide2"``)."""
+    n_iters = 3
+    cfg, comm, state, (first_step, multistep) = _stepper(fast, mesh,
+                                                         periodic_x)
+    wide = sw._resolve_mode(fast, cfg) == "wide2"
+    seen, waits = [], []
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waits.append(x) or block(x))
+    plain = sw.run_multisteps(first_step, multistep, state, n_iters, NUM)
+    flight, waits = waits, []
+    got = sw.run_multisteps(first_step, multistep, state, n_iters, NUM,
+                            seen.append)
+    monkeypatch.undo()
+    assert len(seen) == 1 + n_iters and len(flight) == n_iters
+    frame = (cfg.nproc, cfg.ny_local + 30, cfg.nx_local + 30)
+    for waited in flight:
+        assert len(waited) == 6
+        assert {f.shape for f in waited} == {frame if wide
+                                             else state.h.shape}
+    # with the hook: every snapshot finished before the hook has it
+    assert len(waits) == len(flight) + len(seen)
+    assert all(any(w is snap for w in waits) for snap in seen)
+    for a, b in zip(got, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    by_hand = first_step(state)
+    for i, snap in enumerate(seen):
+        assert isinstance(snap, sw.State)
+        assert {f.shape for f in snap} == {state.h.shape}
+        assert snap.h.sharding == state.h.sharding
+        for name, a, b in zip(snap._fields, snap, by_hand):
+            # to rounding (31 steps; 3.3e-6 read on u): the bits are the
+            # test's above, with fusion off
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=2 * WIDE_ATOL,
+                                       err_msg=f"call {i}: {name}")
+        by_hand = multistep(by_hand, NUM)
+    for a, b in zip(got, seen[-1]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_pair_with_one_form_missing_is_called_as_it_is():
+    """A wrapper round one of ``make_stepper``'s programs hides its carried
+    form: the loop then calls both as ``State -> State``, never a frame
+    into a program that takes a ``State``."""
+    cfg, comm, state, (first_step, multistep) = _stepper("auto", (1, 1),
+                                                         False)
+    calls = []
+
+    def wrapped(state, n):
+        calls.append({f.shape for f in state})
+        return multistep(state, n)
+
+    got = sw.run_multisteps(first_step, wrapped, state, N_ITERS, NUM)
+    want = sw.run_multisteps(first_step, multistep, state, N_ITERS, NUM)
+    assert calls == [{state.h.shape}] * N_ITERS
     for name, a, b in zip(want._fields, got, want):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=WIDE_ATOL, err_msg=name)
 
 
 def test_every_calls_state_is_the_next_calls_input():
@@ -134,15 +220,24 @@ def test_the_published_run_is_45_calls_and_441_steps():
     plan = sw.run_plan(cfg, "auto", n_iters, NUM)
     assert (n_iters, plan["calls"], plan["steps"],
             plan["steps_per_kernel_call"]) == (44, 45, 441, 2)
+    # the run carries the frame: built by the first call, cropped once
+    # behind the last, outside any region
+    assert (plan["frames_built"], plan["crops"]) == (1, 1)
     assert plan["first_step"] == {
         "steps": 1, "euler_calls": 1, "chunk_calls": 0,
-        "single_step_calls": 0, "frames_built": 1, "band_refreshes": 0,
-        "crops": 1}
-    # the first kernel call of a multistep runs off the just-built frame
+        "single_step_calls": 0, "frames_built": 1, "band_refreshes": 1,
+        "crops": 0}
+    # every call hands the frame on with its margins refreshed: the first
+    # kernel call of the next runs off them, a refresh goes before each of
+    # the other four and one behind the last
     assert plan["multistep"] == {
         "steps": 10, "euler_calls": 0, "chunk_calls": 5,
-        "single_step_calls": 0, "frames_built": 1, "band_refreshes": 4,
-        "crops": 1}
+        "single_step_calls": 0, "frames_built": 0, "band_refreshes": 5,
+        "crops": 0}
+    periodic = sw.run_plan(sw.Config(nx=3600, ny=28800), "auto", n_iters,
+                           NUM)
+    assert (periodic["frames_built"], periodic["crops"]) == (0, 0)
+    assert periodic["multistep"]["band_refreshes"] == 0
     with pytest.raises(ValueError, match="n_iters"):
         sw.run_plan(cfg, "auto", -1, NUM)
     with pytest.raises(ValueError, match="num_multisteps"):
@@ -154,29 +249,24 @@ def test_the_published_run_is_45_calls_and_441_steps():
                                              ("pallas2", True)])
 def test_run_plan_counts_the_calls_a_run_makes(monkeypatch, fast,
                                                periodic_x, num):
-    """Both programs traced once under the tally of
-    tests/test_fused_runner.py: what each call makes is what ``run_plan``
-    says of it, and a run is one of the first and ``n_iters`` of the
-    other."""
+    """The programs a run calls, each traced once under the tally of
+    tests/test_fused_runner.py: what each carried call makes is what
+    ``run_plan`` says of it, a run is one of the first and ``n_iters`` of
+    the other, and its one frame and one crop are the first call's build
+    and the crop behind the last."""
     cfg = _config((1, 1), periodic_x)
     _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
-    tally = _Tally(monkeypatch)
-    tally.count("_wide_kernel_call",
-                lambda wf, cfg, first, nsteps, *a: f"/{first}/{nsteps}")
-    tally.count("model_step_pallas",
-                lambda s, cfg, comm, first_step, interpret=None, nsteps=1:
-                f"/{first_step}/{nsteps}")
-    for name in ("_wide_exchange", "_wide_refresh", "_wide_crop"):
-        tally.count(name)
+    tally = _solver_tally(monkeypatch)
     first_step, multistep = sw.make_stepper(cfg, comm, fast=fast)
     state = sw.initial_state(cfg, comm)
     kernel = "_wide_kernel_call" if fast == "wide2" else "model_step_pallas"
     plan = sw.run_plan(cfg, fast, 4, num)
     assert (plan["calls"], plan["steps"]) == (5, 1 + 4 * num)
-    for program, args, first in ((first_step, (), True),
-                                 (multistep, (num,), False)):
+    carry, built = state, 0  # what goes from call to call, by its shapes
+    for program, args, first in ((first_step.carried, (), True),
+                                 (multistep.carried, (num,), False)):
         tally.counts.clear()
-        jax.eval_shape(lambda s: program(s, *args), state)
+        carry = jax.eval_shape(lambda s: program(s, *args), carry)
         got = tally.counts
         call = plan["first_step" if first else "multistep"]
         assert call["steps"] == (
@@ -187,7 +277,40 @@ def test_run_plan_counts_the_calls_a_run_makes(monkeypatch, fast,
         assert got.get(f"{kernel}/False/1", 0) == call["single_step_calls"]
         assert got.get("_wide_exchange", 0) == call["frames_built"]
         assert got.get("_wide_refresh", 0) == call["band_refreshes"]
-        assert got.get("_wide_crop", 0) == call["crops"]
+        assert got.get("_wide_crop", 0) == call["crops"] == 0
+        built += got.get("_wide_exchange", 0) * (1 if first else 4)
+    tally.counts.clear()
+    assert jax.eval_shape(multistep.crop, carry) == jax.eval_shape(
+        lambda s: s, state)
+    cropped = tally.counts.get("_wide_crop", 0)
+    assert (built, cropped) == (plan["frames_built"], plan["crops"])
+    assert plan["crops"] == int(fast == "wide2")
+
+
+@pytest.mark.parametrize("num", [1, 2, 3, 7, 10])
+def test_stepping_by_hand_builds_and_crops_a_frame_a_call(monkeypatch,
+                                                          num):
+    """``first_step(state)`` and ``multistep(state, n)`` take and leave a
+    ``State``: in ``"wide2"`` each builds a frame and crops it, and the
+    first kernel call of a multistep runs off the just-built frame (one
+    refresh fewer than the carried call of the same steps, which leaves
+    one behind its last kernel call for the call that follows)."""
+    cfg = _config((1, 1), False)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    tally = _solver_tally(monkeypatch)
+    first_step, multistep = sw.make_stepper(cfg, comm, fast="wide2")
+    state = sw.initial_state(cfg, comm)
+    carried = sw.run_plan(cfg, "wide2", 1, num)["multistep"]
+    for program, args, calls in ((first_step, (), 1),
+                                 (multistep, (num,), (num + 1) // 2)):
+        tally.counts.clear()
+        jax.eval_shape(lambda s: program(s, *args), state)
+        got = tally.counts
+        assert (got["_wide_exchange"], got["_wide_crop"]) == (1, 1)
+        assert sum(n for k, n in got.items()
+                   if k.startswith("_wide_kernel_call")) == calls
+    assert got.get("_wide_refresh", 0) == carried["band_refreshes"] - 1
+    assert calls == carried["chunk_calls"] + carried["single_step_calls"]
 
 
 @pytest.mark.parametrize("steps", [1, 2, 12, 71])
@@ -196,21 +319,26 @@ def test_run_plan_counts_the_calls_a_run_makes(monkeypatch, fast,
 def test_a_leg_is_a_first_step_and_one_multistep_of_the_rest(fast,
                                                              periodic_x,
                                                              steps):
-    """``leg_plan`` and ``run_plan`` read one schedule: the kernel calls of
-    a leg are those of the first step and of one multistep of the rest; the
-    run builds and crops a frame a call where the leg builds and crops
-    one."""
+    """``leg_plan`` and ``run_plan`` read one schedule: the kernel calls
+    and the band refreshes of a leg are those of the first step and of one
+    carried multistep of the rest, and the run builds one frame and crops
+    one, as the leg does."""
     cfg = _config((1, 1), periodic_x)
     leg = sw.leg_plan(cfg, fast, steps)
     run = sw.run_plan(cfg, fast, int(steps > 1), max(steps - 1, 1))
     first, rest = run["first_step"], run["multistep"]
     assert run["steps"] == (steps if steps > 1 else 1)
-    calls = 1 + int(steps > 1)
-    for key in ("euler_calls", "chunk_calls", "single_step_calls"):
+    for key in ("euler_calls", "chunk_calls", "single_step_calls",
+                "frames_built"):
         assert leg[key] == first[key] + (rest[key] if steps > 1 else 0), key
-    assert (first["frames_built"] + (rest["frames_built"] if steps > 1
-                                     else 0)
-            == calls * leg["frames_built"])
+    # a refresh between any two kernel calls, in one program or across two,
+    # and the one behind the run's last call, which a crop follows
+    assert (first["band_refreshes"]
+            + (rest["band_refreshes"] if steps > 1 else 0)
+            == leg["band_refreshes"] + leg["frames_built"])
+    assert (run["frames_built"], run["crops"]) == (leg["frames_built"],
+                                                   leg["crops"])
+    assert first["crops"] == rest["crops"] == 0
     assert leg["steps_per_kernel_call"] == run["steps_per_kernel_call"]
 
 
@@ -249,17 +377,25 @@ def test_solve_gives_the_snapshots_it_gave(mesh, periodic_x, fast, steps,
                                   devices=devices, fast=fast)
     assert n_steps == want_steps and wall > 0
     assert len(got) == len(want) == 3 + math.ceil((steps - 1) / num)
+    # ``solve()`` carries the frame where the loop as it was built and
+    # cropped one a call: in ``"wide2"`` the snapshots agree to rounding
+    # (the bits: the test with fusion off, above), elsewhere bit for bit
+    atol = 0 if fast is True else WIDE_ATOL
     for i, (a, b) in enumerate(zip(got, want)):
-        np.testing.assert_array_equal(a, b, f"snapshot {i}")
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-5 if atol else 0,
+                                   atol=atol, err_msg=f"snapshot {i}")
 
 
 def test_solve_without_snapshots_keeps_two_calls_in_flight(monkeypatch):
-    """Benchmark mode: no field is read on the host; before call k + 1 goes
-    the loop waits for call k - 1 (one call runs, one is queued), and one
-    ``jax.block_until_ready`` on the last state closes the run (the warm-up
-    before the clock has its own)."""
+    """Benchmark mode: no field is read on the host and nothing is cropped
+    but the last result; before call k + 1 goes the loop waits for call
+    k - 1 (one call runs, one is queued), and one ``jax.block_until_ready``
+    on the cropped state closes the run.  The warm-up before the clock is a
+    run of one multistep through the same loop: the programs it compiles
+    are the ones the timed run calls."""
     cfg = sw.Config(nx=64, ny=32, periodic_x=False)
-    waits, results = [], []
+    waits, results, crops = [], [], []
     block = jax.block_until_ready
 
     def waited(x):
@@ -268,25 +404,35 @@ def test_solve_without_snapshots_keeps_two_calls_in_flight(monkeypatch):
         return block(x)
 
     monkeypatch.setattr(jax, "block_until_ready", waited)
-    run_multisteps = sw.run_multisteps
+    make_stepper = sw.make_stepper
 
-    def counted(first_step, multistep, *args):
-        def call(program):
+    def recorded(*args, **kwargs):
+        """``make_stepper``'s pair, the calls of its carried forms kept."""
+        first_step, multistep = make_stepper(*args, **kwargs)
+
+        def keep(program, into):
             def called(*a):
-                results.append(program(*a))
-                return results[-1]
+                into.append(program(*a))
+                return into[-1]
             return called
-        return run_multisteps(call(first_step), call(multistep), *args)
 
-    monkeypatch.setattr(sw, "run_multisteps", counted)
+        first_step.carried = keep(first_step.carried, results)
+        multistep.carried = keep(multistep.carried, results)
+        multistep.crop = keep(multistep.crop, crops)
+        return first_step, multistep
+
+    monkeypatch.setattr(sw, "make_stepper", recorded)
     snaps, wall, n_steps = sw.solve(cfg, 30 * cfg.dt, devices=jax.devices()[:1],
                                     collect=False, fast="auto")
     assert snaps == [] and n_steps == 31 and wall > 0
-    # (calls dispatched so far, the call waited for): the warm-up's wait
-    # before any; call 1 before call 3 goes, call 2 before call 4; the last
-    assert waits == [(0, None), (2, 1), (3, 2), (4, 4)]
-
-
+    # (calls dispatched so far, the call waited for): the warm-up's two
+    # calls, its wait on the first before the crop goes and solve()'s on
+    # the cropped state; then call 1 of the run (the third dispatched)
+    # before its call 3 goes, call 2 before call 4, call 3 before the crop;
+    # the cropped last
+    assert waits == [(2, 1), (2, None), (4, 3), (5, 4), (6, 5), (6, None)]
+    assert len(crops) == 2  # one a run, the warm-up's and the timed one's
+    assert {f.shape for r in results for f in r} == {(1, 32 + 32, 64 + 32)}
 @pytest.mark.parametrize("seed", [2 ** 31 + 17, 5, 2 ** 31 + 4099])
 def test_a_run_agrees_with_the_walled_reference(seed):
     """48 x 24 closed basin from the benchmark's own initial state: 21 steps

@@ -46,7 +46,8 @@ def compile_leg(topo):
     """The Euler step and ``steps`` more in one region, the program's own
     ``fused_runner``, pinned for one described chip — or for the 2 x 2 with
     ``mesh=(2, 2)``, every chip holding the same local field; with
-    ``multistep=True`` the ``steps``-step program of ``make_stepper``."""
+    ``multistep=True`` the ``steps``-step program of ``make_stepper``, with
+    ``multistep="carried"`` its carried form, frames in and frames out."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     import mpi4jax_tpu as mpx
@@ -63,14 +64,20 @@ def compile_leg(topo):
             (py * px, NY + 2, NX + 2), jnp.float32,
             sharding=NamedSharding(comm.mesh,
                                    PartitionSpec(comm.mesh.axis_names)))
+        arg = sw.State(*[field] * 6)
         if multistep:
             _first_step, program = sw.make_stepper(cfg, comm, fast=mode)
+            if multistep == "carried":
+                program = program.carried
+                arg = (jax.ShapeDtypeStruct(
+                    (py * px, NY + 32, NX + 32), jnp.float32,
+                    sharding=field.sharding),) * 6
         else:
             program, _ = sw.fused_runner(cfg, comm, mode)
         cache = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
-            return mpx.compile(program, sw.State(*[field] * 6), steps)._call
+            return mpx.compile(program, arg, steps)._call
         finally:
             jax.config.update("jax_enable_compilation_cache", cache)
 
@@ -161,7 +168,8 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
     assert temp < 2.1 * SIX_FIELDS, (temp, SIX_FIELDS)
 
 
-FRAME = rf"f32\[{NY + 32},{NX + 32}\]"  # the widened frame: 15 cells a side
+FRAME = rf"f32\[(1,)?{NY + 32},{NX + 32}\]"  # the frame: 15 cells a side
+SIX_FRAMES = 6 * 4 * (NY + 32) * (NX + 32)
 
 
 def _frame_copies(lines, layout=""):
@@ -199,19 +207,9 @@ def test_the_walled_leg_builds_one_frame_and_crops_once(compile_leg):
     assert len(_kernel_calls(outside, 1, "sw_wide", euler=True)) == 1
     assert not _kernel_calls(text.splitlines())  # no whole-step kernel here
 
-    def frames_made(lines):  # a field widened to the frame
-        return [ln for ln in lines
-                if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FRAME}\S* concatenate\(",
-                            ln)]
-
-    def crops(lines):  # a frame cut back to a field
-        return [ln for ln in lines
-                if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FIELD}\S* slice\(",
-                            ln)]
-
-    assert len(frames_made(outside)) == 6 * plan["frames_built"]
-    assert len(crops(outside)) == 6 * plan["crops"]
-    assert not frames_made(body) and not crops(body)
+    assert len(_frames_made(outside)) == 6 * plan["frames_built"]
+    assert len(_crops(outside)) == 6 * plan["crops"]
+    assert not _frames_made(body) and not _crops(body)
     # the carry's frames, one spare set of them and the bands (2.26 read,
     # 2.27 with the copies): the second call writes where the first read
     temp = leg.memory_analysis().temp_size_in_bytes
@@ -264,4 +262,84 @@ def test_short_wide_runs_hold_no_more_than_the_loop(compile_leg, multistep,
     assert len(_kernel_calls(outside, 2, "sw_wide")) == in_line
     assert not _frame_copies(text.splitlines())
     temp = leg.memory_analysis().temp_size_in_bytes
+    assert temp < 2.4 * SIX_FIELDS, (temp, SIX_FIELDS)
+
+
+def _frames_made(lines):  # a field widened to the frame
+    return [ln for ln in lines
+            if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FRAME}\S* concatenate\(",
+                        ln)]
+
+
+def _crops(lines):  # a frame cut back to a field
+    return [ln for ln in lines
+            if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FIELD}\S* slice\(", ln)]
+
+
+@pytest.mark.parametrize("steps,trips,in_line", [
+    (6, [], 3),     # a call off the parameters and two rounds, inlined
+    (10, [2], 1),   # solve()'s default: the call and four rounds, two pairs
+    (12, [2], 2),   # ... and an odd round behind the loop
+])
+def test_the_carried_multistep_neither_builds_nor_crops_a_frame(
+        compile_leg, steps, trips, in_line):
+    """What ``run_multisteps`` calls 44 times a published run, at the width
+    where a frame's default layout is transposed as it is at 3600 x 28800
+    (``f32[1,8222,1054]{1,0,2}``): ``steps`` steps on the six frames the
+    call before left, six frames out.  A ``sw_wide_x2`` call straight off
+    the parameters, rounds of a band refresh and a call (two an iteration
+    of the loop), a last refresh — with no frame built, none cropped and no
+    frame copied in the loop.  What it pays beside its kernels and
+    refreshes is the boundary: **twelve** whole-frame copies, six
+    parameters into row-major and six results out of it, the number a
+    frame whose default layout is row-major would bring to 0 (ROADMAP
+    A2f)."""
+    import shallow_water as sw
+
+    carried = compile_leg("auto", steps, periodic_x=False,
+                          multistep="carried")
+    text = carried.as_text()
+    bodies, outside = _split_at_loops(text)
+    plan = sw.run_plan(sw.Config(nx=NX, ny=NY, periodic_x=False), "auto",
+                       44, steps)["multistep"]
+    rounds = steps // 2
+    assert (plan["chunk_calls"], plan["band_refreshes"],
+            plan["frames_built"], plan["crops"]) == (rounds, rounds, 0, 0)
+    assert _trip_counts(text) == trips
+    for body in bodies:
+        assert len(_kernel_calls(body, 2, "sw_wide")) == 2
+        assert not _frame_copies(body)
+        # four dynamic-update-slices a frame a refresh: the bands alone
+        assert sum(" dynamic-update-slice(" in ln for ln in body) == 2 * 24
+    assert len(_kernel_calls(outside, 2, "sw_wide")) == in_line
+    assert not _kernel_calls(outside, 1, "sw_wide", euler=True)
+    lines = text.splitlines()
+    assert not _frames_made(lines) and not _crops(lines)
+    assert len(_frame_copies(outside)) == 12
+    assert len(_frame_copies(outside, "{2,1,0")) == 6  # in: to row-major
+    assert (sum(" dynamic-update-slice(" in ln for ln in lines)
+            == 24 * (plan["band_refreshes"] - sum(trips) * 2 + 2 * len(trips)))
+    # two sets of six frames, as a leg: the parameters' row-major copy
+    # feeds a kernel call and its buffers come back to the loop (a
+    # refresh in place on it and they never do: 3.29 sets here)
+    temp = carried.memory_analysis().temp_size_in_bytes
+    assert temp < 2.4 * SIX_FRAMES, (temp, SIX_FRAMES)
+
+
+def test_stepping_by_hand_still_builds_and_crops_a_frame_a_call(
+        compile_leg):
+    """``multistep(state, 10)``, the ``State -> State`` program beside the
+    carried one, as it was: the frame built at the top (six
+    ``concatenate``s to the frame's shape), a call off the fresh frame and
+    four rounds (two pairs in the loop), six crops at the bottom."""
+    by_hand = compile_leg("auto", 10, periodic_x=False, multistep=True)
+    text = by_hand.as_text()
+    (body,), outside = _split_at_loops(text)
+    assert len(_kernel_calls(body, 2, "sw_wide")) == 2
+    assert _trip_counts(text) == [2]
+    assert len(_kernel_calls(outside, 2, "sw_wide")) == 1
+    assert len(_frames_made(outside)) == 6 and len(_crops(outside)) == 6
+    assert not _frames_made(body) and not _crops(body)
+    assert not _frame_copies(body)
+    temp = by_hand.memory_analysis().temp_size_in_bytes
     assert temp < 2.4 * SIX_FIELDS, (temp, SIX_FIELDS)
