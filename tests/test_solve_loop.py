@@ -29,6 +29,7 @@ for path in (os.path.dirname(HERE), os.path.join(HERE, "..", "examples"),
 
 import mpi4jax_tpu as mpx  # noqa: E402
 import shallow_water as sw  # noqa: E402
+from chipbench.reference import shallow_water as periodic_ref  # noqa: E402
 from chipbench.reference import shallow_water_walls as walls_ref  # noqa: E402
 from test_fused_runner import _config, _solver_tally, _unfused  # noqa: E402
 
@@ -433,17 +434,33 @@ def test_solve_without_snapshots_keeps_two_calls_in_flight(monkeypatch):
     assert waits == [(2, 1), (2, None), (4, 3), (5, 4), (6, 5), (6, None)]
     assert len(crops) == 2  # one a run, the warm-up's and the timed one's
     assert {f.shape for r in results for f in r} == {(1, 32 + 32, 64 + 32)}
+
+
+# the 48 x 24 domain of the two tests against the benchmark's plain
+# references, as a configuration file of the benchmark states it
+REFERENCE_CONFIG = {"nx": 48, "ny": 24, "dx": 5e3, "dy": 5e3, "gravity": 9.81,
+                    "depth": 100.0, "coriolis_f": 2e-4, "coriolis_beta": 2e-11,
+                    "ab_a": 1.6, "ab_b": -0.6,
+                    "scaled": {"ny": {"published": 24}}}
+
+
+def _check_scales(want, dt):
+    """What the cells' check divides a gap by: the reference's largest
+    height or speed; a tendency's times the time step first."""
+    speed = max(np.abs(want["u"]).max(), np.abs(want["v"]).max())
+    scale = {"h": np.abs(want["h"]).max(), "u": speed, "v": speed}
+    for n in ("h", "u", "v"):
+        scale["d" + n] = scale[n] / dt
+    return scale
+
+
 @pytest.mark.parametrize("seed", [2 ** 31 + 17, 5, 2 ** 31 + 4099])
 def test_a_run_agrees_with_the_walled_reference(seed):
     """48 x 24 closed basin from the benchmark's own initial state: 21 steps
     through ``run_multisteps`` on what ``auto`` picks (``wide2``) against
     the plain reference, gaps scaled as the cell's check scales them.
     Largest reading over the three seeds: 3.8e-7 (u); the limit is 1e-6."""
-    config = {"nx": 48, "ny": 24, "dx": 5e3, "dy": 5e3, "gravity": 9.81,
-              "depth": 100.0, "coriolis_f": 2e-4, "coriolis_beta": 2e-11,
-              "periodic_x": False, "ab_a": 1.6, "ab_b": -0.6,
-              "scaled": {"ny": {"published": 24}}}
-    p = walls_ref.params(config)
+    p = walls_ref.params(dict(REFERENCE_CONFIG, periodic_x=False))
     h, u, v = walls_ref.initial_fields(p, seed)
     cfg = sw.Config(nx=48, ny=24, periodic_x=False)
     _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
@@ -453,11 +470,44 @@ def test_a_run_agrees_with_the_walled_reference(seed):
     got = sw.run_multisteps(first_step, multistep, state, N_ITERS, NUM)
     want = dict(zip(walls_ref.FIELDS, (np.asarray(b) for b in
                                        walls_ref.make_run(p, 21)(h, u, v))))
-    speed = max(np.abs(want["u"]).max(), np.abs(want["v"]).max())
-    scale = {"h": np.abs(want["h"]).max(), "u": speed, "v": speed}
-    for n in ("h", "u", "v"):
-        scale["d" + n] = scale[n] / p["dt"]
+    scale = _check_scales(want, p["dt"])
     for name, a in zip(walls_ref.FIELDS, got):
         gap = np.abs(np.asarray(a)[0] - want[name]).max()
         assert gap <= 1e-6 * scale[name], (name, gap / scale[name])
     assert np.abs(want["h"] - np.asarray(h)).max() > 1.0  # it moved
+
+
+@pytest.mark.parametrize("seed", [2 ** 31 + 17, 5, 2 ** 31 + 4099])
+def test_a_run_agrees_with_the_periodic_reference(seed):
+    """48 x 24 domain, periodic in x, from the benchmark's own initial
+    state: 21 steps through ``run_multisteps`` on what ``auto`` gives one
+    periodic chip (``pallas2``: the carry is the ``State``) against the
+    plain reference — not only ``fused_runner``'s leg, which shares the
+    kernel — gaps scaled as the cell's check scales them.  Largest reading
+    over the three seeds: 5.3e-7 (u; h 2.3e-7); the limit is 1e-6 — the
+    gaps are float32 rounding over 21 steps of two independent orderings
+    of the same sums (an ulp of the 100 m height is 7.6e-8 of it), twice
+    the reading, and the limit of the walled case above.  One multistep
+    fewer reads 3.5e-3 on h."""
+    p = periodic_ref.params(dict(REFERENCE_CONFIG, periodic_x=True))
+    h, u, v = periodic_ref.initial_fields(p, seed)
+    cfg = sw.Config(nx=48, ny=24, periodic_x=True)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    zero = jnp.zeros((1, 26, 50), jnp.float32)
+    state = sw.State(*(periodic_ref.with_halo_columns(a)[None]
+                       for a in (h, u, v)), zero, zero, zero)
+    first_step, multistep = sw.make_stepper(cfg, comm, fast="auto")
+    assert multistep.carried is multistep and multistep.crop(state) is state
+    got = sw.run_multisteps(first_step, multistep, state, N_ITERS, NUM)
+    fewer = sw.run_multisteps(first_step, multistep, state, N_ITERS - 1, NUM)
+    want = dict(zip(periodic_ref.FIELDS, (
+        np.asarray(b) for b in periodic_ref.make_run(p, 21)(h, u, v))))
+    scale = _check_scales(want, p["dt"])
+    for name, a in zip(periodic_ref.FIELDS, got):
+        # the physical domain: the reference has no halo columns, and its
+        # boundary rows are the initial state's
+        gap = np.abs(np.asarray(a)[0, 1:-1, 1:-1] - want[name][1:-1]).max()
+        assert gap <= 1e-6 * scale[name], (name, gap / scale[name])
+    assert np.abs(np.asarray(fewer.h)[0, 1:-1, 1:-1]
+                  - want["h"][1:-1]).max() > 1e-4 * scale["h"]
+    assert np.abs(want["h"] - np.asarray(h)).max() > 1e-2  # it moved

@@ -168,6 +168,35 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
     assert temp < 2.1 * SIX_FIELDS, (temp, SIX_FIELDS)
 
 
+def test_the_periodic_ten_step_call_copies_fields_at_its_boundary_only(
+        compile_leg):
+    """The call the documented host loop makes 44 times a run on one
+    periodic chip (``make_stepper(cfg, comm, fast="auto")``'s ``multistep``
+    at ``num_multisteps`` = 10, the benchmark's
+    ``sw3600x28800_solve.1chip``): five two-step chunks — a loop of two
+    trips with two kernel calls in its body, the fifth call behind it — no
+    field copied in the loop, twelve at the most at the region's entry and
+    exit (changes of layout of the six parameters and the six results: 6
+    at this size, 12 at 3600 x 28800, where they are 15 % of the call),
+    and two spare sets of fields at the most beside arguments and results
+    (1.00 here, 2.05 at 3600 x 28800: 5,133,318,144 B, which is what lets
+    four states and a call's temporaries fit a 16.9e9-byte chip; both
+    sizes compiled for a described v5e, PR 35)."""
+    call = compile_leg("auto", 10, multistep=True)
+    text = call.as_text()
+    (body,), entry = _split_at_loops(text)
+    assert _trip_counts(text) == [2]
+    assert len(_kernel_calls(body)) == 2 and len(_kernel_calls(entry)) == 1
+    assert not _kernel_calls(entry, 1) and not _kernel_calls(entry, 1,
+                                                             euler=True)
+    assert not _field_copies(body)
+    assert len(_field_copies(entry)) <= 12, _field_copies(entry)
+    mem = call.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.1 * SIX_FIELDS, (
+        mem.temp_size_in_bytes, SIX_FIELDS)
+    assert mem.argument_size_in_bytes < 1.01 * SIX_FIELDS
+
+
 FRAME = rf"f32\[(1,)?{NY + 32},{NX + 32}\]"  # the frame: 15 cells a side
 SIX_FRAMES = 6 * 4 * (NY + 32) * (NX + 32)
 
