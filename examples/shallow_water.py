@@ -1198,10 +1198,44 @@ def _wide_exchange(fields, cfg: Config, comm: mpx.Comm, m: int, token):
     Edge ranks of non-wrapping directions get a zeros template
     (``MPI_PROC_NULL`` semantics); those cells are beyond-wall garbage
     that the wide masks keep out of every valid cell.
+
+    The frame is then **aligned**: beyond the east margin come
+    ``-nx_w % 128`` *dead* columns and beyond the north margin
+    ``-ny_w % 8`` dead rows (80 columns and no row at 3600 x 28800:
+    3712 columns, 29 lane tiles), so that a frame's shape is a whole
+    number of ``(8, 128)`` tiles.  A ``T(8,128)`` array of 3632 columns
+    occupies 3712 in HBM anyway; as part of the *shape* they make the
+    layout XLA:TPU gives a frame at a program's boundary the row-major one
+    the kernel reads and writes, so a host loop that carries the frame
+    from call to call (``run_multisteps``) copies none at either end
+    (twelve whole-frame copies a call otherwise, 12.7 % of the walled
+    3600 x 28800 run: PERF.md section 6, PR 36).  Two things hold of the
+    dead cells, which no refresh ever writes and every kernel call
+    recomputes from themselves:
+
+    - *they reach nothing.*  What a call makes of them moves ``m - 1``
+      cells at the most (the depth ``_margin_rows`` is sized for): through
+      the east (north) margin, which is ``m - 1`` deep and refreshed before
+      the next call, and — a ``roll`` wraps — onto the west (south) margin,
+      ``m - 1`` deep and refreshed as well.  It is the argument that keeps
+      the frame's own edge out of the crop region, one band further out;
+      all of them lie on the high side, so every index below them is what
+      it was (tests/test_wide_dead_cells.py overwrites them with ``nan``
+      and finds the same bits);
+    - *they are filled with data the stencil is finite on*: each repeats
+      the last margin column (row) beside it.  At a wall the masks keep
+      them as built, but on a periodic or an interior rank nothing in the
+      masks knows them and they are advanced like any cell; on a zero fill
+      (a depth of 0) the unselected branch of a ``where`` is not finite
+      and ``jax.grad`` through a multistep returns ``nan``.  Masking them
+      in the kernel would cost a select a field a step of the vector work
+      that bounds it.
     """
     nyl, nxl = cfg.ny_local, cfg.nx_local
     commx, commy = comm.sub("px"), comm.sub("py")
     wrap_x = cfg.periodic_x
+    # dead cells: what the margins lack to a whole (8, 128) tile
+    dead_y, dead_x = -(nyl + 2 * (m - 1)) % 8, -(nxl + 2 * (m - 1)) % 128
 
     # ---- x phase: (6, nyl, m) strips --------------------------------
     lo = jnp.stack([f[:, 1:m + 1] for f in fields])
@@ -1213,10 +1247,13 @@ def _wide_exchange(fields, cfg: Config, comm: mpx.Comm, m: int, token):
     wx = []
     for k, f in enumerate(fields):
         w, e = from_west[k], from_east[k]
+        # dead columns ride in the same concatenate: no second pass
+        dead = [jnp.broadcast_to(e[:, -1:], (nyl, dead_x))] if dead_x else []
         if k < 3:  # state: local halo ring kept in place
-            wx.append(jnp.concatenate([w[:, :m - 1], f, e[:, 1:]], axis=1))
+            parts = [w[:, :m - 1], f, e[:, 1:]]
         else:  # tendency: the strip supplies the halo position
-            wx.append(jnp.concatenate([w, f[:, 1:-1], e], axis=1))
+            parts = [w, f[:, 1:-1], e]
+        wx.append(jnp.concatenate(parts + dead, axis=1))
 
     # ---- y phase: (6, m, nx_w) strips of the x-widened arrays -------
     lo = jnp.stack([f[1:m + 1] for f in wx])
@@ -1226,10 +1263,13 @@ def _wide_exchange(fields, cfg: Config, comm: mpx.Comm, m: int, token):
     out = []
     for k, f in enumerate(wx):
         s, n = from_south[k], from_north[k]
+        dead = ([jnp.broadcast_to(n[-1:], (dead_y, f.shape[1]))]
+                if dead_y else [])
         if k < 3:
-            out.append(jnp.concatenate([s[:m - 1], f, n[1:]], axis=0))
+            parts = [s[:m - 1], f, n[1:]]
         else:
-            out.append(jnp.concatenate([s, f[1:-1], n], axis=0))
+            parts = [s, f[1:-1], n]
+        out.append(jnp.concatenate(parts + dead, axis=0))
     return tuple(out), token
 
 
@@ -1305,7 +1345,6 @@ def _wide_kernel_call(wfields, cfg: Config, first_step: bool, nsteps: int,
 
     ny_w, nx_w = wfields[0].shape
     off = _rank_offsets(cfg) - (m - 1)  # widened-frame global offsets
-    vma = frozenset(getattr(jax.typeof(wfields[0]), "vma", frozenset()))
 
     if interpret:
         iy = jax.lax.broadcasted_iota(jnp.int32, (ny_w, nx_w), 0)
@@ -1321,12 +1360,17 @@ def _wide_kernel_call(wfields, cfg: Config, first_step: bool, nsteps: int,
     grid, main_spec, prev_spec, next_spec = _blocked_specs(ny_w, nx_w, m)
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     operands = [off]
+    # Frames in and out stay in HBM.  Left to itself XLA:TPU parks a frame
+    # that fits (27 MB at 3600 x 1800) in VMEM as the kernel's operand or
+    # result, beside a kernel that fills 96 MB of the 128; on the aligned
+    # frame the first such call never ended, at 1800 and at 900 rows, and
+    # took the chip with it, while the same program with no frame of the
+    # kernel's in VMEM ran (PERF.md section 6, PR 36; why is not known).
     for f in wfields:
+        f = pltpu.with_memory_space_constraint(f, pltpu.HBM)
         in_specs += [prev_spec, main_spec, next_spec]
         operands += [f, f, f]
-    out_shape = [
-        jax.ShapeDtypeStruct((ny_w, nx_w), jnp.float32, vma=vma)
-    ] * 6
+    out_shape = [pltpu.HBM((ny_w, nx_w), jnp.float32)] * 6
     return pl.pallas_call(
         lambda *refs: _sw_wide_kernel(cfg, first_step, m, nsteps, refs),
         name=_kernel_name("sw_wide", nsteps, first_step),
@@ -1362,22 +1406,32 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
     is valid but the ``m - 1``-deep margins are recompute garbage.
     ``_wide_run`` therefore never crops between calls: it exchanges just
     the margin bands — four messages of ``(6, ·, m-1)`` — and writes them
-    over the margins with ``.at[].set``, which XLA does in place (a
-    ``dynamic-update-slice`` of the band alone, the interior untouched),
-    so the full-array concat/crop copies of building and cropping the
-    frame happen once per RUN instead of once per pair of steps.
+    over the margins, which XLA does in place (a ``dynamic-update-slice``
+    of the band alone, the interior untouched), so the full-array
+    concat/crop copies of building and cropping the frame happen once per
+    RUN instead of once per pair of steps.  The margins are the ``m - 1``
+    cells either side of the local field: the east band is columns
+    ``[e + nxl, e + nxl + e)`` and the north one rows ``[e + nyl, e + nyl
+    + e)``, not "to the end" — beyond them lie the dead cells that align
+    the frame (``_wide_exchange``), which no band writes.  The update is
+    ``lax.dynamic_update_slice`` itself and not ``.at[].set``: a scatter's
+    out-of-bounds rule comes out of XLA:TPU as a ``select`` between the
+    band and the cells it replaces, and where the band is a slice of the
+    same frame (one rank, periodic in x) and does not end the array, that
+    ``select`` keeps the frame as it was alive beside the updated one —
+    a whole-frame copy a field a refresh (tests/test_solver_loop_hlo.py).
 
     Two-phase for corners: x bands first (their corner rows are the
     sender's own garbage y-margins), then y bands at full widened width —
     sliced *after* the x update, so their corner columns carry the
-    y-neighbor's freshly refreshed x margins (= diagonal-neighbor data).
+    y-neighbor's freshly refreshed x margins (= diagonal-neighbor data);
+    a band's dead cells are whatever the neighbor's were, and never read.
     In the carried frame the state/tendency assembly distinction of
     ``_wide_exchange`` disappears: the halo-position ring is valid
     post-kernel (computed as the owner computes it) and is not touched.
     """
     e = m - 1
     nyl, nxl = cfg.ny_local, cfg.nx_local
-    ny_w, nx_w = wf[0].shape
     commx, commy = comm.sub("px"), comm.sub("py")
     wrap_x = cfg.periodic_x
 
@@ -1393,8 +1447,9 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
         jnp.stack([f[:, e + 2:2 * e + 2] for f in wf]),
         shift(-1, wrap=wrap_x), commx, token,
     )
+    put = jax.lax.dynamic_update_slice
     wf = tuple(
-        f.at[:, :e].set(from_west[k]).at[:, e + nxl:].set(from_east[k])
+        put(put(f, from_west[k], (0, 0)), from_east[k], (0, e + nxl))
         for k, f in enumerate(wf)
     )
 
@@ -1408,7 +1463,7 @@ def _wide_refresh(wf, cfg: Config, comm: mpx.Comm, m: int, token):
         shift(-1, wrap=False), commy, token,
     )
     return tuple(
-        f.at[:e, :].set(from_south[k]).at[e + nyl:, :].set(from_north[k])
+        put(put(f, from_south[k], (0, 0)), from_north[k], (e + nyl, 0))
         for k, f in enumerate(wf)
     )
 
@@ -1447,10 +1502,14 @@ def _wide_run(state, num_steps: int, cfg: Config, comm: mpx.Comm,
     frames, their margins refreshed behind the last kernel call, and crops
     nothing.  So a frame handed on has valid margins as a just-built one
     has, and the next call's first kernel call runs straight off its
-    parameters — for what XLA:TPU makes of such a program at 3600 x 28800
-    (PERF.md section 6, PR 34): a refresh in place as the first instruction
-    on a parameter's row-major copy costs a third set of six frames (7.71
-    GB of temporaries for 5.14: the copy's buffers are then never reused).
+    parameters, with no copy between: the frame is aligned to ``(8, 128)``
+    tiles (``_wide_exchange``), so the layout it has at a call's boundary
+    is the kernel's own (PERF.md section 6, PR 36).  The refresh goes
+    behind the last kernel call and not before the first for what XLA:TPU
+    made of the other order at 3600 x 28800 while the boundary still
+    copied the frames (PR 34): a refresh in place as the first instruction
+    on a parameter's row-major copy cost a third set of six frames (7.71
+    GB of temporaries for 5.14: the copy's buffers were never reused).
     The flags live here, not in a function of their own between the
     region and the kernel calls: one Python frame more put 0.8 s on the
     walled leg's warm ``setup_s`` (PERF.md section 6, PR 30 and PR 34).

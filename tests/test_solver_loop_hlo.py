@@ -70,7 +70,7 @@ def compile_leg(topo):
             if multistep == "carried":
                 program = program.carried
                 arg = (jax.ShapeDtypeStruct(
-                    (py * px, NY + 32, NX + 32), jnp.float32,
+                    (py * px, FRAME_NY, FRAME_NX), jnp.float32,
                     sharding=field.sharding),) * 6
         else:
             program, _ = sw.fused_runner(cfg, comm, mode)
@@ -197,8 +197,11 @@ def test_the_periodic_ten_step_call_copies_fields_at_its_boundary_only(
     assert mem.argument_size_in_bytes < 1.01 * SIX_FIELDS
 
 
-FRAME = rf"f32\[(1,)?{NY + 32},{NX + 32}\]"  # the frame: 15 cells a side
-SIX_FRAMES = 6 * 4 * (NY + 32) * (NX + 32)
+# the frame: 15 cells a side, then rows up to a multiple of 8 and columns
+# to one of 128 (``_wide_exchange``'s dead cells): 8222 x 1054 -> 8224 x 1152
+FRAME_NY, FRAME_NX = NY + 32 + -(NY + 32) % 8, NX + 32 + -(NX + 32) % 128
+FRAME = rf"f32\[(1,)?{FRAME_NY},{FRAME_NX}\]"
+SIX_FRAMES = 6 * 4 * FRAME_NY * FRAME_NX
 
 
 def _frame_copies(lines, layout=""):
@@ -294,6 +297,18 @@ def test_short_wide_runs_hold_no_more_than_the_loop(compile_leg, multistep,
     assert temp < 2.4 * SIX_FIELDS, (temp, SIX_FIELDS)
 
 
+def _entry_layouts(text):
+    """The parameters' and the results' shapes with their layouts, in
+    order, from the module's ``entry_computation_layout``."""
+    line = text.splitlines()[0]
+    start = end = line.index("entry_computation_layout={") + 26
+    depth = 1
+    while depth:  # to the brace that closes the attribute
+        depth += {"{": 1, "}": -1}.get(line[end], 0)
+        end += 1
+    return re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", line[start:end])
+
+
 def _frames_made(lines):  # a field widened to the frame
     return [ln for ln in lines
             if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FRAME}\S* concatenate\(",
@@ -312,17 +327,19 @@ def _crops(lines):  # a frame cut back to a field
 ])
 def test_the_carried_multistep_neither_builds_nor_crops_a_frame(
         compile_leg, steps, trips, in_line):
-    """What ``run_multisteps`` calls 44 times a published run, at the width
-    where a frame's default layout is transposed as it is at 3600 x 28800
-    (``f32[1,8222,1054]{1,0,2}``): ``steps`` steps on the six frames the
-    call before left, six frames out.  A ``sw_wide_x2`` call straight off
-    the parameters, rounds of a band refresh and a call (two an iteration
-    of the loop), a last refresh — with no frame built, none cropped and no
-    frame copied in the loop.  What it pays beside its kernels and
-    refreshes is the boundary: **twelve** whole-frame copies, six
-    parameters into row-major and six results out of it, the number a
-    frame whose default layout is row-major would bring to 0 (ROADMAP
-    A2f)."""
+    """What ``run_multisteps`` calls 44 times a published run, at a width
+    whose unaligned frame XLA:TPU would hand over transposed, as it did at
+    3600 x 28800 (``f32[1,8222,1054]{1,2,0}``; aligned it is
+    ``f32[1,8224,1152]``): ``steps`` steps on the six frames the call
+    before left, six frames out.  A ``sw_wide_x2`` call straight off the
+    parameters, rounds of a band refresh and a call (two an iteration of
+    the loop), a last refresh — with no frame built, none cropped and no
+    frame copied, in the loop or at the boundary: the aligned frame's
+    default layout there is the row-major one the kernel reads and writes
+    (**0** whole-frame copies where the unaligned frame paid twelve, six
+    parameters in and six results out: PERF.md section 6, PR 36), and the
+    call holds one set of six frames beside its parameters and results
+    where the copies' buffers made it two."""
     import shallow_water as sw
 
     carried = compile_leg("auto", steps, periodic_x=False,
@@ -344,15 +361,18 @@ def test_the_carried_multistep_neither_builds_nor_crops_a_frame(
     assert not _kernel_calls(outside, 1, "sw_wide", euler=True)
     lines = text.splitlines()
     assert not _frames_made(lines) and not _crops(lines)
-    assert len(_frame_copies(outside)) == 12
-    assert len(_frame_copies(outside, "{2,1,0")) == 6  # in: to row-major
+    assert not _frame_copies(lines)
+    boundary = _entry_layouts(text)
+    assert boundary == [
+        f"f32[1,{FRAME_NY},{FRAME_NX}]{{2,1,0:T(8,128)}}"] * 12, boundary
     assert (sum(" dynamic-update-slice(" in ln for ln in lines)
             == 24 * (plan["band_refreshes"] - sum(trips) * 2 + 2 * len(trips)))
-    # two sets of six frames, as a leg: the parameters' row-major copy
-    # feeds a kernel call and its buffers come back to the loop (a
-    # refresh in place on it and they never do: 3.29 sets here)
+    # one set of six frames: the first kernel call reads the parameters
+    # where they lie and the last refresh writes the results' own buffers
+    # (1.00 sets here; 2,568,811,520 B at 3600 x 28800, where the
+    # unaligned frame's call held 5,137,299,456)
     temp = carried.memory_analysis().temp_size_in_bytes
-    assert temp < 2.4 * SIX_FRAMES, (temp, SIX_FRAMES)
+    assert temp < 1.2 * SIX_FRAMES, (temp, SIX_FRAMES)
 
 
 def test_stepping_by_hand_still_builds_and_crops_a_frame_a_call(
