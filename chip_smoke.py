@@ -339,18 +339,26 @@ def _flagship(ctx, periodic_x, host_loop=False):
         wall = time.perf_counter() - start
         check(n_steps == n_want, f"planned {n_steps} steps, not {n_want}")
     else:
-        before = mpx.cache_stats()["aot"]
+        from mpi4jax_tpu.utils import profiling
+
+        before, kept = mpx.cache_stats()["aot"], len(profiling.builds())
         wall, n_steps, out = sw.solve_fused(
             cfg, t1, num_multisteps=multisteps, devices=ctx["devices"],
             fast="auto", pinned=True, return_state=True)
         after = mpx.cache_stats()["aot"]
         check(n_steps == n_want, f"ran {n_steps} steps, not {n_want}")
-        # the pinned artifact is what ran: one pin, compiled here, called
+        # the pinned artifact is what ran: one pin — compiled here, or
+        # fetched from jax's persistent cache by a later process — called
         # for the warm-up and for the timed run
         delta = {k: after[k] - before[k]
                  for k in ("pins", "compiles", "calls")}
-        check(delta == {"pins": 1, "compiles": 1, "calls": 2},
-              f"pinned program accounting {delta}")
+        origins = [r["attrs"].get("origin")
+                   for r in profiling.builds()[kept:]
+                   if r["attrs"].get("kind") == "pin"]
+        check(origins in (["compiled"], ["jax_cache"])
+              and delta == {"pins": 1, "calls": 2,
+                            "compiles": int(origins == ["compiled"])},
+              f"pinned program accounting {delta}, from {origins}")
 
     # the plain jnp step, same steps, same devices
     _, n_ref, ref = sw.solve_fused(

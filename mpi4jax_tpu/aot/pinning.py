@@ -34,12 +34,14 @@ to the jit path (pinned by tests/test_aot.py).
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..utils.profiling import span as _span, tracing as _tracing
+from ..utils.profiling import (account as _account, span as _span,
+                               tracing as _tracing)
 from . import diskcache, keys, serialization
 from .invalidation import StaleProgramError, WorldStamp
 
@@ -75,9 +77,12 @@ _stats = _Stats()
 def stats() -> dict:
     """AOT-layer counters: ``pins`` (programs pinned), ``calls`` (pinned
     executions), ``stale_raises`` (MPX129 refusals), ``disk_loads``
-    (pins served by deserializing a persistent artifact), ``compiles``
-    (pins that lowered+compiled fresh), ``warmed`` (programs
-    pre-compiled by the cache-warming CLI — aot/warm.py)."""
+    (pins served by deserializing an artifact of the package's disk
+    tier), ``compiles`` (pins whose executable XLA compiled in this
+    process: one fetched from jax's persistent cache, or held by jax in
+    memory, counts under ``cache_stats()["builds"]["by_origin"]`` only),
+    ``warmed`` (programs pre-compiled by the cache-warming CLI —
+    aot/warm.py)."""
     return {k: getattr(_stats, k) for k in _Stats.__slots__}
 
 
@@ -227,9 +232,14 @@ def _pin_executable(jitted, mesh, avals, label: str,
     dispatch per call, so the MPX128 hot-loop advisory must keep firing
     for them — only a true ``mpx.compile`` pin is exempt.
     """
+    # the build path's spans are kept: recorded with or without a profiler
+    # session, with where the executable came from (utils/profiling.py).
+    # Only an ``mpx.compile`` pin is a build of its own (``kind``); a
+    # region's disk consult lies inside that region's ``mpx.build``
+    kind = {"kind": "pin"} if mark_pinned else {}
     with (_pinned_trace_scope() if mark_pinned else _null_scope()), \
-            _span("mpx.pin", program=label):
-        with _span("mpx.pin.trace"):
+            _span("mpx.pin", keep=True, program=label, **kind):
+        with _span("mpx.pin.trace", keep=True):
             traced = jitted.trace(*avals)
         key = None
         if diskcache.enabled():
@@ -244,11 +254,18 @@ def _pin_executable(jitted, mesh, avals, label: str,
             payload = diskcache.get(key)
             if payload is not None:
                 _stats.disk_loads += 1
-                with _span("mpx.pin.load"):
-                    return serialization.loads(payload), key, True
-        with _span("mpx.pin.compile"):
-            compiled = traced.lower().compile()
-        _stats.compiles += 1
+                with _span("mpx.pin.load", keep=True):
+                    began = time.perf_counter()
+                    loaded = serialization.loads(payload)
+                    _account("fetch_s", time.perf_counter() - began,
+                             "package_cache")
+                    return loaded, key, True
+        with _span("mpx.pin.lower", keep=True):
+            lowered = traced.lower()
+        with _span("mpx.pin.compile", keep=True) as built:
+            compiled = lowered.compile()
+        if built["attrs"].get("origin") == "compiled":
+            _stats.compiles += 1
         if key is not None:
             diskcache.put(key, serialization.dumps(compiled))
         return compiled, key, False
@@ -364,6 +381,20 @@ class PinnedProgram:
     def is_stale(self) -> bool:
         """Non-raising probe: would the next call raise MPX129?"""
         return not self._world.is_current()
+
+    def memory_bytes(self) -> Optional[dict]:
+        """What the pinned executable needs on a device by XLA's own
+        account (``memory_analysis()``), read on demand: ``temporaries``
+        (scratch the program holds while it runs, which a device's
+        ``memory_stats()`` leaves out), ``arguments``, ``results`` and
+        ``code``, in bytes; ``None`` where the backend gives no account."""
+        analysis = self._call.memory_analysis()
+        if analysis is None:
+            return None
+        return {"temporaries": int(analysis.temp_size_in_bytes),
+                "arguments": int(analysis.argument_size_in_bytes),
+                "results": int(analysis.output_size_in_bytes),
+                "code": int(analysis.generated_code_size_in_bytes)}
 
     def repin(self) -> "PinnedProgram":
         """Re-lower/re-compile (or re-load from the persistent tier)

@@ -35,6 +35,7 @@ from ..parallel.region import (
 )
 from ..utils.debug import get_logging, get_runtime_tracing, op_scope
 from ..utils.dtypes import check_dtype
+from ..utils import profiling as _profiling
 from ..utils.profiling import span as _span, tracing as _tracing
 
 # the trace-time collective verifier and the telemetry layer ride the same
@@ -572,7 +573,14 @@ def cache_stats() -> dict:
     - ``"disk_cache"``: the persistent tier
       (``MPI4JAX_TPU_COMPILE_CACHE_DIR`` — hits/misses/writes/
       evictions/bytes plus the on-disk entry count), the before/after
-      evidence for cold-start behavior (docs/aot.md).
+      evidence for cold-start behavior (docs/aot.md);
+    - ``"builds"``: programs built in this process — a pin, the first
+      call of a region's or an eager op's new program — ``by_kind``
+      (``pin``, ``region``, ``eager``) and ``by_origin``: ``compiled``
+      (XLA compiled here), ``jax_cache`` (fetched from jax's persistent
+      cache), ``package_cache`` (from the disk tier above), ``memory``
+      (jax had the executable already).  The records behind the counts,
+      with the seconds of each stage: ``utils.profiling.builds()``.
 
     Reset by ``clear_caches()`` (on-disk artifacts are untouched).
     """
@@ -580,6 +588,7 @@ def cache_stats() -> dict:
     from ..aot import stats as _aot_stats
 
     out.update(_aot_stats())
+    out["builds"] = _profiling.build_counts()
     return out
 
 
@@ -591,7 +600,8 @@ def _bump_cache_stat(name: str, telemetry_off: bool = False) -> None:
 
 def clear_caches() -> None:
     """Drain the eager one-op compiled-program cache (resetting its
-    hit/miss/eviction stats) and the memoized ``mpx.analyze`` reports.
+    hit/miss/eviction stats, the AOT counters and the kept build records
+    with their counts) and the memoized ``mpx.analyze`` reports.
 
     Each eager entry pins a compiled executable plus its mesh; call this
     after retiring a mesh, or when flipping a trace-shaping environment
@@ -612,6 +622,7 @@ def clear_caches() -> None:
     from ..aot import reset_stats as _aot_reset
 
     _aot_reset()
+    _profiling.clear_builds()
 
 
 # lanes of a TPU vector register: the minor extent of every tiled layout
@@ -850,13 +861,16 @@ def _dispatch_eager(opname: str, comm: Comm, body, arrays, token,
     # insert into the cache only after the first call succeeds — a
     # trace/compile failure must not leave a broken entry to be replayed
     tele_cell = _telemetry.EagerCell()
-    if telemetry_off:
-        results, tok_out = _launch(sm, arrays, token)
-    else:
-        sig = _telemetry.call_signature(arrays)
-        with _telemetry.capture_eager(tele_cell, sig):
+    # the new program's first call, under a kept span: jax's trace, lower
+    # and fetch or compile and that call's launch (utils/profiling.py)
+    with _span("mpx.build", keep=True, program=opname, kind="eager"):
+        if telemetry_off:
             results, tok_out = _launch(sm, arrays, token)
-        _telemetry.count_eager_call(tele_cell, sig)
+        else:
+            sig = _telemetry.call_signature(arrays)
+            with _telemetry.capture_eager(tele_cell, sig):
+                results, tok_out = _launch(sm, arrays, token)
+            _telemetry.count_eager_call(tele_cell, sig)
     if cache_key is not None:
         _eager_cache[cache_key] = (sm, tele_cell)
         if len(_eager_cache) > _EAGER_CACHE_MAX:

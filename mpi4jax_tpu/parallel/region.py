@@ -235,6 +235,25 @@ def _launched(program):
     return launch
 
 
+def _first_call(program, name, launch=False):
+    """The first call of a region's new program, under the kept span
+    ``mpx.build``: it holds jax's trace, lower and fetch or compile *and*
+    that call's launch (utils/profiling.py says what of each).  The
+    program cache keeps the program itself: a hit pays nothing for this.
+    ``launch`` puts the call under ``mpx.launch`` here, in the place of
+    ``_launched``'s frame and not above it: a Python frame more over a
+    trace makes the trace slower (PERF.md section 6, PR 37)."""
+
+    def build(*args):
+        with _span("mpx.build", keep=True, program=name, kind="region"):
+            if not launch:
+                return program(*args)
+            with _span("mpx.launch"):
+                return program(*args)
+
+    return build
+
+
 def spmd(
     fn=None,
     *,
@@ -434,9 +453,14 @@ def spmd(
                         from ..aot import pinning as _pinning
 
                         sm = _pinning.through_disk_cache(sm, c, label=name)
+                        first = _first_call(sm, name)
                     else:
+                        first = _first_call(sm, name, launch=True)
                         sm = _launched(sm)
+                else:
+                    first = sm
                 program_cache[key] = sm
+                sm = first
             return sm, (*dyn_args, *(kwargs[k] for k in kw_names))
 
         @functools.wraps(f)

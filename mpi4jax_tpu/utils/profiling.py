@@ -27,11 +27,17 @@ it.  The context manager blocks on every live array before closing, which
 fences all outstanding device work into the captured window.
 
 ``span`` is the one host-span primitive of the call path (a call of a
-pinned program, of an ``mpx.spmd`` function, of an eager op; a pin).  It is
-on while a profiler session runs and is one flag test otherwise: see
-docs/observability.md "Host spans of the call path".
+pinned program, of an ``mpx.spmd`` function, of an eager op) and of the
+build path (a pin; the first call of a region's or an eager op's new
+program).  A call is frequent and fast: its spans are on while a profiler
+session runs and one flag test otherwise.  A build is rare and slow (a
+handful a process, 0.4 to 100 s each): its spans are *kept* — recorded with
+or without a session, with what jax itself says of where the executable
+came from (:func:`builds`).  See docs/observability.md "Host spans of the
+call path".
 """
 
+import collections
 import contextlib
 import itertools
 import os
@@ -41,7 +47,8 @@ import time
 import jax
 
 __all__ = ["profile_ops", "ProfileSummary", "span", "spans", "clear_spans",
-           "spans_dropped", "tracing"]
+           "spans_dropped", "tracing", "builds", "builds_dropped",
+           "build_counts", "clear_builds", "account"]
 
 
 # ---------------------------------------------------------------------------
@@ -49,15 +56,19 @@ __all__ = ["profile_ops", "ProfileSummary", "span", "spans", "clear_spans",
 # ---------------------------------------------------------------------------
 
 SPAN_CAP = 1 << 16  # records kept per session; beyond it, dropped and counted
+BUILD_CAP = 1 << 12  # kept records per process; beyond it, dropped and counted
 
 _session_running = jax.profiler.TraceAnnotation.is_enabled
 _ids = itertools.count(1)
-_open = threading.local()  # .stack: the spans open on this thread
-_lock = threading.Lock()  # the buffer's restart and its count of drops
+# .stack: the records of the spans open on this thread; .kept: the kept
+# spans among them
+_open = threading.local()
+_lock = threading.Lock()  # a buffer's restart, its count of drops, the counts
 
 
 class _Buffer:
-    """The records of the newest profiler session."""
+    """The records of the newest profiler session (``_buffer``), or the
+    kept records of the process (``_builds``)."""
 
     __slots__ = ("records", "dropped", "live")
 
@@ -72,6 +83,10 @@ class _Buffer:
 
 
 _buffer = _Buffer()
+_builds = _Buffer()
+# builds closed, by ``kind`` and by ``origin``: cache_stats()["builds"]
+_build_counts = {"by_kind": collections.Counter(),
+                 "by_origin": collections.Counter()}
 
 
 # what ``span`` hands out while no session runs: nothing is made, nothing
@@ -80,30 +95,36 @@ _OFF = contextlib.nullcontext()
 
 
 class _Span:
-    """A span while a session runs.  ``start_ns`` is read before anything
-    is made and ``end_ns`` after everything is closed, so the record
-    encloses its own annotation and bookkeeping: a parent's self time
-    holds what its children's spans cost, and a span that is the first
-    thing in a caller's own annotation starts a constant after it."""
+    """A span that records: while a session runs (``annotation``), or kept
+    (``events``), or both.  ``start_ns`` is read before anything is made
+    and ``end_ns`` after everything is closed, so the record encloses its
+    own annotation and bookkeeping: a parent's self time holds what its
+    children's spans cost, and a span that is the first thing in a
+    caller's own annotation starts a constant after it."""
 
-    __slots__ = ("record", "annotation")
+    __slots__ = ("record", "annotation", "events")
 
-    def __init__(self, name, attrs):
+    def __init__(self, name, attrs, session, keep):
         start_ns = time.time_ns()
         self.record = {"name": name, "start_ns": start_ns, "end_ns": 0,
                        "id": next(_ids), "parent": None, "call": 0,
                        "attrs": attrs}
-        self.annotation = jax.profiler.TraceAnnotation(name, **attrs)
+        self.annotation = (jax.profiler.TraceAnnotation(name, **attrs)
+                           if session else None)
+        # kept: jax's timed events under this span, innermost last, as
+        # (start, seconds, key) — see ``account``
+        self.events = [] if keep else None
 
     def __enter__(self):
-        if not _buffer.live:  # the first span of a session starts it afresh
-            with _lock:
+        if self.annotation is not None and not _buffer.live:
+            with _lock:  # the first span of a session starts it afresh
                 if not _buffer.live:
                     _buffer.clear()
                     _buffer.live = True
         stack = getattr(_open, "stack", None)
         if stack is None:
             stack = _open.stack = []
+            _open.kept = []
         record = self.record
         if stack:
             record["parent"] = stack[-1]["id"]
@@ -111,20 +132,123 @@ class _Span:
         else:
             record["call"] = record["id"]
         stack.append(record)
-        self.annotation.__enter__()
+        if self.events is not None:
+            kept = _open.kept
+            record["attrs"]["build"] = (kept[0].record["id"] if kept
+                                        else record["id"])
+            kept.append(self)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         return record
 
     def __exit__(self, *exc):
-        self.annotation.__exit__(*exc)
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         _open.stack.pop()
         record = self.record
         record["end_ns"] = time.time_ns()
-        if len(_buffer.records) < SPAN_CAP:
-            _buffer.records.append(record)
-        else:
-            with _lock:
-                _buffer.dropped += 1
+        if self.annotation is not None:
+            if len(_buffer.records) < SPAN_CAP:
+                _buffer.records.append(record)
+            else:
+                with _lock:
+                    _buffer.dropped += 1
+        if self.events is not None:
+            _close_kept(record)
         return False
+
+
+# ---------------------------------------------------------------------------
+# kept spans: the build path, and where its executables came from
+# ---------------------------------------------------------------------------
+
+# jax's own timed events of a build (jax/_src/dispatch.py,
+# jax/_src/compiler.py), each with the attribute its seconds go to and the
+# origin it tells of; none fires on a warm call
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("trace_s", None),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lower_s", None),
+    "/jax/core/compile/backend_compile_duration": ("compile_s", "compiled"),
+    "/jax/compilation_cache/cache_retrieval_time_sec": ("fetch_s",
+                                                        "jax_cache"),
+}
+_SECONDS = tuple(key for key, _ in _JAX_EVENTS.values())
+_listening = False
+
+
+def _listen():
+    """Register the one listener, at the first kept span."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    import jax.monitoring
+
+    def on_duration(event, seconds, **_kw):
+        stage = _JAX_EVENTS.get(event)
+        if stage is not None:
+            account(stage[0], seconds, stage[1])
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def account(key: str, seconds: float, origin: str = None) -> None:
+    """Add ``seconds`` of a build's stage that has just ended (``key``:
+    ``trace_s``, ``lower_s``, ``compile_s`` or ``fetch_s``) to the
+    innermost kept span open on this thread; nothing where none is open.
+
+    Every second is counted once, under the innermost stage that held it:
+    a stage that encloses stages already accounted (a trace inside which
+    a helper was traced, or compiled) adds its own time only.  jax 0.9.0
+    closes ``backend_compile_duration`` round a fetch from its persistent
+    cache too (after ``cache_retrieval_time_sec``): such a "compile" is
+    the fetch's, ``compile_s`` stays 0 and the origin ``"jax_cache"``.
+    ``origin`` says where an executable came from: ``"compiled"`` wins
+    over the others, which stay as first given."""
+    kept = getattr(_open, "kept", None)
+    if not kept:
+        return
+    span_ = kept[-1]
+    start = time.monotonic() - seconds
+    events, inside = span_.events, 0.0
+    while events and events[-1][0] >= start:
+        _, held, held_key = events.pop()
+        inside += held
+        if key == "compile_s" and held_key == "fetch_s":
+            key, origin = "fetch_s", "jax_cache"
+    events.append((start, seconds, key))
+    attrs = span_.record["attrs"]
+    attrs[key] = attrs.get(key, 0.0) + max(seconds - inside, 0.0)
+    _set_origin(attrs, origin)
+
+
+def _set_origin(attrs, origin):
+    if origin is not None and (origin == "compiled" or "origin" not in attrs):
+        attrs["origin"] = origin
+
+
+def _close_kept(record):
+    """A kept span has ended: what it holds goes to the kept span round
+    it; the outermost one is a build, and is counted."""
+    kept = _open.kept
+    kept.pop()
+    attrs = record["attrs"]
+    if kept:
+        outer = kept[-1].record["attrs"]
+        for key in _SECONDS:
+            if key in attrs:
+                outer[key] = outer.get(key, 0.0) + attrs[key]
+        _set_origin(outer, attrs.get("origin"))
+    else:
+        with _lock:
+            _build_counts["by_kind"][attrs.get("kind", "other")] += 1
+            _build_counts["by_origin"][attrs.get("origin", "memory")] += 1
+    if len(_builds.records) < BUILD_CAP:
+        _builds.records.append(record)
+    else:
+        with _lock:
+            _builds.dropped += 1
 
 
 def tracing() -> bool:
@@ -136,8 +260,10 @@ def tracing() -> bool:
     return False
 
 
-def span(name: str, **attrs):
-    """A host span of the call path: ``with span("mpx.call", program=...)``.
+def span(name: str, keep: bool = False, **attrs):
+    """A host span: ``with span("mpx.call", program=...)`` on the call
+    path, ``with span("mpx.pin", keep=True, program=...)`` on the build
+    path.
 
     While a profiler session runs (``jax.profiler.start_trace``,
     ``jax.profiler.trace``, :func:`profile_ops`) the span is a
@@ -154,10 +280,28 @@ def span(name: str, **attrs):
 
     With no session running this is one flag test (:func:`tracing`): no
     annotation, no record, no timestamp.
+
+    ``keep=True`` is for the build path (a pin, a new program's first
+    call): the span records whether or not a session runs, into the
+    process's own buffer, which no session's start clears (:func:`builds`;
+    at most ``BUILD_CAP`` records, :func:`builds_dropped` counts the rest);
+    while a session runs it is the annotation and the session's record
+    too.  Its ``attrs`` gain ``build`` (the id of the outermost kept span
+    open round it: its own id says it *is* the build) and what jax said
+    under it (:func:`account`): ``trace_s``, ``lower_s``, ``compile_s``,
+    ``fetch_s`` — each second once, under the innermost stage — and
+    ``origin``: ``"compiled"`` (XLA compiled an executable under it),
+    ``"jax_cache"`` (all came from jax's persistent cache),
+    ``"package_cache"`` (from the package's disk tier); no ``origin``
+    means jax had every executable in this process already.  A kept span
+    hands its seconds and origin to the kept span round it when it ends.
     """
-    if not tracing():
+    session = tracing()
+    if not session and not keep:
         return _OFF
-    return _Span(name, attrs)
+    if keep and not _listening:
+        _listen()
+    return _Span(name, attrs, session, keep)
 
 
 def spans() -> list:
@@ -172,7 +316,38 @@ def spans_dropped() -> int:
 
 
 def clear_spans() -> None:
+    """Empty the session's buffer.  Kept records stay (:func:`builds`)."""
     _buffer.clear()
+
+
+def builds() -> list:
+    """The kept records of this process, in order of their end (a child
+    before its parent), whether or not a session ran.  A *build* is a
+    record whose ``attrs["build"]`` is its own ``id``: ``mpx.pin``
+    (``kind="pin"``), ``mpx.build`` (``kind="region"`` or ``"eager"``).
+    Copies: the caller may keep them."""
+    return [dict(r, attrs=dict(r["attrs"])) for r in _builds.records]
+
+
+def builds_dropped() -> int:
+    """How many kept spans found their buffer full."""
+    return _builds.dropped
+
+
+def build_counts() -> dict:
+    """Builds ended in this process by ``kind`` and by ``origin``
+    (``"memory"``: no executable was compiled or fetched under it), drops
+    included: ``mpx.cache_stats()["builds"]``."""
+    with _lock:
+        return {k: dict(v) for k, v in _build_counts.items()}
+
+
+def clear_builds() -> None:
+    """Empty the kept buffer and zero the counts (``mpx.clear_caches``)."""
+    with _lock:
+        _builds.clear()
+        for counts in _build_counts.values():
+            counts.clear()
 
 
 # ---------------------------------------------------------------------------

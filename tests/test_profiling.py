@@ -73,6 +73,7 @@ def test_profile_ops_nested_exceptions_close_trace(tmp_path):
 
 import contextlib  # noqa: E402
 import threading  # noqa: E402
+import time  # noqa: E402
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -164,8 +165,9 @@ def test_pinned_calls_give_call_and_launch_spans(pinned, tmp_path):
 @pytest.mark.parametrize("tier", ["compile", "load"])
 def test_a_pin_inside_a_session_names_its_stages(tier, tmp_path,
                                                  monkeypatch):
-    """``mpx.pin`` carries the program's name and two children: the trace,
-    then the compile, or the load where the disk tier has the program."""
+    """``mpx.pin`` carries the program's name and its stages as children:
+    the trace, then the lowering and the compile, or the load where the
+    disk tier has the program.  It says where the executable came from."""
     comm = _world()
     x = jnp.ones((4, 16), jnp.float32)
 
@@ -181,11 +183,20 @@ def test_a_pin_inside_a_session_names_its_stages(tier, tmp_path,
         program = mpx.compile(scaled_sum, x)
     assert program.from_disk == (tier == "load")
     (pin,) = _named("mpx.pin")
-    assert pin["attrs"] == {"program": "scaled_sum"}
+    assert pin["attrs"]["program"] == "scaled_sum"
+    assert pin["attrs"]["kind"] == "pin"
+    assert pin["attrs"]["origin"] == ("compiled" if tier == "compile"
+                                      else "package_cache")
     stages = sorted(_children(pin), key=lambda r: r["start_ns"])
-    assert [s["name"] for s in stages] == ["mpx.pin.trace", "mpx.pin." + tier]
+    assert [s["name"] for s in stages] == ["mpx.pin.trace"] + (
+        ["mpx.pin.lower", "mpx.pin.compile"] if tier == "compile"
+        else ["mpx.pin.load"])
     assert all(_inside(s, pin) and s["call"] == pin["call"] for s in stages)
-    assert stages[0]["end_ns"] <= stages[1]["start_ns"]
+    assert all(a["end_ns"] <= b["start_ns"]
+               for a, b in zip(stages, stages[1:]))
+    # the session's records of a kept span are the kept buffer's
+    assert [r["id"] for r in profiling.builds()][-len(stages) - 1:] == [
+        s["id"] for s in stages] + [pin["id"]]
 
 
 @pytest.mark.parametrize("path", ["region", "eager"])
@@ -217,16 +228,28 @@ def test_region_and_eager_calls_give_spans(path, tmp_path):
 
 
 def test_region_call_pins_beside_its_launch(tmp_path, monkeypatch):
-    """With the disk tier on, a program-cache miss pins: ``mpx.pin`` is a
-    child of ``mpx.region_call`` and ends before ``mpx.launch`` starts."""
+    """With the disk tier on, a program-cache miss pins: ``mpx.pin`` lies
+    in the region's ``mpx.build`` (a child of ``mpx.region_call``) and
+    ends before ``mpx.launch`` starts.  The build is the region's: the
+    pin inside it is no build of its own, and hands it what it found."""
     monkeypatch.setenv("MPI4JAX_TPU_COMPILE_CACHE_DIR", str(tmp_path / "t"))
     fn = mpx.spmd(_reduce, comm=_world())
+    before = mpx.cache_stats()["builds"]["by_kind"]
     with _session(tmp_path):
         fn(jnp.ones((4, 8), jnp.float32))
     (region,) = _named("mpx.region_call")
-    pin, launch = sorted(_children(region), key=lambda r: r["start_ns"])
+    (build,) = _children(region)
+    assert build["name"] == "mpx.build"
+    pin, launch = sorted(_children(build), key=lambda r: r["start_ns"])
     assert (pin["name"], launch["name"]) == ("mpx.pin", "mpx.launch")
     assert pin["end_ns"] <= launch["start_ns"]
+    assert "kind" not in pin["attrs"]
+    assert pin["attrs"]["build"] == build["id"] == build["attrs"]["build"]
+    assert build["attrs"]["origin"] == pin["attrs"]["origin"] == "compiled"
+    assert build["attrs"]["compile_s"] == pin["attrs"]["compile_s"] > 0
+    after = mpx.cache_stats()["builds"]["by_kind"]
+    assert after["region"] - before.get("region", 0) == 1
+    assert after.get("pin", 0) == before.get("pin", 0)
 
 
 def test_spans_of_two_threads_keep_separate_parents(tmp_path):
@@ -376,3 +399,291 @@ def test_algorithm_phases_carry_a_scope_under_the_ops(monkeypatch):
                   "mpi4jax_tpu.bcast/binomial_scatter/ppermute",
                   "mpi4jax_tpu.bcast/ring_allgather/ppermute"):
         assert scope in text, scope
+
+
+# ---------------------------------------------------------------------------
+# kept spans: the build path, with or without a session
+# ---------------------------------------------------------------------------
+
+
+def _builds_named(name, since=0):
+    return [r for r in profiling.builds()[since:] if r["name"] == name]
+
+
+def _fresh(tag):
+    """A region no test has built before: its own function, its own
+    constant (so neither jax's caches nor the package's know it)."""
+    def body(v):
+        return _reduce(v) * float(tag)
+
+    body.__name__ = f"fresh_{tag}"
+    return mpx.spmd(body, comm=_world())
+
+
+def test_a_kept_span_records_with_no_session():
+    """No session runs: a kept span still makes a record of the common
+    shape, in the kept buffer and not in the session's; a call-path span
+    beside it stays the shared do-nothing object and records nothing."""
+    profiling.clear_spans()
+    n = len(profiling.builds())
+    assert not profiling.tracing()
+    off = profiling.span("mpx.call", program="f")
+    with profiling.span("kept.outer", keep=True, program="p") as outer:
+        assert profiling.span("mpx.launch") is off
+        with off as nothing:
+            assert nothing is None
+        with profiling.span("kept.inner", keep=True) as inner:
+            pass
+    assert profiling.spans() == []
+    got = profiling.builds()[n:]
+    assert [r["name"] for r in got] == ["kept.inner", "kept.outer"]
+    assert set(outer) == {"name", "start_ns", "end_ns", "id", "parent",
+                          "call", "attrs"}
+    assert outer["parent"] is None and outer["call"] == outer["id"]
+    assert inner["parent"] == outer["id"] and inner["call"] == outer["id"]
+    assert outer["attrs"] == {"program": "p", "build": outer["id"]}
+    assert inner["attrs"] == {"build": outer["id"]}
+    assert _inside(inner, outer) and 0 < outer["start_ns"]
+
+
+def test_a_call_path_span_with_no_session_reads_no_clock(monkeypatch):
+    """The call path off: the same object every time, no record in either
+    buffer, and the clock is not read (``keep=False`` says the same)."""
+    def no_clock():
+        raise AssertionError("a call-path span read the clock")
+
+    profiling.clear_spans()
+    n = len(profiling.builds())
+    monkeypatch.setattr(profiling.time, "time_ns", no_clock)
+    names = ("mpx.call", "mpx.region_call", "mpx.eager.allreduce",
+             "mpx.launch")
+    handed = {id(profiling.span(name, program="f")) for name in names}
+    handed.add(id(profiling.span("mpx.call", keep=False)))
+    assert handed == {id(profiling._OFF)}
+    for name in names:
+        with profiling.span(name) as record:
+            assert record is None
+    assert profiling.spans() == [] and len(profiling.builds()) == n
+
+
+def test_kept_records_outlive_a_session_and_clear_spans(tmp_path):
+    """A session's first span clears the session's buffer, and
+    ``clear_spans()`` empties it: neither touches the kept records.
+    ``clear_builds()`` (``mpx.clear_caches``) does."""
+    with profiling.span("kept.before", keep=True):
+        pass
+    n = len(profiling.builds())
+    with _session(tmp_path):
+        with profiling.span("in.session"):
+            pass
+        with profiling.span("kept.during", keep=True):
+            pass
+    assert [r["name"] for r in profiling.spans()] == ["in.session",
+                                                      "kept.during"]
+    assert [r["name"] for r in profiling.builds()[n - 1:]] == [
+        "kept.before", "kept.during"]
+    profiling.clear_spans()
+    assert profiling.spans() == []
+    assert [r["name"] for r in profiling.builds()[n - 1:]] == [
+        "kept.before", "kept.during"]
+    mpx.clear_caches()
+    assert profiling.builds() == [] and profiling.builds_dropped() == 0
+    assert mpx.cache_stats()["builds"] == {"by_kind": {}, "by_origin": {}}
+
+
+def test_a_fresh_pin_is_one_build_compiled_here():
+    """``mpx.compile`` of a program nobody has built: one ``mpx.pin``,
+    a build (``build`` is its own id), ``origin`` ``"compiled"``, the
+    stages' seconds summed from its children and inside its duration;
+    the counters at the same boundary rise by one each."""
+    before, n = mpx.cache_stats(), len(profiling.builds())
+    x = jnp.ones((4, 16), jnp.float32)
+    program = mpx.compile(_fresh(11), x)
+    after = mpx.cache_stats()
+    (pin,) = _builds_named("mpx.pin", n)
+    attrs = pin["attrs"]
+    assert attrs["program"] == "fresh_11" and attrs["kind"] == "pin"
+    assert attrs["build"] == pin["id"] and attrs["origin"] == "compiled"
+    assert attrs["compile_s"] > 0 and attrs["trace_s"] > 0
+    assert attrs["lower_s"] > 0 and "fetch_s" not in attrs
+    stages = {r["name"]: r for r in profiling.builds()[n:]
+              if r["parent"] == pin["id"]}
+    assert sorted(stages) == ["mpx.pin.compile", "mpx.pin.lower",
+                              "mpx.pin.trace"]
+    for key, stage in (("trace_s", "mpx.pin.trace"),
+                       ("lower_s", "mpx.pin.lower"),
+                       ("compile_s", "mpx.pin.compile")):
+        assert stages[stage]["attrs"][key] == attrs[key]
+        assert stages[stage]["attrs"]["build"] == pin["id"]
+    held = attrs["trace_s"] + attrs["lower_s"] + attrs["compile_s"]
+    assert held <= (pin["end_ns"] - pin["start_ns"]) * 1e-9
+    assert after["aot"]["pins"] - before["aot"]["pins"] == 1
+    assert after["aot"]["compiles"] - before["aot"]["compiles"] == 1
+    for counts, key in (("by_kind", "pin"), ("by_origin", "compiled")):
+        assert (after["builds"][counts][key]
+                - before["builds"][counts].get(key, 0)) == 1
+    assert program.memory_bytes().keys() == {"temporaries", "arguments",
+                                             "results", "code"}
+    assert program.memory_bytes()["arguments"] == x.nbytes // 4
+
+
+@pytest.fixture
+def jax_cache(tmp_path):
+    """jax's persistent cache on, in a directory of the test's own, for
+    every program however small and quick."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    old = {name: getattr(jax.config, name) for name in names}
+    jax.config.update(names[0], str(tmp_path / "jax_cache"))
+    jax.config.update(names[1], 0.0)
+    jax.config.update(names[2], -1)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for name, value in old.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
+
+def test_a_pin_fetched_from_jaxs_cache_says_so(jax_cache):
+    """The same program pinned again after ``jax.clear_caches()`` with
+    jax's persistent cache on: ``origin`` ``"jax_cache"``, ``fetch_s``
+    over 0 and ``compile_s`` 0 — jax 0.9.0 closes a
+    ``backend_compile_duration`` round the fetch too, which is the
+    fetch's.  ``aot.compiles`` counts the compile alone."""
+    x = jnp.ones((4, 16), jnp.float32)
+    fn = _fresh(13)
+    n = len(profiling.builds())
+    before = mpx.cache_stats()
+    mpx.compile(fn, x)
+    jax.clear_caches()
+    want = np.asarray(mpx.compile(fn, x)(x))
+    after = mpx.cache_stats()
+    first, second = _builds_named("mpx.pin", n)
+    assert first["attrs"]["origin"] == "compiled"
+    assert first["attrs"]["compile_s"] > 0
+    assert second["attrs"]["origin"] == "jax_cache"
+    assert second["attrs"]["fetch_s"] > 0
+    assert second["attrs"].get("compile_s", 0.0) == 0.0
+    assert second["attrs"]["trace_s"] > 0 and second["attrs"]["lower_s"] > 0
+    np.testing.assert_array_equal(want, np.full((4, 16), 4.0 * 13))
+    assert after["aot"]["pins"] - before["aot"]["pins"] == 2
+    assert after["aot"]["compiles"] - before["aot"]["compiles"] == 1
+    origins = after["builds"]["by_origin"]
+    assert origins["jax_cache"] - before["builds"]["by_origin"].get(
+        "jax_cache", 0) == 1
+
+
+@pytest.mark.parametrize("kind", ["region", "eager"])
+def test_a_new_programs_first_call_is_one_build(kind):
+    """A region's first call (a program-cache miss) runs under one kept
+    ``mpx.build`` with ``kind="region"``, an eager op's cache miss under
+    one with ``kind="eager"``: its second call, a hit, under none."""
+    comm = _world()
+    if kind == "region":
+        fn, program = _fresh(17), "fresh_17"
+        x = jnp.ones((4, 8), jnp.float32)
+    else:
+        fn, program = (lambda v: mpx.allreduce(v, op=mpx.MAX, comm=comm)[0]), \
+            "allreduce"
+        x = jnp.ones((4, 24), jnp.float32)
+        mpx.clear_caches()  # the eager cache: a miss for certain
+    n = len(profiling.builds())
+    before = mpx.cache_stats()["builds"]["by_kind"].get(kind, 0)
+    first = np.asarray(fn(x))
+    (build,) = profiling.builds()[n:]
+    assert build["name"] == "mpx.build" and build["parent"] is None
+    assert build["attrs"]["program"] == program
+    assert build["attrs"]["kind"] == kind
+    assert build["attrs"]["build"] == build["id"]
+    assert build["attrs"]["origin"] == "compiled"
+    assert build["attrs"]["trace_s"] > 0 and build["attrs"]["compile_s"] > 0
+    np.testing.assert_array_equal(np.asarray(fn(x)), first)
+    assert len(profiling.builds()) == n + 1
+    assert mpx.cache_stats()["builds"]["by_kind"][kind] - before == 1
+
+
+def test_kept_pins_are_as_many_as_the_counter_says():
+    """The count of kept ``mpx.pin`` builds equals the rise of
+    ``cache_stats()["aot"]["pins"]``, across ``clear_caches()`` too."""
+    mpx.clear_caches()
+    x = jnp.ones((4, 8), jnp.float32)
+    fn = _fresh(19)
+    for _ in range(3):
+        mpx.compile(fn, x)
+    fn(x)  # a region's build is no pin
+    pins = [r for r in profiling.builds()
+            if r["name"] == "mpx.pin" and r["attrs"]["build"] == r["id"]]
+    assert len(pins) == 3 == mpx.cache_stats()["aot"]["pins"]
+    assert mpx.cache_stats()["builds"]["by_kind"] == {"pin": 3, "region": 1}
+
+
+def test_kept_buffer_cap_drops_and_counts(monkeypatch):
+    """Beyond ``BUILD_CAP`` records a kept span is dropped and counted;
+    the counts of builds go on."""
+    mpx.clear_caches()
+    monkeypatch.setattr(profiling, "BUILD_CAP", 3)
+    for i in range(5):
+        with profiling.span("kept", keep=True, kind="test", i=str(i)):
+            pass
+    assert [r["attrs"]["i"] for r in profiling.builds()] == list("012")
+    assert profiling.builds_dropped() == 2
+    assert mpx.cache_stats()["builds"]["by_kind"] == {"test": 5}
+    assert mpx.cache_stats()["builds"]["by_origin"] == {"memory": 5}
+    profiling.clear_builds()
+    assert profiling.builds() == [] and profiling.builds_dropped() == 0
+
+
+def test_kept_and_session_spans_nest_across_each_other(tmp_path):
+    """One stack of open spans for both sorts: ``parent`` and ``call``
+    cross from a session span to a kept one and back, ``build`` names the
+    outermost *kept* span, and a session span is in the session's buffer
+    alone."""
+    n = len(profiling.builds())
+    with _session(tmp_path):
+        with profiling.span("call.outer") as outer:
+            with profiling.span("kept.build", keep=True) as build:
+                with profiling.span("call.launch") as launch:
+                    with profiling.span("kept.stage", keep=True) as stage:
+                        profiling.account("fetch_s", 0.25, "jax_cache")
+    assert [r["name"] for r in profiling.spans()] == [
+        "kept.stage", "call.launch", "kept.build", "call.outer"]
+    assert [r["name"] for r in profiling.builds()[n:]] == [
+        "kept.stage", "kept.build"]
+    assert build["parent"] == outer["id"] and launch["parent"] == build["id"]
+    assert stage["parent"] == launch["id"]
+    assert {r["call"] for r in (outer, build, launch, stage)} == {
+        outer["id"]}
+    assert stage["attrs"]["build"] == build["id"] == build["attrs"]["build"]
+    assert "build" not in launch["attrs"] and "build" not in outer["attrs"]
+    assert build["attrs"]["fetch_s"] == stage["attrs"]["fetch_s"] == 0.25
+    assert build["attrs"]["origin"] == "jax_cache"
+
+
+def test_a_builds_seconds_are_counted_once():
+    """A stage that ends round stages already accounted adds its own time
+    only; a "compile" that ends round a fetch is the fetch's; one real
+    compile makes the whole build ``"compiled"``."""
+    with profiling.span("kept", keep=True) as record:
+        time.sleep(0.02)
+        profiling.account("compile_s", 0.004, "compiled")  # a helper
+        profiling.account("trace_s", 0.015)  # the trace that held it
+        time.sleep(0.02)
+        profiling.account("fetch_s", 0.003, "jax_cache")
+        profiling.account("compile_s", 0.005, "compiled")  # round the fetch
+    attrs = record["attrs"]
+    assert attrs["compile_s"] == pytest.approx(0.004)
+    assert attrs["trace_s"] == pytest.approx(0.011)
+    assert attrs["fetch_s"] == pytest.approx(0.005)
+    assert attrs["origin"] == "compiled"
+    with profiling.span("kept", keep=True) as fetched:
+        time.sleep(0.01)
+        profiling.account("fetch_s", 0.003, "jax_cache")
+        profiling.account("compile_s", 0.005, "compiled")
+    assert fetched["attrs"]["origin"] == "jax_cache"
+    assert "compile_s" not in fetched["attrs"]
+    profiling.account("compile_s", 1.0, "compiled")  # no kept span open
