@@ -14,14 +14,16 @@ fails or a fallback taken makes the exit code non-zero:
   be laid out over all devices.
 - **B — the flagship**: ``examples/shallow_water.py`` at benchmark width
   (3600 x 1800) through ``solve_fused(fast="auto", pinned=True)``, the
-  region the benchmark times: the Mosaic-compiled Pallas kernel (``pallas2`` on one
-  device, the ``(2, 2)`` wide-halo ``wide2`` on four), the pinned artifact,
-  the final state against the plain ``jnp`` step on the same devices.  On
-  one device the closed basin (``periodic_x=False``) follows at the same
-  size: ``auto`` gives it ``wide2`` on the carried widened frame, the path
-  of every decomposed run, here without its permutes — once as that pinned
-  region, once through the host loop ``solve()`` runs (``run_multisteps``
-  over ``make_stepper``'s two un-pinned programs, a call a multistep).
+  region the benchmark times: the Mosaic-compiled Pallas kernel (``wide2``,
+  the wide-halo kernel on the carried widened frame, which ``auto`` gives
+  every domain of this size: one device or the ``(2, 2)`` of four), the
+  pinned artifact, the final state against the plain ``jnp`` step on the
+  same devices.  On one device the closed basin (``periodic_x=False``)
+  follows at the same size — the same kernel, its x bands zeros where the
+  periodic domain's are slices of its own frame — and either domain runs
+  once as that pinned region, once through the host loop ``solve()`` runs
+  (``run_multisteps`` over ``make_stepper``'s two un-pinned programs, a
+  call a multistep, the frame carried from call to call).
 - **C — a server that answers a few requests**: ``mpx.serving.ServingEngine``
   with the ``bench`` preset of ``examples/serving/serve.py``, tensor-parallel
   over all devices, a dozen requests, continuous scheduler; every program
@@ -282,8 +284,8 @@ def stage_a(ctx):
 
 def stage_b(ctx):
     """The periodic domain on every device count; on one device also the
-    closed basin, which ``auto`` sends down the wide-halo path that every
-    decomposed run takes: each as one pinned program, then through the
+    closed basin: ``auto`` sends both down the wide-halo path that every
+    decomposed run takes, each as one pinned program, then through the
     host loop ``solve()`` runs (``run_multisteps``: a call a multistep) —
     the published benchmark's own driver on the periodic domain."""
     info = _flagship(ctx, periodic_x=True)
@@ -317,8 +319,9 @@ def _flagship(ctx, periodic_x, host_loop=False):
 
     _, comm = sw.make_mesh_and_comm(cfg, devices=ctx["devices"])
     single, chunk, _ = sw.select_steps("auto", cfg)
-    want_kernel = (sw.model_step2_pallas if n == 1 and periodic_x
-                   else sw.model_step2_wide)
+    # every interior here fits the exchange depth (the rehearsal's are the
+    # smallest that do), so it is the wide-halo pair on any device count
+    want_kernel = sw.model_step2_wide
     check(chunk is want_kernel,
           f"'auto' chose {getattr(chunk, '__name__', chunk)} on {n} "
           f"device(s), not {want_kernel.__name__}")

@@ -1527,7 +1527,8 @@ def _wide_run(state, num_steps: int, cfg: Config, comm: mpx.Comm,
     docs/shallow-water.rst:56-94) with the fused-kernel per-chip speed.
     Requires a local interior of at least ``m`` cells per dimension (strips
     must come from the immediate neighbor only); ``select_steps("auto")``
-    falls back to the split-phase path below that.
+    falls back below that (the whole-step kernel on one periodic rank, the
+    split-phase path elsewhere).
 
     The chunk loop advances two refresh-and-call rounds per iteration (an
     odd count's last round follows the loop), as ``_run_steps``' does and
@@ -1608,14 +1609,19 @@ def _resolve_mode(fast, cfg: Config = None):
                 "select_steps('auto') needs the Config to decide kernel "
                 "eligibility — pass cfg"
             )
-        # whole-step kernel where eligible (no exchanges at all); the
-        # wide-halo pair kernel everywhere else (multi-rank meshes, walls)
-        # unless the local interior is smaller than its exchange depth.
+        # the wide-halo pair kernel on the carried, lane-aligned frame
+        # wherever the local interior fits its exchange depth, whatever the
+        # mesh and the boundary: on whole lane tiles the same window
+        # functions run about 1.5 x faster than on a field's own width
+        # (PERF.md section 6, PR 36 and 38), and a single periodic rank's
+        # x bands are slices of its own frame.  Below that depth what each
+        # got before: the whole-step kernel for a single periodic rank (no
+        # exchanges at all), the split-phase kernels for the rest.
         # Pair depth: three steps a call do not compile at benchmark width.
-        if cfg.nproc == 1 and cfg.periodic_x:
-            return "pallas2"
         if min(cfg.ny_local, cfg.nx_local) - 2 >= _margin_rows(2):
             return "wide2"
+        if cfg.nproc == 1 and cfg.periodic_x:
+            return "pallas2"
         return "pallas_halo"
     if isinstance(fast, str) and fast not in ("pallas2", "wide2",
                                               "pallas_halo"):
@@ -1645,9 +1651,12 @@ def select_steps(fast, cfg: Config = None):
       dimension, ``_wide_run``);
     - ``"pallas_halo"`` — the split-phase Pallas kernels with real halo
       exchanges between them (any mesh, ``model_step_pallas_halo``);
-    - ``"auto"`` — ``"pallas2"`` when ``cfg`` is a single-rank periodic-x
-      decomposition (the benchmark configuration); else ``"wide2"`` when
-      the local interior fits its exchange depth; else ``"pallas_halo"``.
+    - ``"auto"`` — ``"wide2"`` wherever the local interior fits its
+      exchange depth (16 cells a dimension), whatever the mesh and the
+      boundary: the benchmark configuration (one rank, periodic in x, 3600
+      columns) included, whose frame is then 29 whole lane tiles wide;
+      below that ``"pallas2"`` for a single-rank periodic-x decomposition
+      and ``"pallas_halo"`` for the rest.
     """
     mode = _resolve_mode(fast, cfg)
     if mode == "wide2":
@@ -1808,7 +1817,24 @@ def _run_steps(state: State, num_steps: int, cfg, comm, step, chunk,
     iteration XLA's while loop copies all six new fields back into the
     carry's buffers (28.6 % of device time at 3600 x 28800 on a v5e), with
     two the second call writes into the buffers the first has just read and
-    no field is copied (tests/test_solver_loop_hlo.py)."""
+    no field is copied (tests/test_solver_loop_hlo.py).
+
+    Handed the wide-halo pair (``chunk is model_step2_wide``: what
+    ``select_steps`` gives for ``"wide2"``), the steps run on the carried
+    widened frame — ``_wide_run``: one frame built, a band refresh and a
+    kernel call a chunk, one crop — and not as a loop of standalone chunk
+    steps, each of which would build and crop a frame of its own; the
+    ``State`` that ``make_stepper``'s ``multistep`` returns, bit for bit
+    (tests/test_fused_runner.py).  So a driver that
+    composes ``select_steps`` with this function gets what
+    ``fused_runner`` gives, less the frame its own first step builds.
+    ``_wide_run`` is called here, with no function between (PERF.md
+    section 6, PR 30 and 34: a Python frame more above it is paid in
+    tracing time)."""
+    if chunk is model_step2_wide:
+        return _wide_run(state, num_steps, cfg, comm, chunk_size,
+                         _margin_rows(chunk_size), _resolve_interpret(comm),
+                         euler_first=False)
     nchunks, rem = _steps_schedule(num_steps, chunk, chunk_size)
     if chunk is not None:
         if nchunks:  # fori_loop(0, 0) would still trace the chunk kernel

@@ -74,8 +74,8 @@ def test_rehearsal_passes_on_the_eight_device_mesh(tmp_path):
 def test_one_device_rehearsal_drives_both_domains_through_the_host_loop(
         tmp_path):
     """On one device stage B runs the flagship four times: either domain
-    as one pinned program and through ``run_multisteps``, the periodic one
-    on the whole-step kernel and the walled one on the wide-halo kernel."""
+    as one pinned program and through ``run_multisteps``, both on the
+    wide-halo kernel (``auto`` on a periodic chip too, since PR 38)."""
     res = _run([SMOKE, "--rehearse"], devices=1, cache_dir=tmp_path)
     assert res.returncode == 0, res.stdout[-3000:] + res.stderr[-3000:]
     (line,) = [ln for ln in res.stdout.splitlines() if "stage B ok" in ln]
@@ -85,8 +85,7 @@ def test_one_device_rehearsal_drives_both_domains_through_the_host_loop(
     for name, run in info.items():
         periodic = name.startswith("periodic")
         assert run["periodic_x"] is periodic and run["steps"] == 5, name
-        assert run["kernel"] == ("model_step2_pallas" if periodic
-                                 else "model_step2_wide"), name
+        assert run["kernel"] == "model_step2_wide", name
 
 
 def test_a_failing_stage_fails_the_run(tmp_path):

@@ -297,11 +297,14 @@ def test_select_step_auto_picks_kernel_by_mesh():
     def select_step(fast, cfg):
         return select_steps(fast, cfg)[0]
 
-    # whole-step kernel only where every refresh is an in-register periodic
-    # fix; the wide-halo kernel everywhere else, unless the local interior
-    # is smaller than its 16-cell exchange depth (then split-phase)
+    # the wide-halo kernel wherever the local interior fits its 16-cell
+    # exchange depth, whatever the mesh and the boundary (PR 38); below
+    # that the whole-step kernel where every refresh is an in-register
+    # periodic fix (one rank, periodic in x), split-phase for the rest
     single = Config(nproc_y=1, nproc_x=1, nx=48, ny=24)
-    assert select_step("auto", single) is model_step_pallas
+    assert select_step("auto", single) is model_step_wide
+    small_single = Config(nproc_y=1, nproc_x=1, nx=48, ny=12)
+    assert select_step("auto", small_single) is model_step_pallas
     multi = Config(nproc_y=2, nproc_x=4, nx=48, ny=24)  # 12x12 interior
     assert select_step("auto", multi) is model_step_pallas_halo
     big_multi = Config(nproc_y=2, nproc_x=4, nx=64, ny=32)  # 16x16 interior
@@ -311,6 +314,37 @@ def test_select_step_auto_picks_kernel_by_mesh():
     small_walls = replace(Config(nproc_y=1, nproc_x=1, nx=48, ny=12),
                           periodic_x=False)
     assert select_step("auto", small_walls) is model_step_pallas_halo
+
+
+@pytest.mark.parametrize("mesh,nx,ny,periodic_x,want", [
+    ((1, 1), 48, 24, True, "wide2"),         # single periodic rank, fits
+    ((1, 1), 3600, 28800, True, "wide2"),    # the benchmark's periodic chip
+    ((1, 1), 48, 15, True, "pallas2"),       # ... interior under 16 rows
+    ((1, 1), 15, 48, True, "pallas2"),       # ... under 16 columns
+    ((1, 1), 48, 16, True, "wide2"),         # ... at the depth exactly
+    ((1, 1), 48, 24, False, "wide2"),        # walled, fits
+    ((1, 1), 48, 12, False, "pallas_halo"),  # walled, too small
+    ((2, 2), 64, 32, True, "wide2"),         # 2x2 periodic, 16 x 32 a rank
+    ((2, 2), 64, 32, False, "wide2"),
+    ((2, 2), 48, 24, True, "pallas_halo"),   # 2x2, 12 rows a rank
+    ((2, 2), 48, 24, False, "pallas_halo"),
+])
+def test_auto_resolves_by_what_fits_the_exchange_depth(mesh, nx, ny,
+                                                       periodic_x, want):
+    """``auto`` reads the local interior first and the mesh and boundary
+    only below the wide-halo kernel's exchange depth; a mode named by the
+    caller is left as it is, so ``"pallas2"`` stays a mode one can ask
+    for."""
+    from shallow_water import _margin_rows, _resolve_mode, select_steps
+
+    cfg = Config(nproc_y=mesh[0], nproc_x=mesh[1], nx=nx, ny=ny,
+                 periodic_x=periodic_x)
+    assert _resolve_mode("auto", cfg) == want
+    fits = min(cfg.ny_local, cfg.nx_local) - 2 >= _margin_rows(2)
+    assert fits == (want == "wide2")
+    assert select_steps("auto", cfg) == select_steps(want, cfg)
+    for named in ("pallas2", "wide2", "pallas_halo", True, False):
+        assert _resolve_mode(named, cfg) is named
 
 
 @pytest.mark.parametrize("name", ["pallas3", "pallas", "wide", "fast"])

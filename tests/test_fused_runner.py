@@ -10,6 +10,7 @@ while it is traced.
 
 import os
 import sys
+from functools import partial
 
 import numpy as np
 import pytest
@@ -235,3 +236,108 @@ def test_leg_plan_of_a_mode_without_a_chunk_kernel():
                 or plan["crops"])
     with pytest.raises(ValueError, match="Euler"):
         sw.leg_plan(cfg, "auto", 0)
+
+
+# -- ``_run_steps`` handed the wide-halo pair (PR 38) ------------------------
+#
+# ``select_steps`` is "the single source of truth for every driver", and a
+# driver that composes it with ``_run_steps`` itself (the benchmark's
+# ``chipbench/drivers/solver.py`` does) hands ``_run_steps`` the pair
+# ``(model_step_wide, model_step2_wide)`` wherever ``auto`` resolves to
+# ``"wide2"`` — since PR 38 on one periodic chip too.  It must then run on
+# the carried frame and not build and crop one a chunk.
+
+WIDE_PAIR_CASES = [((1, 1), True), ((1, 1), False), ((2, 2), True)]
+
+
+def _composed(cfg, comm, fast="auto"):
+    """The two regions a driver builds from ``select_steps`` and
+    ``_run_steps``: the steps after the first alone, and the whole leg as
+    ``chipbench/drivers/solver.py`` builds it."""
+    step, chunk, chunk_size = sw.select_steps(fast, cfg)
+
+    @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+    def rest(state, num_steps):
+        return sw._run_steps(state, num_steps, cfg, comm, step, chunk,
+                             chunk_size)
+
+    @partial(mpx.spmd, comm=comm, static_argnums=(1,))
+    def leg(state, total):
+        state = step(state, cfg, comm, first_step=True)
+        return sw._run_steps(state, total, cfg, comm, step, chunk,
+                             chunk_size)
+
+    return rest, leg
+
+
+@pytest.mark.parametrize("mesh,periodic_x", WIDE_PAIR_CASES)
+def test_run_steps_given_the_wide_pair_is_the_leg_less_its_euler_step(
+        mesh, periodic_x):
+    """Bit for bit: ``_run_steps`` with the pair ``auto`` gives is
+    ``_wide_run`` without the Euler call — the program of ``make_stepper``'s
+    ``multistep``, the ``fused_runner`` leg less its first step — over an
+    odd chunk count with a step over; and the leg a driver composes from
+    it (its Euler step on a frame of its own, cropped, then the rest) has
+    with XLA's fusion pass off the bits of ``fused_runner``'s."""
+    cfg = _config(mesh, periodic_x)
+    assert sw._resolve_mode("auto", cfg) == "wide2"
+    _mesh, comm = sw.make_mesh_and_comm(cfg,
+                                        devices=jax.devices()[: cfg.nproc])
+    state = sw.initial_state(cfg, comm)
+    rest, leg = _composed(cfg, comm)
+    first_step, multistep = sw.make_stepper(cfg, comm, fast="auto")
+    after_first = first_step(state)
+    got, want = rest(after_first, STEPS - 1), multistep(after_first,
+                                                        STEPS - 1)
+    assert isinstance(got, sw.State)
+    for name, a, b in zip(want._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    assert np.abs(np.asarray(got.u) - np.asarray(after_first.u)).max() > 1e-2
+
+    fused, _ = sw.fused_runner(cfg, comm, "auto")
+    want = _unfused(lambda s: fused(s, STEPS - 1), state)
+    got = _unfused(lambda s: leg(s, STEPS - 1), state)
+    for name, a, b in zip(want._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
+@pytest.mark.parametrize("num_steps", [0, 1, 2, 3, 4, 5, 6, 7, 10, 11, 70])
+def test_run_steps_given_the_wide_pair_builds_one_frame_and_crops_once(
+        monkeypatch, num_steps):
+    """One periodic rank, whatever ``num_steps`` — no chunk, even and odd
+    chunk counts, a step over: one frame built, one crop, a refresh before
+    every kernel call but the first (``_wide_schedule``), and never a
+    frame a chunk, which is what a loop of ``model_step2_wide`` made."""
+    cfg = _config((1, 1), True)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    tally = _solver_tally(monkeypatch)
+    rest, _leg = _composed(cfg, comm)
+    state = sw.initial_state(cfg, comm)
+    out = jax.eval_shape(lambda s: rest(s, num_steps), state)
+    assert out == jax.eval_shape(lambda s: s, state)
+    got = tally.counts
+    used = int(num_steps > 0)
+    assert got.get("_wide_exchange", 0) == used
+    assert got.get("_wide_crop", 0) == used
+    assert got.get("_wide_kernel_call/False/2", 0) == num_steps // 2
+    assert got.get("_wide_kernel_call/False/1", 0) == num_steps % 2
+    assert not got.get("_wide_kernel_call/True/1", 0)
+    head, trips, rem = sw._wide_schedule(num_steps, 2, False)
+    assert head + trips == num_steps // 2 and rem == num_steps % 2
+    # the first kernel call runs off the frame as it was built
+    assert got.get("_wide_refresh", 0) == max(
+        num_steps // 2 + num_steps % 2 - 1, 0)
+    assert tally.trips == ([trips] if trips else [])
+
+
+def test_run_steps_leaves_the_other_modes_their_loop(monkeypatch):
+    """The branch reads the chunk function it is handed: the whole-step
+    pair still loops over ``model_step2_pallas``, no frame anywhere."""
+    cfg = _config((1, 1), True)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    tally = _solver_tally(monkeypatch)
+    rest, _leg = _composed(cfg, comm, "pallas2")
+    jax.eval_shape(lambda s: rest(s, 11), sw.initial_state(cfg, comm))
+    assert tally.counts == {"model_step_pallas/False/2": 5,
+                            "model_step_pallas/False/1": 1}
+    assert tally.trips == [5]

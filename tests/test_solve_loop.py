@@ -37,11 +37,13 @@ from test_fused_runner import (  # noqa: E402
 NUM = 10     # upstream's and solve()'s default num_multisteps
 N_ITERS = 2  # 1 + 2 x 10 steps
 
-# (fast, mesh, periodic_x): what ``auto`` gives one chip on either side of
-# its choice, and the wide-halo path on a mesh
+# (fast, mesh, periodic_x): what ``auto`` gives one chip in either boundary
+# mode (``wide2`` since PR 38, the carried frame on both), the whole-step
+# kernel by name while that mode lives, and the wide-halo path on a mesh
 CASES = [
-    ("auto", (1, 1), True),     # pallas2
-    ("auto", (1, 1), False),    # wide2
+    ("auto", (1, 1), True),     # wide2: its x bands slices of its own frame
+    ("auto", (1, 1), False),    # wide2: every band zeros
+    ("pallas2", (1, 1), True),  # the carry is the State
     ("wide2", (1, 1), True),
     ("wide2", (2, 2), True),
     ("wide2", (2, 2), False),
@@ -66,8 +68,10 @@ CASES = [
 # not a dead cell reaching a valid one: with fusion off run and leg still
 # agree bit for bit in all seven cases (the test below), and a frame whose
 # dead cells hold ``nan`` crops to the same bits
-# (tests/test_wide_dead_cells.py).
-ATOL = {("auto", (1, 1), True): 1e-6}
+# (tests/test_wide_dead_cells.py).  ``("auto", (1, 1), True)`` is the
+# program of ``("wide2", (1, 1), True)`` since PR 38 and reads what it
+# reads (u 1.49e-6 where ``pallas2`` read 0 and had the 1e-6 it keeps).
+ATOL = {("pallas2", (1, 1), True): 1e-6}
 WIDE_ATOL = 4e-6
 
 
@@ -245,14 +249,51 @@ def test_the_published_run_is_45_calls_and_441_steps():
         "steps": 10, "euler_calls": 0, "chunk_calls": 5,
         "single_step_calls": 0, "frames_built": 0, "band_refreshes": 5,
         "crops": 0}
-    periodic = sw.run_plan(sw.Config(nx=3600, ny=28800), "auto", n_iters,
-                           NUM)
+    # the periodic chip carries the same frame since PR 38: the same plan
+    assert sw.run_plan(sw.Config(nx=3600, ny=28800), "auto", n_iters,
+                       NUM) == plan
+    # the whole-step kernel, by name: the carry is the ``State``
+    periodic = sw.run_plan(sw.Config(nx=3600, ny=28800), "pallas2",
+                           n_iters, NUM)
     assert (periodic["frames_built"], periodic["crops"]) == (0, 0)
     assert periodic["multistep"]["band_refreshes"] == 0
+    assert periodic["multistep"]["chunk_calls"] == 5
     with pytest.raises(ValueError, match="n_iters"):
         sw.run_plan(cfg, "auto", -1, NUM)
     with pytest.raises(ValueError, match="num_multisteps"):
         sw.run_plan(cfg, "auto", 1, 0)
+
+
+def test_the_periodic_benchmark_domain_carries_the_frame_under_auto():
+    """``Config(nx=3600, ny=28800)``, the published domain on one chip:
+    ``auto`` is ``wide2`` there since PR 38, so the run and the leg are
+    counted on the carried frame — by the schedule ``_wide_run`` follows —
+    and the whole-step kernel's plan is what a caller gets by name."""
+    cfg = sw.Config(nx=3600, ny=28800)
+    assert sw._resolve_mode("auto", cfg) == "wide2"
+    run = sw.run_plan(cfg, "auto", 44, NUM)
+    assert run == sw.run_plan(cfg, "wide2", 44, NUM)
+    assert (run["frames_built"], run["crops"],
+            run["steps_per_kernel_call"]) == (1, 1, 2)
+    first, multi = run["first_step"], run["multistep"]
+    head, trips, rem = sw._wide_schedule(NUM, 2, False)
+    assert (head, trips, rem) == (1, 4, 0)
+    assert (multi["chunk_calls"], multi["single_step_calls"]) == (5, 0)
+    # a refresh a round, and one behind the last call for the next call
+    assert multi["band_refreshes"] == trips + rem + 1 == 5
+    assert (first["euler_calls"], first["frames_built"],
+            first["band_refreshes"]) == (1, 1, 1)
+    # a run: what the traced line's ``traced_custom_calls_a_run`` must read
+    assert (first["euler_calls"], 44 * multi["chunk_calls"],
+            first["band_refreshes"] + 44 * multi["band_refreshes"]) == (
+        1, 220, 221)
+    assert sw.leg_plan(cfg, "auto", 71) == {
+        "steps": 71, "steps_per_kernel_call": 2, "euler_calls": 1,
+        "chunk_calls": 35, "single_step_calls": 0, "frames_built": 1,
+        "band_refreshes": 35, "crops": 1}
+    named = sw.leg_plan(cfg, "pallas2", 71)
+    assert (named["chunk_calls"], named["frames_built"],
+            named["band_refreshes"], named["crops"]) == (35, 0, 0, 0)
 
 
 @pytest.mark.parametrize("num", [1, 2, 3, 7, 10])
@@ -492,14 +533,15 @@ def test_a_run_agrees_with_the_walled_reference(seed):
 def test_a_run_agrees_with_the_periodic_reference(seed):
     """48 x 24 domain, periodic in x, from the benchmark's own initial
     state: 21 steps through ``run_multisteps`` on what ``auto`` gives one
-    periodic chip (``pallas2``: the carry is the ``State``) against the
-    plain reference — not only ``fused_runner``'s leg, which shares the
-    kernel — gaps scaled as the cell's check scales them.  Largest reading
-    over the three seeds: 5.3e-7 (u; h 2.3e-7); the limit is 1e-6 — the
-    gaps are float32 rounding over 21 steps of two independent orderings
-    of the same sums (an ulp of the 100 m height is 7.6e-8 of it), twice
-    the reading, and the limit of the walled case above.  One multistep
-    fewer reads 3.5e-3 on h."""
+    periodic chip (``wide2`` since PR 38: the carry is the widened frame,
+    its x bands read out of itself) against the plain reference — not
+    only ``fused_runner``'s leg, which shares the kernel — gaps scaled as
+    the cell's check scales them.  Largest reading over the three seeds:
+    5.3e-7 (u; h 1.5e-7, and 2.3e-7 on ``pallas2``, which ``auto`` gave
+    until PR 38); the limit is 1e-6 — the gaps are float32 rounding over
+    21 steps of two independent orderings of the same sums (an ulp of the
+    100 m height is 7.6e-8 of it), twice the reading, and the limit of the
+    walled case above.  One multistep fewer reads 3.5e-3 on h."""
     p = periodic_ref.params(dict(REFERENCE_CONFIG, periodic_x=True))
     h, u, v = periodic_ref.initial_fields(p, seed)
     cfg = sw.Config(nx=48, ny=24, periodic_x=True)
@@ -508,7 +550,12 @@ def test_a_run_agrees_with_the_periodic_reference(seed):
     state = sw.State(*(periodic_ref.with_halo_columns(a)[None]
                        for a in (h, u, v)), zero, zero, zero)
     first_step, multistep = sw.make_stepper(cfg, comm, fast="auto")
-    assert multistep.carried is multistep and multistep.crop(state) is state
+    assert multistep.carried is not multistep
+    assert first_step.carried is not first_step
+    frames = jax.eval_shape(first_step.carried, state)
+    assert {f.shape for f in frames} == {(1, *_frame_shape(cfg))}
+    assert jax.eval_shape(multistep.crop, frames) == jax.eval_shape(
+        lambda s: s, state)
     got = sw.run_multisteps(first_step, multistep, state, N_ITERS, NUM)
     fewer = sw.run_multisteps(first_step, multistep, state, N_ITERS - 1, NUM)
     want = dict(zip(periodic_ref.FIELDS, (

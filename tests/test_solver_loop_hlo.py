@@ -126,18 +126,28 @@ def _field_copies(lines):
             if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FIELD}\S* copy\(", ln)]
 
 
-def test_the_loop_of_the_compiled_leg_copies_no_field(compile_leg):
-    """What ``auto`` picks on one chip, at the benchmark's 70 steps: 35
-    two-step chunks, 17 pairs in the loop and one chunk after it."""
-    leg = compile_leg("auto", 70)
-    (body,) = _loop_bodies(leg.as_text())
-    assert len(_kernel_calls(body)) == 2, [
-        ln.split(" = ")[0].strip() for ln in body]
-    assert not _field_copies(body)
-    # one spare set of six fields and no more: the second call of an
+@pytest.mark.parametrize("mode,kind,sets", [
+    # the whole-step kernel on the ``State``, by name while the mode lives:
+    # one spare set of six fields and no more, the second call of an
     # iteration writes where the first has read
+    ("pallas2", "sw_steps", 1.1),
+    # what ``auto`` picks on one periodic chip since PR 38: the wide-halo
+    # pair on the carried frame (the walled leg's 2.26 sets, test below)
+    ("auto", "sw_wide", 2.4),
+])
+def test_the_loop_of_the_compiled_leg_copies_no_field(compile_leg, mode,
+                                                      kind, sets):
+    """One periodic chip at the benchmark's 70 steps: 35 two-step chunks,
+    17 pairs in the loop and one chunk after it."""
+    leg = compile_leg(mode, 70)
+    text = leg.as_text()
+    (body,) = _loop_bodies(text)
+    assert len(_kernel_calls(body, 2, kind)) == 2, [
+        ln.split(" = ")[0].strip() for ln in body]
+    assert _trip_counts(text) == [17]
+    assert not _field_copies(body) and not _frame_copies(body)
     temp = leg.memory_analysis().temp_size_in_bytes
-    assert temp < 1.1 * SIX_FIELDS, (temp, SIX_FIELDS)
+    assert temp < sets * SIX_FIELDS, (temp, SIX_FIELDS)
 
 
 @pytest.mark.parametrize("mode,steps,loops,in_line", [
@@ -170,10 +180,12 @@ def test_short_runs_hold_two_spare_sets_at_most(compile_leg, mode, steps,
 
 def test_the_periodic_ten_step_call_copies_fields_at_its_boundary_only(
         compile_leg):
-    """The call the documented host loop makes 44 times a run on one
-    periodic chip (``make_stepper(cfg, comm, fast="auto")``'s ``multistep``
-    at ``num_multisteps`` = 10, the benchmark's
-    ``sw3600x28800_solve.1chip``): five two-step chunks — a loop of two
+    """The call the documented host loop made 44 times a run on one
+    periodic chip until PR 38 gave that chip the carried frame, and makes
+    under ``fast="pallas2"`` while the mode lives (``make_stepper``'s
+    ``multistep`` at ``num_multisteps`` = 10; what the benchmark's
+    ``sw3600x28800_solve.1chip`` calls now is the carried call held
+    below): five two-step chunks — a loop of two
     trips with two kernel calls in its body, the fifth call behind it — no
     field copied in the loop, twelve at the most at the region's entry and
     exit (changes of layout of the six parameters and the six results: 6
@@ -182,7 +194,7 @@ def test_the_periodic_ten_step_call_copies_fields_at_its_boundary_only(
     (1.00 here, 2.05 at 3600 x 28800: 5,133,318,144 B, which is what lets
     four states and a call's temporaries fit a 16.9e9-byte chip; both
     sizes compiled for a described v5e, PR 35)."""
-    call = compile_leg("auto", 10, multistep=True)
+    call = compile_leg("pallas2", 10, multistep=True)
     text = call.as_text()
     (body,), entry = _split_at_loops(text)
     assert _trip_counts(text) == [2]
@@ -320,15 +332,21 @@ def _crops(lines):  # a frame cut back to a field
             if re.match(rf"\s*(ROOT )?%[\w.\-]+ = {FIELD}\S* slice\(", ln)]
 
 
+@pytest.mark.parametrize("periodic_x", [False, True])
 @pytest.mark.parametrize("steps,trips,in_line", [
     (6, [], 3),     # a call off the parameters and two rounds, inlined
     (10, [2], 1),   # solve()'s default: the call and four rounds, two pairs
     (12, [2], 2),   # ... and an odd round behind the loop
 ])
 def test_the_carried_multistep_neither_builds_nor_crops_a_frame(
-        compile_leg, steps, trips, in_line):
-    """What ``run_multisteps`` calls 44 times a published run, at a width
-    whose unaligned frame XLA:TPU would hand over transposed, as it did at
+        compile_leg, steps, trips, in_line, periodic_x):
+    """What ``run_multisteps`` calls 44 times a published run — on the
+    closed basin, and since PR 38 on the periodic chip too, where the x
+    bands of a refresh are slices of the frame itself and not zeros (the
+    case ``_wide_refresh`` writes its bands with ``dynamic_update_slice``
+    for: a scatter there kept a whole-frame copy a field a refresh) — at
+    a width whose unaligned frame XLA:TPU would hand over transposed, as
+    it did at
     3600 x 28800 (``f32[1,8222,1054]{1,2,0}``; aligned it is
     ``f32[1,8224,1152]``): ``steps`` steps on the six frames the call
     before left, six frames out.  A ``sw_wide_x2`` call straight off the
@@ -342,12 +360,12 @@ def test_the_carried_multistep_neither_builds_nor_crops_a_frame(
     where the copies' buffers made it two."""
     import shallow_water as sw
 
-    carried = compile_leg("auto", steps, periodic_x=False,
+    carried = compile_leg("auto", steps, periodic_x=periodic_x,
                           multistep="carried")
     text = carried.as_text()
     bodies, outside = _split_at_loops(text)
-    plan = sw.run_plan(sw.Config(nx=NX, ny=NY, periodic_x=False), "auto",
-                       44, steps)["multistep"]
+    plan = sw.run_plan(sw.Config(nx=NX, ny=NY, periodic_x=periodic_x),
+                       "auto", 44, steps)["multistep"]
     rounds = steps // 2
     assert (plan["chunk_calls"], plan["band_refreshes"],
             plan["frames_built"], plan["crops"]) == (rounds, rounds, 0, 0)

@@ -90,12 +90,13 @@ def _assert_fields_close(a, b, what):
 
 
 def test_whole_step_pair_kernel_compiled():
-    """The benchmark path: the fused whole-step pair kernel, Mosaic-
-    compiled (multi-block grid: ny_local = 2 x _PBLK)."""
-    from shallow_water import Config, model_step_pallas, select_steps
+    """The fused whole-step pair kernel, Mosaic-compiled (multi-block
+    grid: ny_local = 2 x _PBLK), by name: the benchmark's path until PR 38
+    gave one periodic chip the wide-halo kernel wherever it fits."""
+    from shallow_water import Config, model_step_wide, select_steps
 
     cfg = Config(nproc_y=1, nproc_x=1, nx=512, ny=254)
-    assert select_steps("auto", cfg)[0] is model_step_pallas
+    assert select_steps("auto", cfg)[0] is model_step_wide
     _assert_fields_close(
         _run(cfg, "pallas2", 7), _run(cfg, True, 7), "pallas2"
     )
@@ -115,9 +116,10 @@ def test_wide_halo_kernel_compiled():
 
 
 def test_wide_halo_kernel_compiled_periodic():
-    """Wide path on a periodic config: the wrap self-exchanges are elided
-    to identity routings; the compiled kernel must agree with the
-    specialist whole-step kernel's physics."""
+    """Wide path on a periodic config, what ``auto`` gives it (the
+    benchmark's path since PR 38): the wrap self-exchanges are elided to
+    identity routings; the compiled kernel must agree with the specialist
+    whole-step kernel's physics."""
     from shallow_water import Config
 
     cfg = Config(nproc_y=1, nproc_x=1, nx=512, ny=254)

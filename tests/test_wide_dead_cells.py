@@ -16,6 +16,7 @@ zero bands would need: ROADMAP A2d.)
 import functools
 import os
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -104,3 +105,46 @@ def test_the_dead_cells_are_built_finite_and_stay_so(periodic_x):
     # beyond-wall zeros that the margin itself holds)
     depth = np.asarray(built[0])[:, M:row - M, col:]
     assert (depth > 0).all() == periodic_x
+
+
+@pytest.mark.parametrize("fill", [np.nan, 1e30])
+@pytest.mark.parametrize("ny", [32, 29])  # no dead row; three of them
+def test_a_periodic_chips_host_loop_run_never_reads_its_dead_cells(ny, fill):
+    """What ``auto`` gives one periodic rank since PR 38, over a run of the
+    documented host loop — 1 + 10 x 10 steps through ``run_multisteps`` on
+    the carried frame, whose x bands every refresh reads out of the frame
+    itself and whose dead columns the periodic masks *advance* like any
+    cell: with the dead cells of every frame a call hands on overwritten
+    (eleven times a run), the cropped ``State`` has the bits of the run
+    that was left alone."""
+    cfg = sw.Config(nx=64, ny=ny)
+    _mesh, comm = sw.make_mesh_and_comm(cfg, devices=jax.devices()[:1])
+    first_step, multistep = sw.make_stepper(cfg, comm, fast="auto")
+    assert sw._resolve_mode("auto", cfg) == "wide2"
+    assert multistep.carried is not multistep
+    state = sw.initial_state(cfg, comm)
+    row, col = _dead(cfg)
+    planted = []
+
+    def spoiled(frames):
+        assert {f.shape[1:] for f in frames} == {_frame_shape(cfg)}
+        planted.append(len(frames))
+        return tuple(f.at[:, row:, :].set(fill).at[:, :, col:].set(fill)
+                     for f in frames)
+
+    # the pair as the loop sees it: the carried forms and the crop alone
+    first = types.SimpleNamespace(
+        carried=lambda s: spoiled(first_step.carried(s)))
+    multi = types.SimpleNamespace(
+        carried=lambda fr, n: spoiled(multistep.carried(fr, n)),
+        crop=multistep.crop)
+
+    want = sw.run_multisteps(first_step, multistep, state, 10, NUM)
+    got = sw.run_multisteps(first, multi, state, 10, NUM)
+    assert planted == [6] * 11
+    assert isinstance(got, sw.State)
+    for name, a, b in zip(want._fields, got, want):
+        assert a.shape == state.h.shape
+        assert np.isfinite(np.asarray(b)).all(), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    assert np.abs(np.asarray(want.h) - np.asarray(state.h)).max() > 1e-2
