@@ -47,7 +47,9 @@ __all__ = [
 
 
 def reference_attention(q, k, v, *, causal=False):
-    """Plain full attention (B, T, H, D) — the single-device ground truth."""
+    """Plain full attention (B, T, H, D) — the single-device ground truth.
+    ``v`` may have its own width (B, T, H, Dv); the scale is ``1/sqrt`` of
+    the query/key width."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
@@ -276,7 +278,9 @@ _ring_attention_me.defvjp(_ring_me_fwd, _ring_me_bwd)
 def flash_attention(q, k, v, causal=False):
     """Single-device attention via the fused flash kernel: block partials +
     normalization, so the (T, T) score matrix never reaches HBM (the
-    ``reference_attention`` einsum materializes it).  Causal uses the
+    ``reference_attention`` einsum materializes it).  ``v`` may be narrower
+    than ``q``/``k`` (latent attention's 128 against 192); the scale is
+    ``1/sqrt`` of the query/key width.  Causal uses the
     key-tile-skipping kernel on TPU; non-causal streams (512, 512) key
     tiles with online-softmax carries, so the live score tile is fixed-
     size regardless of sequence length — the VMEM ceiling is the K/V

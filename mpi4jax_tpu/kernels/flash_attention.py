@@ -115,7 +115,7 @@ def _kernel(*refs, bq, bk, tk, n_kt, has_mask):
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref = refs
         mask_ref = None
     q = q_ref[0]
-    d = q.shape[-1]
+    dv = v_ref.shape[-1]
 
     ragged = tk != n_kt * bk
 
@@ -136,7 +136,7 @@ def _kernel(*refs, bq, bk, tk, n_kt, has_mask):
             s = jnp.where(valid, s, -jnp.inf)
         return _merge_tile(carry, s, vv)
 
-    m, l, acc = jax.lax.fori_loop(0, n_kt, body, _carry_init(bq, d))
+    m, l, acc = jax.lax.fori_loop(0, n_kt, body, _carry_init(bq, dv))
     o_ref[0] = acc.astype(o_ref.dtype)
     m_ref[0, 0] = m
     l_ref[0, 0] = l
@@ -152,7 +152,7 @@ def _kernel_causal(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, bq, bk, tk):
     same partials contract as ``_kernel``."""
     qi = pl.program_id(1)
     q = q_ref[0]
-    d = q.shape[-1]
+    dv = v_ref.shape[-1]
 
     def load_tile(ref, kt):
         return ref[0, pl.dslice(kt * bk, bk), :]
@@ -162,7 +162,7 @@ def _kernel_causal(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, bq, bk, tk):
                     preferred_element_type=jnp.float32)
         return _merge_tile(carry, s, load_tile(v_ref, kt))
 
-    m, l, acc = jax.lax.fori_loop(0, qi, body, _carry_init(bq, d))
+    m, l, acc = jax.lax.fori_loop(0, qi, body, _carry_init(bq, dv))
 
     # boundary tile: triangular causal mask on global positions, plus the
     # ragged-tail guard (the final tile's rows beyond tk read clamped data)
@@ -177,10 +177,11 @@ def _kernel_causal(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, bq, bk, tk):
     l_ref[0, 0] = l
 
 
-def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp):
+def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp,
+                   name="flash_attention"):
     """Forward partials — see ``flash_block_partials`` for the contract."""
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
 
     if not _use_kernel(interpret, force_jnp):
         if causal:
@@ -207,7 +208,7 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp):
     # walk (bq, bk) key tiles, so the live score tile is fixed-size and the
     # VMEM ceiling is set by the K/V residency (~2·Tk·D·itemsize), not Tk²
     def to_bht(x, t):
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
+        return jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1])
 
     bq = _Q_TILE if tq > _Q_TILE else tq  # partial final tiles are fine
     bk = bq if causal else (_K_TILE if tk > _K_TILE else tk)
@@ -218,17 +219,21 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp):
     # outputs must be typed varying over the same axes as the inputs
     vma = frozenset(getattr(jax.typeof(q), "vma", frozenset()))
     out_shapes = (
-        jax.ShapeDtypeStruct((b * h, tq, d), q.dtype, vma=vma),
+        jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype, vma=vma),
         jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32, vma=vma),
         jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32, vma=vma),
     )
     q_spec = pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, tk_pad, d), lambda i, j: (i, 0, 0),
-                           memory_space=pltpu.VMEM)
+    k_spec = pl.BlockSpec((1, tk_pad, d), lambda i, j: (i, 0, 0),
+                          memory_space=pltpu.VMEM)
+    v_spec = pl.BlockSpec((1, tk_pad, dv), lambda i, j: (i, 0, 0),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, bq, dv), lambda i, j: (i, j, 0),
+                          memory_space=pltpu.VMEM)
     ml_spec = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
                            memory_space=pltpu.VMEM)
-    in_specs = [q_spec, kv_spec, kv_spec]
+    in_specs = [q_spec, k_spec, v_spec]
     # pad K/V to a whole number of key tiles: pl.dslice would CLAMP the
     # last tile's start otherwise, silently misaligning the positional
     # masks; padded keys sit at kpos >= tk, which both kernels' ragged
@@ -251,10 +256,10 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp):
             operands.append(_pad_to(mask, 1, tk_pad))
     o_bht, m_f, l_f = pl.pallas_call(
         kernel,
-        name="flash_attention_fwd",
+        name=name + "_fwd",
         grid=grid,
         in_specs=in_specs,
-        out_specs=(q_spec, ml_spec, ml_spec),
+        out_specs=(o_spec, ml_spec, ml_spec),
         out_shape=out_shapes,
         interpret=interpret,
         compiler_params=(
@@ -263,7 +268,7 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp):
             )
         ),
     )(*operands)
-    o = jnp.moveaxis(o_bht.reshape(b, h, tq, d), 1, 2)
+    o = jnp.moveaxis(o_bht.reshape(b, h, tq, dv), 1, 2)
     m = m_f.reshape(b, h, tq)
     l = l_f.reshape(b, h, tq)
     return o, m, l
@@ -316,7 +321,7 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, tk, n_kt, has_mask):
     m = m_ref[0, 0]
     gl = gl_ref[0, 0]
     m_safe = jnp.where(jnp.isinf(m), 0.0, m)
-    d = q.shape[-1]
+    d = q.shape[-1]  # the q/k width; v and g_o may be narrower
     qpos = qj * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
 
     def body(kt, acc):
@@ -357,7 +362,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, n_qt, has_mask):
     kj = pl.program_id(1)
     kk = k_ref[0].astype(jnp.float32)
     vv = v_ref[0].astype(jnp.float32)
-    d = kk.shape[-1]
+    d, dv = kk.shape[-1], vv.shape[-1]
     kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
 
     def body(qj, carry):
@@ -388,14 +393,16 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, n_qt, has_mask):
 
     lo = kj if causal else 0  # causal: query tiles before kj see no key here
     zeros = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, n_qt, body, (zeros, zeros))
+    dv_zeros = zeros if dv == d else jnp.zeros((bk, dv), jnp.float32)
+    dk, dv = jax.lax.fori_loop(lo, n_qt, body, (zeros, dv_zeros))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
+def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
+                       name="flash_attention"):
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     bq = _Q_TILE if tq > _Q_TILE else tq
     bk = bq if causal else (_Q_TILE if tk > _Q_TILE else tk)
     n_qt = (tq + bq - 1) // bq
@@ -403,7 +410,8 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
     tq_pad, tk_pad = n_qt * bq, n_kt * bk
 
     def to_bht(x, t, tp):
-        return _pad_to(jnp.moveaxis(x, 2, 1).reshape(b * h, t, d), 1, tp)
+        return _pad_to(jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1]),
+                       1, tp)
 
     qf = to_bht(q, tq, tq_pad)
     gof = to_bht(g_o, tq, tq_pad)
@@ -419,14 +427,14 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
         maskf = _pad_to(_pad_to(mask, 0, tq_pad), 1, tk_pad)
 
     vma = frozenset(getattr(jax.typeof(q), "vma", frozenset()))
-    tile_spec = pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
-                             memory_space=pltpu.VMEM)
-    ktile_spec = pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
-                              memory_space=pltpu.VMEM)
-    qwhole_spec = pl.BlockSpec((1, tq_pad, d), lambda i, j: (i, 0, 0),
-                               memory_space=pltpu.VMEM)
-    kwhole_spec = pl.BlockSpec((1, tk_pad, d), lambda i, j: (i, 0, 0),
-                               memory_space=pltpu.VMEM)
+    def tile_spec(rows, width):
+        return pl.BlockSpec((1, rows, width), lambda i, j: (i, j, 0),
+                            memory_space=pltpu.VMEM)
+
+    def whole_spec(rows, width):
+        return pl.BlockSpec((1, rows, width), lambda i, j: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
     mtile_spec = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
                               memory_space=pltpu.VMEM)
     params = (
@@ -436,8 +444,9 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
     )
 
     # dq: one grid step per (batch*head, query tile), loop over key tiles
-    dq_in_specs = [tile_spec, kwhole_spec, kwhole_spec, mtile_spec,
-                   mtile_spec, tile_spec]
+    dq_in_specs = [tile_spec(bq, d), whole_spec(tk_pad, d),
+                   whole_spec(tk_pad, dv), mtile_spec, mtile_spec,
+                   tile_spec(bq, dv)]
     dq_operands = [qf, kf, vf, mf, glf, gof]
     if maskf is not None:
         dq_in_specs.append(
@@ -450,10 +459,10 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
             _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
             tk=tk, n_kt=n_kt, has_mask=maskf is not None,
         ),
-        name="flash_attention_bwd_dq",
+        name=name + "_bwd_dq",
         grid=(b * h, n_qt),
         in_specs=dq_in_specs,
-        out_specs=tile_spec,
+        out_specs=tile_spec(bq, d),
         out_shape=jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype, vma=vma),
         interpret=interpret,
         compiler_params=params,
@@ -468,8 +477,9 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
                            memory_space=pltpu.VMEM)
     mf_t = jnp.swapaxes(mf, 1, 2)
     glf_t = jnp.swapaxes(glf, 1, 2)
-    dkv_in_specs = [qwhole_spec, ktile_spec, ktile_spec, mT_spec,
-                    mT_spec, qwhole_spec]
+    dkv_in_specs = [whole_spec(tq_pad, d), tile_spec(bk, d),
+                    tile_spec(bk, dv), mT_spec, mT_spec,
+                    whole_spec(tq_pad, dv)]
     dkv_operands = [qf, kf, vf, mf_t, glf_t, gof]
     if maskf is not None:
         dkv_in_specs.append(
@@ -482,39 +492,41 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret):
             _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
             tq=tq, tk=tk, n_qt=n_qt, has_mask=maskf is not None,
         ),
-        name="flash_attention_bwd_dkv",
+        name=name + "_bwd_dkv",
         grid=(b * h, n_kt),
         in_specs=dkv_in_specs,
-        out_specs=(ktile_spec, ktile_spec),
+        out_specs=(tile_spec(bk, d), tile_spec(bk, dv)),
         out_shape=(
             jax.ShapeDtypeStruct((b * h, tk_pad, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b * h, tk_pad, d), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b * h, tk_pad, dv), v.dtype, vma=vma),
         ),
         interpret=interpret,
         compiler_params=params,
     )(*dkv_operands)
 
     def from_bht(x, t):
-        return jnp.moveaxis(x[:, :t].reshape(b, h, t, d), 1, 2)
+        return jnp.moveaxis(x[:, :t].reshape(b, h, t, x.shape[-1]), 1, 2)
 
     return from_bht(dq_f, tq), from_bht(dk_f, tk), from_bht(dv_f, tk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _partials(scale, causal, interpret, q, k, v, mask):
-    return _partials_impl(q, k, v, mask, scale, causal, interpret, False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _partials(scale, causal, interpret, name, q, k, v, mask):
+    return _partials_impl(q, k, v, mask, scale, causal, interpret, False,
+                          name)
 
 
-def _partials_fwd(scale, causal, interpret, q, k, v, mask):
-    o, m, l = _partials_impl(q, k, v, mask, scale, causal, interpret, False)
+def _partials_fwd(scale, causal, interpret, name, q, k, v, mask):
+    o, m, l = _partials_impl(q, k, v, mask, scale, causal, interpret, False,
+                             name)
     return (o, m, l), (q, k, v, mask, m)
 
 
-def _partials_bwd(scale, causal, interpret, res, cts):
+def _partials_bwd(scale, causal, interpret, name, res, cts):
     q, k, v, mask, m = res
     g_o, _g_m, g_l = cts  # g_m dropped: stop-gradient stabilizer (see above)
     dq, dk, dv = _partials_bwd_impl(
-        q, k, v, mask, m, g_o, g_l, scale, causal, interpret
+        q, k, v, mask, m, g_o, g_l, scale, causal, interpret, name
     )
     dmask = None if mask is None else np.zeros(mask.shape, jax.dtypes.float0)
     return dq, dk, dv, dmask
@@ -524,7 +536,8 @@ _partials.defvjp(_partials_fwd, _partials_bwd)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "causal", "interpret", "force_jnp")
+    jax.jit,
+    static_argnames=("scale", "causal", "interpret", "force_jnp", "name"),
 )
 def flash_block_partials(
     q,
@@ -536,11 +549,14 @@ def flash_block_partials(
     causal: bool = False,
     interpret: bool = False,
     force_jnp: bool = False,
+    name: str = "flash_attention",
 ):
     """Streaming-softmax partials of ``softmax(q k^T * scale) v`` for one
     K/V block.
 
-    ``q``: (B, Tq, H, D); ``k``/``v``: (B, Tk, H, D); ``mask``: (Tq, Tk)
+    ``q``/``k``: (B, Tq|Tk, H, D); ``v``: (B, Tk, H, Dv), where the value
+    width ``Dv`` may differ from the query/key width ``D`` (latent
+    attention: 192 against 128); ``mask``: (Tq, Tk)
     bool, True = attend (shared across batch and heads — the ring-step
     causal mask depends only on block offsets), or ``None`` for no masking
     (skips the mask load and selects entirely).
@@ -551,7 +567,11 @@ def flash_block_partials(
     MXU work than masking a fully-computed score block.  Semantically
     identical to ``mask=jnp.tril(...)``.
 
-    Returns ``(o_part, m, l)`` with shapes (B, Tq, H, D), (B, H, Tq),
+    ``name`` prefixes the kernels' names, which the device trace shows as
+    their HLO instructions: ``<name>_fwd``, ``<name>_bwd_dq``,
+    ``<name>_bwd_dkv``.
+
+    Returns ``(o_part, m, l)`` with shapes (B, Tq, H, Dv), (B, H, Tq),
     (B, H, Tq); ``m``/``l`` are float32, ``o_part`` keeps ``q``'s dtype
     (both paths).  Rows with no attendable key get ``m = -inf``, ``l = 0``,
     ``o_part = 0``.
@@ -577,7 +597,7 @@ def flash_block_partials(
                 f"Tq == Tk, got {q.shape[1]} vs {k.shape[1]}"
             )
     if _use_kernel(interpret, force_jnp):
-        return _partials(scale, causal, interpret, q, k, v, mask)
+        return _partials(scale, causal, interpret, name, q, k, v, mask)
     return _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp)
 
 

@@ -1,5 +1,6 @@
 """Expert-parallel mixture-of-experts: capacity-bucketed alltoall
-dispatch/combine around a per-expert MLP (docs/moe.md).
+dispatch/combine around a per-expert MLP (docs/moe.md), and the
+held-experts layer of a top-k sigmoid-routed MoE (``held_experts_layer``).
 
 The workload class ROADMAP item 5a names: ``k`` ranks each own ONE
 expert, tokens are routed by a top-1 gate, and the two hottest
@@ -50,6 +51,10 @@ __all__ = [
     "expert_mlp",
     "moe_layer",
     "reference_moe",
+    "HeldExperts",
+    "route_sigmoid_topk",
+    "held_capacity_rows",
+    "held_experts_layer",
 ]
 
 
@@ -235,3 +240,122 @@ def reference_moe(x_global, d_ff: int, experts: int, *, seed: int = 0,
         out[r] = np.einsum("tec,ecd->td", Ds[r],
                            combined) * gates[r][:, None]
     return out
+
+
+# ---------------------------------------------------------------------------
+# the held-experts layer: top-k sigmoid routing over every expert, grouped
+# products over the experts this chip holds, no token dropped
+# ---------------------------------------------------------------------------
+
+
+class HeldExperts(NamedTuple):
+    """One chip's share of a routed-expert layer: the router over ALL
+    experts and the SwiGLU weights of the experts it holds."""
+
+    router: object     # (d, experts) — every expert's router column
+    w_gate_up: object  # (held, d, 2 * d_ff) — gate and up, side by side
+    w_down: object     # (held, d_ff, d)
+
+
+def route_sigmoid_topk(x, router, bias, *, top_k: int,
+                       routed_scaling: float):
+    """Sigmoid top-k routing with a selection bias, in float32 (the
+    DeepSeek-V3 ``noaux_tc`` rule with one group): ``s = sigmoid(x @
+    router)``; the experts chosen are the ``top_k`` largest of ``s +
+    bias``; their weights are ``routed_scaling * s_i / sum_chosen s``.
+    ``bias`` only selects (it carries no gradient).  Returns ``(experts
+    (t, k) int32, weights (t, k) f32, loads (experts,) int32)``: ``loads``
+    counts, for every expert, the tokens that chose it."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    weights = routed_scaling * chosen / (chosen.sum(-1, keepdims=True)
+                                         + 1e-20)
+    loads = jnp.zeros(router.shape[1], jnp.int32).at[idx.reshape(-1)].add(1)
+    return idx.astype(jnp.int32), weights, loads
+
+
+def held_capacity_rows(tokens: int, top_k: int, held: int) -> int:
+    """Rows of the dispatch buffer: every held assignment of ``tokens``
+    (a token chooses at most ``min(top_k, held)`` held experts) in whole
+    tiles, a group's last tile padded, each held expert given at least
+    one tile — so no routing, however uneven, drops a token."""
+    from ..kernels.grouped_matmul import TILE_ROWS
+
+    tiles = tokens * min(top_k, held) // TILE_ROWS + held + 1
+    return tiles * TILE_ROWS
+
+
+def held_experts_layer(x, params: HeldExperts, bias, *, held, top_k: int,
+                       routed_scaling: float, interpret: bool = False):
+    """The routed part of a top-k sigmoid MoE layer on the chip that holds
+    experts ``held = (first, count)`` of ``params.router.shape[1]``.
+
+    Every token is routed over all experts (``route_sigmoid_topk``); the
+    assignments to held experts are sorted by expert into whole tiles of
+    a dispatch buffer (``held_capacity_rows``: nothing is dropped) and
+    run through the grouped products of ``kernels/grouped_matmul.py``
+    (``moe_gmm``: gate and up side by side, then down, a SwiGLU expert);
+    each output row, weighted, is added back to its token.  Assignments
+    to experts held elsewhere are left out: they are the other chips'
+    part of the result.  A shared expert, which every chip computes
+    alike, is the caller's.
+
+    The products take bfloat16 operands and accumulate in float32.  The
+    layer runs on one chip's tokens without exchange; the dispatch over
+    ranks is not implemented.  Returns ``(y, loads)``: ``y`` float32 like
+    ``x``,
+    ``loads`` (experts,) int32 — how many of this chip's tokens chose
+    each expert (the selection bias's update reads it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels.grouped_matmul import TILE_ROWS, grouped_matmul
+
+    first, count = held
+    tokens, d = x.shape
+    idx, weights, loads = route_sigmoid_topk(
+        x, params.router, bias, top_k=top_k, routed_scaling=routed_scaling)
+
+    # assignments sorted by held expert; the others sort last
+    local = idx.reshape(-1) - first
+    is_held = (local >= 0) & (local < count)
+    key = jnp.where(is_held, local, count)
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    held_loads = jax.lax.dynamic_slice_in_dim(loads, first, count)
+    tiles = jnp.maximum(1, -(-held_loads // TILE_ROWS))
+    tile_start = jnp.cumsum(tiles) - tiles
+    group_start = jnp.cumsum(held_loads) - held_loads
+    rows = held_capacity_rows(tokens, top_k, count)
+    safe_key = jnp.minimum(sorted_key, count - 1)
+    rank = jnp.arange(order.shape[0]) - group_start[safe_key]
+    dest = jnp.where(sorted_key < count,
+                     tile_start[safe_key] * TILE_ROWS + rank, rows)
+    row_token = jnp.full((rows,), tokens, jnp.int32).at[dest].set(
+        (order // top_k).astype(jnp.int32), mode="drop")
+    row_weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
+        weights.reshape(-1)[order], mode="drop")
+    n_tiles = jnp.sum(tiles).astype(jnp.int32).reshape(1)
+    tile_expert = jnp.repeat(jnp.arange(count, dtype=jnp.int32), tiles,
+                             total_repeat_length=rows // TILE_ROWS)
+
+    xo = x.astype(jnp.bfloat16)
+    x_rows = jnp.concatenate([xo, jnp.zeros((1, d), xo.dtype)])[row_token]
+    gate_up = grouped_matmul(x_rows, params.w_gate_up, tile_expert, n_tiles,
+                             name="moe_gmm", interpret=interpret)
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    h = (jax.nn.silu(gate) * up).astype(jnp.bfloat16)
+    out = grouped_matmul(h, params.w_down, tile_expert, n_tiles,
+                         name="moe_gmm", interpret=interpret)
+    # rows past the live tiles are not written: weight 0 and a select keep
+    # whatever they hold out of the sum
+    live = jnp.arange(rows) < n_tiles[0] * TILE_ROWS
+    contrib = jnp.where(live[:, None], out * row_weight[:, None], 0.0)
+    y = jnp.zeros((tokens + 1, d), jnp.float32).at[row_token].add(contrib)
+    return y[:tokens], loads
