@@ -47,6 +47,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -510,6 +511,13 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
     return from_bht(dq_f, tq), from_bht(dk_f, tk), from_bht(dv_f, tk)
 
 
+def partials_name(name: str) -> str:
+    """The ``checkpoint_name`` of the outputs of the kernel named
+    ``name``: ``jax.checkpoint_policies.save_only_these_names`` of it keeps
+    them through a ``jax.checkpoint``."""
+    return name + "_partials"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
 def _partials(scale, causal, interpret, name, q, k, v, mask):
     return _partials_impl(q, k, v, mask, scale, causal, interpret, False,
@@ -517,8 +525,11 @@ def _partials(scale, causal, interpret, name, q, k, v, mask):
 
 
 def _partials_fwd(scale, causal, interpret, name, q, k, v, mask):
-    o, m, l = _partials_impl(q, k, v, mask, scale, causal, interpret, False,
-                             name)
+    # named so that a rematerialisation policy can keep the kernel's
+    # outputs, the residual ``m`` with them (see ``flash_block_partials``)
+    o, m, l = checkpoint_name(
+        _partials_impl(q, k, v, mask, scale, causal, interpret, False, name),
+        partials_name(name))
     return (o, m, l), (q, k, v, mask, m)
 
 
@@ -569,7 +580,15 @@ def flash_block_partials(
 
     ``name`` prefixes the kernels' names, which the device trace shows as
     their HLO instructions: ``<name>_fwd``, ``<name>_bwd_dq``,
-    ``<name>_bwd_dkv``.
+    ``<name>_bwd_dkv``.  On the kernel path the forward's three outputs
+    carry the ``checkpoint_name`` :func:`partials_name` (``<name>_partials``):
+    under ``jax.checkpoint`` with
+    ``policy=jax.checkpoint_policies.save_only_these_names(...)`` of it the
+    backward reads them, and the forward kernel runs once; under any other
+    policy, or none, the name changes nothing.  jax keeps what it derived
+    for a call at given shapes for the next trace of the same call, under
+    whatever policy: a process that traces one call under two policies
+    clears jax's caches (``jax.clear_caches()``) between them.
 
     Returns ``(o_part, m, l)`` with shapes (B, Tq, H, Dv), (B, H, Tq),
     (B, H, Tq); ``m``/``l`` are float32, ``o_part`` keeps ``q``'s dtype

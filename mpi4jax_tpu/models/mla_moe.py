@@ -32,7 +32,9 @@ experts would add is left out.
 moments in float32; matmul operands in bfloat16 (``precision.
 matmul_operands``) with float32 accumulation; norms, softmax, router,
 losses and the update in float32.  Every decoder layer is rematerialised
-in the backward pass, the MoE layers run as one ``lax.scan``, and the
+in the backward pass but for the attention kernel's partials (``o_part``,
+``m``, ``l``), which the layer keeps, so the forward kernel runs once a
+layer and step; the MoE layers run as one ``lax.scan``, and the
 cross-entropy is computed in chunks of tokens.
 
 :func:`init_state` wraps the caller's initial parameters in the step-0
@@ -47,7 +49,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..kernels.flash_attention import flash_block_partials
+from ..kernels.flash_attention import flash_block_partials, partials_name
 from ..parallel.moe import HeldExperts, held_experts_layer
 
 __all__ = [
@@ -66,6 +68,16 @@ GRAD_GROUPS = ("embed", "head", "attn", "dense_ffn", "shared", "routed",
 ATTENTION_KERNEL = "mla_attention"
 EXPERT_KERNEL = "moe_gmm"
 _CE_CHUNK = 1024
+# what a decoder layer's rematerialisation keeps: the attention kernel's
+# partials (about 69 MB a layer at 8192 tokens and 32 heads of value width
+# 128), so the backward reads them instead of running the kernel again;
+# the projections, the experts and the norms are recomputed
+_LAYER_POLICY = jax.checkpoint_policies.save_only_these_names(
+    partials_name(ATTENTION_KERNEL))
+
+
+def _remat_layer(f):
+    return jax.checkpoint(f, policy=_LAYER_POLICY)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,10 +330,10 @@ class _Model:
         positions ``0..T-1`` of ``tokens`` (``T + 2`` ids), and the MoE
         layers' loads ``(moe_layers, experts)``."""
         x = params["embed"][tokens[:-2]]
-        x, _ = jax.checkpoint(lambda p, x: self.block(p, None, x))(
+        x, _ = _remat_layer(lambda p, x: self.block(p, None, x))(
             params["dense"], x)
 
-        @jax.checkpoint
+        @_remat_layer
         def moe_layer(x, pb):
             return self.block(pb[0], pb[1], x)
 
@@ -334,7 +346,7 @@ class _Model:
         joined = jnp.concatenate(
             [_rms_norm(h, m["h_norm"], eps),
              _rms_norm(params["embed"][tokens[1:-1]], m["e_norm"], eps)], -1)
-        y, loads = jax.checkpoint(lambda p, b, x: self.block(p, b, x))(
+        y, loads = _remat_layer(lambda p, b, x: self.block(p, b, x))(
             m["block"], bias["mtp"], self.mm(joined, m["eh_proj"]))
         return self.cross_entropy(y, m["final_norm"], params["head"],
                                   tokens[2:]), loads
@@ -429,12 +441,13 @@ def make_train_step(d: Dims, *, interpret=False):
 
 def train_plan(d: Dims) -> dict:
     """Kernel calls one step makes, by kernel name: each attention layer's
-    forward twice (the pass and its rematerialisation in the backward),
-    its two backward kernels once; each MoE layer's two grouped products
-    twice, their input and weight gradients once."""
+    forward once (its rematerialisation in the backward keeps the kernel's
+    partials and does not run it again) and its two backward kernels once;
+    each MoE layer's two grouped products twice (the pass and the
+    rematerialisation), their input and weight gradients once."""
     attn_layers = 1 + d.moe_layers + 1
     moe_layers = d.moe_layers + 1
-    return {f"{ATTENTION_KERNEL}_fwd": 2 * attn_layers,
+    return {f"{ATTENTION_KERNEL}_fwd": attn_layers,
             f"{ATTENTION_KERNEL}_bwd_dq": attn_layers,
             f"{ATTENTION_KERNEL}_bwd_dkv": attn_layers,
             EXPERT_KERNEL: 4 * moe_layers,

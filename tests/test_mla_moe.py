@@ -20,7 +20,9 @@ least one of these by more.
 
 import math
 import os
+import re
 import sys
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -34,6 +36,7 @@ if REPO not in sys.path:
 
 from chipbench.reference import mla_moe as ref  # noqa: E402
 from mpi4jax_tpu.attention import reference_attention  # noqa: E402
+from mpi4jax_tpu.kernels import flash_attention  # noqa: E402
 from mpi4jax_tpu.kernels import grouped_matmul as gm  # noqa: E402
 from mpi4jax_tpu.kernels.flash_attention import (  # noqa: E402
     flash_block_partials,
@@ -441,9 +444,77 @@ def test_grouped_products_interpreted_match_the_jnp_path():
 def test_train_plan_counts_the_kernels_a_step_calls():
     d = mla_moe.Dims.from_config(tiny_config())
     assert mla_moe.train_plan(d) == {
-        "mla_attention_fwd": 8, "mla_attention_bwd_dq": 4,
+        "mla_attention_fwd": 4, "mla_attention_bwd_dq": 4,
         "mla_attention_bwd_dkv": 4, "moe_gmm": 12, "moe_gmm_dx": 6,
         "moe_gmm_dw": 6}
+
+
+def _kernel_calls(jaxpr, times=1, calls=None):
+    """``{kernel name: calls}`` of the ``pallas_call``s in ``jaxpr`` and
+    every jaxpr nested in it, a scan's body counted once an iteration."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    calls = Counter() if calls is None else calls
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls[eqn.params["name"]] += times
+            continue
+        n = times * eqn.params["length"] if eqn.primitive.name == "scan" \
+            else times
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, (ClosedJaxpr, Jaxpr)):
+                    _kernel_calls(getattr(sub, "jaxpr", sub), n, calls)
+    return calls
+
+
+def _step_program(d, state, ids, policy=mla_moe._LAYER_POLICY):
+    """The interpreted kernels' step under a layer rematerialisation
+    ``policy`` (None: every value recomputed): its kernel calls by the
+    jaxpr, its new state and outputs, and every gradient.  jax keeps what
+    it derived of a jitted call for the next trace of the same call
+    whatever the enclosing policy, so the caches are cleared around it."""
+    old = mla_moe._LAYER_POLICY
+    jax.clear_caches()
+    try:
+        mla_moe._LAYER_POLICY = policy
+        step = mla_moe.make_train_step(d, interpret=True)
+        calls = _kernel_calls(jax.make_jaxpr(step)(state, ids).jaxpr)
+        new, out = jax.jit(step)(state, ids)
+        grads = jax.jit(jax.grad(lambda p: mla_moe.loss_and_aux(
+            d, p, state["bias"], ids, interpret=True)[0]))(state["params"])
+    finally:
+        mla_moe._LAYER_POLICY = old
+        jax.clear_caches()
+    return calls, (new, out, grads)
+
+
+def test_the_step_calls_each_kernel_as_train_plan_says(case):
+    """The attention and expert kernels in the step's program, each as
+    often as ``train_plan`` says: the plan cannot drift from the
+    program."""
+    c, d, state, ids, _ = case
+    calls, _ = _step_program(d, state, ids)
+    assert dict(calls) == mla_moe.train_plan(d)
+
+
+def test_keeping_the_attention_partials_changes_no_bit(case):
+    """The step that keeps the attention kernel's partials through each
+    layer's rematerialisation against the one that recomputes every value:
+    the forward kernel runs half as often, and the losses, the gradients,
+    the outputs and the updated state are the same bits."""
+    c, d, state, ids, _ = case
+    kept_calls, kept = _step_program(d, state, ids)
+    full_calls, full = _step_program(d, state, ids, policy=None)
+    fwd = "mla_attention_fwd"
+    assert full_calls[fwd] == 2 * kept_calls[fwd] == 2 * mla_moe.train_plan(
+        d)[fwd]
+    assert full_calls - Counter({fwd: full_calls[fwd]}) == \
+        kept_calls - Counter({fwd: kept_calls[fwd]})
+    assert jax.tree.structure(kept) == jax.tree.structure(full)
+    for (path, a), b in zip(jax.tree.flatten_with_path(kept)[0],
+                            jax.tree.leaves(full)):
+        assert np.array_equal(a, b), jax.tree_util.keystr(path)
 
 
 def test_the_step_trains_and_moves_the_bias():
@@ -540,46 +611,89 @@ def topo():
 @pytest.fixture
 def kernels_on(monkeypatch):
     """Take the kernel path on this CPU host, as the chip would."""
-    from mpi4jax_tpu.kernels import flash_attention
-
     monkeypatch.setattr(flash_attention, "_use_kernel",
                         lambda interpret, force_jnp: not force_jnp)
     monkeypatch.setattr(gm, "_use_kernel", lambda interpret: True)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    # jitted calls traced at these shapes on the jnp path, or under another
+    # rematerialisation policy, are not to be reused
+    jax.clear_caches()
     yield
+    jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", cache)
 
 
 def _custom_calls(text):
-    import re
-    from collections import Counter
-
-    return Counter(re.sub(r"(\.\d+)+$", "", n) for n in re.findall(
+    """The named custom calls of a compiled program (XLA's own, named plain
+    ``custom-call``, left out)."""
+    calls = Counter(re.sub(r"(\.\d+)+$", "", n) for n in re.findall(
         r"%([\w.\-]+) = [^\n]*custom-call\(", text))
+    calls.pop("custom-call", None)
+    return calls
 
 
-@pytest.mark.parametrize("name,dqk,dv", [("mla_attention", 192, 128),
-                                         ("flash_attention", 128, 128)])
-def test_attention_kernels_compile_for_v5e(topo, kernels_on, name, dqk, dv):
+def _keep_partials(name):
+    return jax.checkpoint_policies.save_only_these_names(
+        flash_attention.partials_name(name))
+
+
+@pytest.mark.parametrize("name,dqk,dv,remat,forwards", [
+    pytest.param("mla_attention", 192, 128, None, 1,
+                 id="mla_attention-192-128"),
+    pytest.param("flash_attention", 128, 128, None, 1,
+                 id="flash_attention-128-128"),
+    pytest.param("mla_attention", 192, 128, jax.checkpoint, 2,
+                 id="mla_attention-192-128-checkpoint"),
+    pytest.param("mla_attention", 192, 128,
+                 lambda f: jax.checkpoint(
+                     f, policy=_keep_partials("mla_attention")), 1,
+                 id="mla_attention-192-128-checkpoint_keeping_partials"),
+])
+def test_attention_kernels_compile_for_v5e(topo, kernels_on, name, dqk, dv,
+                                           remat, forwards):
     """Forward and both backward kernels at 8192 tokens and 32 heads, the
     cell's widths (192 against 128) and one width; each named in the
-    compiled program as the trace will show it."""
+    compiled program as the trace will show it.  Under a plain
+    ``jax.checkpoint`` the backward runs the forward kernel again, as ring
+    attention's callers get it; under the policy that keeps the kernel's
+    partials it does not."""
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(topo.devices[0])
     qk = jax.ShapeDtypeStruct((1, 8192, 32, dqk), jnp.bfloat16, sharding=one)
     v = jax.ShapeDtypeStruct((1, 8192, 32, dv), jnp.bfloat16, sharding=one)
 
-    def loss(q, k, v):
+    def attend(q, k, v):
         o, _, l = flash_block_partials(q, k, v, None, scale=0.07,
                                        causal=True, name=name)
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(l)
 
-    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(qk, qk, v).compile()\
+    loss = attend if remat is None else remat(attend)
+    # the loss's value too, as a training step reports it: the forward
+    # pass's kernel then stays in the program beside any recomputation
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(qk, qk, v)\
+        .compile().as_text()
+    assert _custom_calls(text) == {f"{name}_fwd": forwards,
+                                   f"{name}_bwd_dq": 1, f"{name}_bwd_dkv": 1}
+
+
+def test_the_tiny_step_compiles_for_v5e_to_train_plan(topo, kernels_on):
+    """The tiny step with one MoE layer (its scan runs once, so the
+    compiled program names each kernel call once) compiled for the chip:
+    every attention and expert kernel as often as ``train_plan`` says."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    c = dict(tiny_config(), num_hidden_layers=2)
+    d = mla_moe.Dims.from_config(c)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: _initial(c, d)))
+    ids = jax.ShapeDtypeStruct((TOKENS + 2,), jnp.int32, sharding=one)
+    text = jax.jit(mla_moe.make_train_step(d)).lower(state, ids).compile()\
         .as_text()
-    assert _custom_calls(text) == {f"{name}_fwd": 1, f"{name}_bwd_dq": 1,
-                                   f"{name}_bwd_dkv": 1}
+    assert _custom_calls(text) == mla_moe.train_plan(d)
 
 
 def test_grouped_products_compile_for_v5e(topo, kernels_on):
