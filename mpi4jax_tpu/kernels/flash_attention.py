@@ -99,6 +99,16 @@ def _pad_to(x, axis, target):
     return jnp.pad(x, pad)
 
 
+def _kv_head(group):
+    """The K/V row of the flattened ``(B*Hkv, T, D)`` layout that query row
+    ``i`` of ``(B*H, T, D)`` reads: ``i // group`` (query heads ``h`` of a
+    batch read K/V head ``h // group``; with one K/V head a query head,
+    ``i`` itself)."""
+    if group == 1:
+        return lambda i: i
+    return lambda i: i // group
+
+
 def _kernel(*refs, bq, bk, tk, n_kt, has_mask):
     # Non-causal streaming kernel (one (batch*head, q-tile) grid step):
     # q (1, Bq, D), k/v whole (1, Tk_pad, D), [mask (Bq, Tk_pad) — absent
@@ -183,8 +193,11 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp,
     """Forward partials — see ``flash_block_partials`` for the contract."""
     b, tq, h, d = q.shape
     tk, dv = k.shape[1], v.shape[-1]
+    group = h // k.shape[2]
 
     if not _use_kernel(interpret, force_jnp):
+        if group > 1:
+            k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
         if causal:
             mask = jnp.tril(jnp.ones((tq, tk), bool))
         # scores/partials in f32, matching the kernel's accumulators, so
@@ -209,7 +222,9 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp,
     # walk (bq, bk) key tiles, so the live score tile is fixed-size and the
     # VMEM ceiling is set by the K/V residency (~2·Tk·D·itemsize), not Tk²
     def to_bht(x, t):
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1])
+        return jnp.moveaxis(x, 2, 1).reshape(b * x.shape[2], t, x.shape[-1])
+
+    kv_head = _kv_head(group)
 
     bq = _Q_TILE if tq > _Q_TILE else tq  # partial final tiles are fine
     bk = bq if causal else (_K_TILE if tk > _K_TILE else tk)
@@ -226,9 +241,9 @@ def _partials_impl(q, k, v, mask, scale, causal, interpret, force_jnp,
     )
     q_spec = pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                           memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, tk_pad, d), lambda i, j: (i, 0, 0),
+    k_spec = pl.BlockSpec((1, tk_pad, d), lambda i, j: (kv_head(i), 0, 0),
                           memory_space=pltpu.VMEM)
-    v_spec = pl.BlockSpec((1, tk_pad, dv), lambda i, j: (i, 0, 0),
+    v_spec = pl.BlockSpec((1, tk_pad, dv), lambda i, j: (kv_head(i), 0, 0),
                           memory_space=pltpu.VMEM)
     o_spec = pl.BlockSpec((1, bq, dv), lambda i, j: (i, j, 0),
                           memory_space=pltpu.VMEM)
@@ -346,7 +361,8 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, tk, n_kt, has_mask):
     dq_ref[0] = acc.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, n_qt, has_mask):
+def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, n_qt, has_mask,
+                    group=1):
     # grid step (i, kj): k/v tiles (1, bk, d), q/g_o whole (1, tq_pad, d),
     # m/g_l whole (1, tq_pad, 1), [mask (tq_pad, bk)], out dk/dv (1, bk, d).
     # m/g_l arrive TRANSPOSED (query positions on the SUBLANE dim): the
@@ -354,6 +370,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, n_qt, has_mask):
     # dynamic offsets to be provable multiples of 128 — only true when bq
     # is itself a multiple of 128, and bq = min(Tq, 512) — while sublane
     # offsets only need multiples of 8 (every bq here is).
+    # With grouped K/V (``group`` query heads a K/V head) a third grid axis
+    # walks the group's query heads; dk/dv (float32) sum over it.
     if has_mask:
         (q_ref, k_ref, v_ref, m_ref, gl_ref, go_ref, mask_ref,
          dk_ref, dv_ref) = refs
@@ -396,14 +414,28 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, tq, tk, n_qt, has_mask):
     zeros = jnp.zeros((bk, d), jnp.float32)
     dv_zeros = zeros if dv == d else jnp.zeros((bk, dv), jnp.float32)
     dk, dv = jax.lax.fori_loop(lo, n_qt, body, (zeros, dv_zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    member = pl.program_id(2)
+
+    @pl.when(member == 0)
+    def _():
+        dk_ref[0] = dk
+        dv_ref[0] = dv
+
+    @pl.when(member > 0)
+    def _():
+        dk_ref[0] += dk
+        dv_ref[0] += dv
 
 
 def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
                        name="flash_attention"):
     b, tq, h, d = q.shape
-    tk, dv = k.shape[1], v.shape[-1]
+    tk, dv, hk = k.shape[1], v.shape[-1], k.shape[2]
+    group = h // hk
     bq = _Q_TILE if tq > _Q_TILE else tq
     bk = bq if causal else (_Q_TILE if tk > _Q_TILE else tk)
     n_qt = (tq + bq - 1) // bq
@@ -411,8 +443,10 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
     tq_pad, tk_pad = n_qt * bq, n_kt * bk
 
     def to_bht(x, t, tp):
-        return _pad_to(jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1]),
-                       1, tp)
+        return _pad_to(jnp.moveaxis(x, 2, 1).reshape(
+            b * x.shape[2], t, x.shape[-1]), 1, tp)
+
+    kv_head = _kv_head(group)
 
     qf = to_bht(q, tq, tq_pad)
     gof = to_bht(g_o, tq, tq_pad)
@@ -432,8 +466,8 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
         return pl.BlockSpec((1, rows, width), lambda i, j: (i, j, 0),
                             memory_space=pltpu.VMEM)
 
-    def whole_spec(rows, width):
-        return pl.BlockSpec((1, rows, width), lambda i, j: (i, 0, 0),
+    def whole_spec(rows, width, row=lambda i: i):
+        return pl.BlockSpec((1, rows, width), lambda i, j: (row(i), 0, 0),
                             memory_space=pltpu.VMEM)
 
     mtile_spec = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
@@ -445,8 +479,8 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
     )
 
     # dq: one grid step per (batch*head, query tile), loop over key tiles
-    dq_in_specs = [tile_spec(bq, d), whole_spec(tk_pad, d),
-                   whole_spec(tk_pad, dv), mtile_spec, mtile_spec,
+    dq_in_specs = [tile_spec(bq, d), whole_spec(tk_pad, d, kv_head),
+                   whole_spec(tk_pad, dv, kv_head), mtile_spec, mtile_spec,
                    tile_spec(bq, dv)]
     dq_operands = [qf, kf, vf, mf, glf, gof]
     if maskf is not None:
@@ -488,27 +522,73 @@ def _partials_bwd_impl(q, k, v, mask, m, g_o, g_l, scale, causal, interpret,
                          memory_space=pltpu.VMEM)
         )
         dkv_operands.append(maskf)
-    dk_f, dv_f = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-            tq=tq, tk=tk, n_qt=n_qt, has_mask=maskf is not None,
-        ),
-        name=name + "_bwd_dkv",
-        grid=(b * h, n_kt),
-        in_specs=dkv_in_specs,
-        out_specs=(tile_spec(bk, d), tile_spec(bk, dv)),
-        out_shape=(
-            jax.ShapeDtypeStruct((b * h, tk_pad, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b * h, tk_pad, dv), v.dtype, vma=vma),
-        ),
-        interpret=interpret,
-        compiler_params=params,
-    )(*dkv_operands)
+    dkv_kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+        tq=tq, tk=tk, n_qt=n_qt, has_mask=maskf is not None,
+    )
+    if group == 1:
+        dk_f, dv_f = pl.pallas_call(
+            dkv_kernel,
+            name=name + "_bwd_dkv",
+            grid=(b * h, n_kt),
+            in_specs=dkv_in_specs,
+            out_specs=(tile_spec(bk, d), tile_spec(bk, dv)),
+            out_shape=(
+                jax.ShapeDtypeStruct((b * h, tk_pad, d), k.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b * h, tk_pad, dv), v.dtype, vma=vma),
+            ),
+            interpret=interpret,
+            compiler_params=params,
+        )(*dkv_operands)
+    else:
+        dk_f, dv_f = _grouped_dkv(
+            dkv_kernel, dkv_in_specs, dkv_operands, group=group, rows=b * hk,
+            n_kt=n_kt, bk=bk, shapes=((tk_pad, d), (tk_pad, dv)), vma=vma,
+            name=name, interpret=interpret, params=params)
+        dk_f, dv_f = dk_f.astype(k.dtype), dv_f.astype(v.dtype)
 
     def from_bht(x, t):
-        return jnp.moveaxis(x[:, :t].reshape(b, h, t, x.shape[-1]), 1, 2)
+        return jnp.moveaxis(
+            x[:, :t].reshape(b, x.shape[0] // b, t, x.shape[-1]), 1, 2)
 
     return from_bht(dq_f, tq), from_bht(dk_f, tk), from_bht(dv_f, tk)
+
+
+def _grouped_dkv(kernel, in_specs, operands, *, group, rows, n_kt, bk,
+                 shapes, vma, name, interpret, params):
+    """The dk/dv kernel over a grid of ``(B*Hkv, key tiles, group)``: K/V
+    tiles by K/V head, the query-side operands (whole, by query head
+    ``i * group + member``, or a mask shared by every head) by the group's
+    member, dk/dv in float32 summed over the members in the output block,
+    which stays put along the last axis."""
+    def member(spec, by_head):
+        index = spec.index_map
+        if by_head:
+            return pl.BlockSpec(
+                spec.block_shape,
+                lambda i, j, r: index(i * group + r, j),
+                memory_space=pltpu.VMEM)
+        return pl.BlockSpec(spec.block_shape, lambda i, j, r: index(i, j),
+                            memory_space=pltpu.VMEM)
+
+    # q, k, v, m, g_l, g_o[, mask]: k and v by K/V head, the mask shared
+    by_head = (True, False, False, True, True, True, False)
+    specs = [member(s, h) for s, h in zip(in_specs, by_head)]
+    out_specs = tuple(
+        pl.BlockSpec((1, bk, width), lambda i, j, r: (i, j, 0),
+                     memory_space=pltpu.VMEM) for _, width in shapes)
+    return pl.pallas_call(
+        functools.partial(kernel, group=group),
+        name=name + "_bwd_dkv",
+        grid=(rows, n_kt, group),
+        in_specs=specs,
+        out_specs=out_specs,
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((rows,) + s, jnp.float32, vma=vma)
+            for s in shapes),
+        interpret=interpret,
+        compiler_params=params,
+    )(*operands)
 
 
 def partials_name(name: str) -> str:
@@ -565,9 +645,13 @@ def flash_block_partials(
     """Streaming-softmax partials of ``softmax(q k^T * scale) v`` for one
     K/V block.
 
-    ``q``/``k``: (B, Tq|Tk, H, D); ``v``: (B, Tk, H, Dv), where the value
-    width ``Dv`` may differ from the query/key width ``D`` (latent
-    attention: 192 against 128); ``mask``: (Tq, Tk)
+    ``q``: (B, Tq, H, D); ``k``: (B, Tk, Hkv, D); ``v``: (B, Tk, Hkv, Dv),
+    where the value width ``Dv`` may differ from the query/key width ``D``
+    (latent attention: 192 against 128) and ``Hkv`` divides ``H``: grouped
+    K/V, query head ``h`` attending with K/V head ``h // (H // Hkv)``
+    (the kernels read that head's K/V through their block index, with no
+    copy of K/V per query head; ``Hkv == H`` builds the kernels of
+    ungrouped attention unchanged); ``mask``: (Tq, Tk)
     bool, True = attend (shared across batch and heads — the ring-step
     causal mask depends only on block offsets), or ``None`` for no masking
     (skips the mask load and selects entirely).
@@ -607,6 +691,10 @@ def flash_block_partials(
     ``acc / l`` normalization — i.e. any correct use) gets exact gradients;
     differentiating ``m`` in isolation returns zero by design.
     """
+    if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads cannot share {k.shape[2]} key and "
+            f"{v.shape[2]} value heads evenly")
     if causal:
         if mask is not None:
             raise ValueError("causal=True replaces mask; pass mask=None")

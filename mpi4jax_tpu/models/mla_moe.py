@@ -51,6 +51,9 @@ import jax.numpy as jnp
 
 from ..kernels.flash_attention import flash_block_partials, partials_name
 from ..parallel.moe import HeldExperts, held_experts_layer
+from . import training
+from .training import is_norm as _is_norm
+from .training import rms_norm as _rms_norm
 
 __all__ = [
     "Dims",
@@ -67,7 +70,6 @@ GRAD_GROUPS = ("embed", "head", "attn", "dense_ffn", "shared", "routed",
                "router", "mtp_proj", "norms")
 ATTENTION_KERNEL = "mla_attention"
 EXPERT_KERNEL = "moe_gmm"
-_CE_CHUNK = 1024
 # what a decoder layer's rematerialisation keeps: the attention kernel's
 # partials (about 69 MB a layer at 8192 tokens and 32 heads of value width
 # 128), so the backward reads them instead of running the kernel again;
@@ -98,6 +100,7 @@ class Dims:
     held: int             # experts this chip holds in each MoE layer
     top_k: int
     routed_scaling: float
+    router_eps: float     # the routing weights' normaliser's epsilon
     moe_layers: int       # after the one dense layer, before the MTP block
     vocab: int
     rope_theta: float
@@ -137,6 +140,8 @@ class Dims:
             held_first=dep["held_first"], held=c["n_routed_experts"],
             top_k=c["num_experts_per_tok"],
             routed_scaling=float(c["routed_scaling_factor"]),
+            # DeepSeek-V3's modelling adds 1e-20 (no config.json key)
+            router_eps=1e-20,
             moe_layers=c["num_hidden_layers"] - 1, vocab=c["vocab_size"],
             rope_theta=float(c["rope_theta"]), eps=float(c["rms_norm_eps"]),
             mtp_weight=float(assumed["mtp_loss_weight"]),
@@ -180,10 +185,6 @@ def _shapes(d: Dims) -> dict:
         "mtp": {"h_norm": (h,), "e_norm": (h,), "eh_proj": (2 * h, h),
                 "block": moe_block, "final_norm": (h,)},
     }
-
-
-def _is_norm(path) -> bool:
-    return str(getattr(path[-1], "key", path[-1])).endswith("norm")
 
 
 def grad_group(path) -> str:
@@ -236,11 +237,6 @@ def init_state(d: Dims, params, bias) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _rms_norm(x, w, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
 def _rope(x, theta):
     """Rotary position on interleaved pairs ``(x[2i], x[2i+1])`` of the
     last axis; positions along axis 0."""
@@ -261,10 +257,7 @@ class _Model:
         self.d = d
         self.interpret = interpret
 
-    @staticmethod
-    def mm(a, w):
-        return jnp.dot(a.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
+    mm = staticmethod(training.mm)
 
     def mla(self, p, x):
         d, t = self.d, x.shape[0]
@@ -304,26 +297,16 @@ class _Model:
         routed, loads = held_experts_layer(
             xn, HeldExperts(r["router"], r["w_gate_up"], r["w_down"]),
             bias, held=(d.held_first, d.held), top_k=d.top_k,
-            routed_scaling=d.routed_scaling, interpret=self.interpret)
+            routed_scaling=d.routed_scaling, norm_eps=d.router_eps,
+            interpret=self.interpret)
         return h + self.swiglu(p["shared"], xn) + routed, loads
 
     def cross_entropy(self, h, norm, head, targets):
         """Mean cross-entropy over the slice's logits, a chunk of tokens
         at a time (each chunk rematerialised)."""
-        t = h.shape[0]
-        chunk = _CE_CHUNK if t % _CE_CHUNK == 0 else t
-
-        @jax.checkpoint
-        def one(xs):
-            hc, tc = xs
-            logits = self.mm(_rms_norm(hc, norm, self.d.eps), head)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            hit = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-            return jnp.sum(lse - hit)
-
-        sums = jax.lax.map(one, (h.reshape(-1, chunk, h.shape[-1]),
-                                 targets.reshape(-1, chunk)))
-        return jnp.sum(sums) / t
+        return training.cross_entropy(
+            h, targets,
+            lambda hc: self.mm(_rms_norm(hc, norm, self.d.eps), head))
 
     def trunk(self, params, bias, tokens):
         """The main model's last hidden state (before its final norm) at
@@ -375,36 +358,10 @@ def loss_and_aux(d: Dims, params, bias, tokens, *, interpret=False):
 # ---------------------------------------------------------------------------
 
 
-def _group_norms(grads):
-    sq = dict.fromkeys(GRAD_GROUPS, jnp.zeros((), jnp.float32))
-    for path, g in jax.tree.flatten_with_path(grads)[0]:
-        name = grad_group(path)
-        sq[name] = sq[name] + jnp.sum(jnp.square(g))
-    return jnp.sqrt(jnp.stack([sq[n] for n in GRAD_GROUPS]))
-
-
-def _adamw(d: Dims, state, grads):
-    """AdamW with decoupled weight decay (not on norm scales) at a
-    constant learning rate."""
-    count = state["count"] + 1
-    step_count = count.astype(jnp.float32)
-    c1 = 1.0 - d.beta1 ** step_count
-    c2 = 1.0 - d.beta2 ** step_count
-
-    def update(path, p, g, m, v):
-        m = d.beta1 * m + (1.0 - d.beta1) * g
-        v = d.beta2 * v + (1.0 - d.beta2) * g * g
-        step = (m / c1) / (jnp.sqrt(v / c2) + d.adam_eps)
-        if not _is_norm(path):
-            step = step + d.weight_decay * p
-        return p - d.lr * step, m, v
-
-    out = jax.tree.map_with_path(update, state["params"], grads, state["m"],
-                                 state["v"])
-    pick = [jax.tree.map(lambda t, i=i: t[i], out,
-                         is_leaf=lambda t: isinstance(t, tuple))
-            for i in range(3)]
-    return pick, count
+def _adamw(d, state, grads):
+    """AdamW at the constant rate ``d.lr``; the module's own name, so that
+    a test can put a planted fault in its place."""
+    return training.adamw(d, state, grads, d.lr)
 
 
 def make_train_step(d: Dims, *, interpret=False):
@@ -423,16 +380,15 @@ def make_train_step(d: Dims, *, interpret=False):
             has_aux=True)
         grads, (losses, loads) = grad_fn(state["params"])
         (params, m, v), count = _adamw(d, state, grads)
-        mean = jnp.mean(loads.astype(jnp.float32), -1, keepdims=True)
-        move = d.bias_rate * jnp.sign(mean - loads.astype(jnp.float32))
+        move = training.bias_move(loads, d.bias_rate)
         bias = {"moe": state["bias"]["moe"] + move[:-1],
                 "mtp": state["bias"]["mtp"] + move[-1]}
-        held = jax.lax.dynamic_slice_in_dim(loads, d.held_first, d.held, 1)
-        capacity = tokens.shape[0] - 2
-        capacity *= min(d.top_k, d.held)
-        dropped = jnp.sum(jnp.maximum(held.sum(-1) - capacity, 0))
-        out = {"loss": jnp.stack(losses), "grad_norms": _group_norms(grads),
-               "loads": loads, "dropped": dropped}
+        out = {"loss": jnp.stack(losses),
+               "grad_norms": training.group_norms(grads, grad_group,
+                                                  GRAD_GROUPS),
+               "loads": loads,
+               "dropped": training.dropped(loads, d.held_first, d.held,
+                                           tokens.shape[0] - 2, d.top_k)}
         return ({"params": params, "m": m, "v": v, "count": count,
                  "bias": bias}, out)
 

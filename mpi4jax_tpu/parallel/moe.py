@@ -258,11 +258,13 @@ class HeldExperts(NamedTuple):
 
 
 def route_sigmoid_topk(x, router, bias, *, top_k: int,
-                       routed_scaling: float):
+                       routed_scaling: float, norm_eps: float):
     """Sigmoid top-k routing with a selection bias, in float32 (the
     DeepSeek-V3 ``noaux_tc`` rule with one group): ``s = sigmoid(x @
     router)``; the experts chosen are the ``top_k`` largest of ``s +
-    bias``; their weights are ``routed_scaling * s_i / sum_chosen s``.
+    bias``; their weights are ``routed_scaling * s_i / (sum_chosen s +
+    norm_eps)`` (the models' own epsilon: DeepSeek-V3's modelling adds
+    1e-20, LFM2's 1e-6).
     ``bias`` only selects (it carries no gradient).  Returns ``(experts
     (t, k) int32, weights (t, k) f32, loads (experts,) int32)``: ``loads``
     counts, for every expert, the tokens that chose it."""
@@ -275,7 +277,7 @@ def route_sigmoid_topk(x, router, bias, *, top_k: int,
     _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     weights = routed_scaling * chosen / (chosen.sum(-1, keepdims=True)
-                                         + 1e-20)
+                                         + norm_eps)
     loads = jnp.zeros(router.shape[1], jnp.int32).at[idx.reshape(-1)].add(1)
     return idx.astype(jnp.int32), weights, loads
 
@@ -292,11 +294,13 @@ def held_capacity_rows(tokens: int, top_k: int, held: int) -> int:
 
 
 def held_experts_layer(x, params: HeldExperts, bias, *, held, top_k: int,
-                       routed_scaling: float, interpret: bool = False):
+                       routed_scaling: float, norm_eps: float,
+                       interpret: bool = False):
     """The routed part of a top-k sigmoid MoE layer on the chip that holds
     experts ``held = (first, count)`` of ``params.router.shape[1]``.
 
-    Every token is routed over all experts (``route_sigmoid_topk``); the
+    Every token is routed over all experts (``route_sigmoid_topk``, with
+    ``norm_eps`` in its weights' normaliser); the
     assignments to held experts are sorted by expert into whole tiles of
     a dispatch buffer (``held_capacity_rows``: nothing is dropped) and
     run through the grouped products of ``kernels/grouped_matmul.py``
@@ -320,7 +324,8 @@ def held_experts_layer(x, params: HeldExperts, bias, *, held, top_k: int,
     first, count = held
     tokens, d = x.shape
     idx, weights, loads = route_sigmoid_topk(
-        x, params.router, bias, top_k=top_k, routed_scaling=routed_scaling)
+        x, params.router, bias, top_k=top_k, routed_scaling=routed_scaling,
+        norm_eps=norm_eps)
 
     # assignments sorted by held expert; the others sort last
     local = idx.reshape(-1) - first

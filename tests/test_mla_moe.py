@@ -352,7 +352,8 @@ def test_shares_of_all_ranks_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.key(1), (TOKENS, d.hidden))
     bias = 0.01 * jax.random.normal(jax.random.key(2), (d.experts,))
     r = p["routed"]
-    kw = dict(top_k=d.top_k, routed_scaling=d.routed_scaling)
+    kw = dict(top_k=d.top_k, routed_scaling=d.routed_scaling,
+              norm_eps=d.router_eps)
 
     def share(first, count):
         held = moe.HeldExperts(r["router"],
@@ -383,7 +384,7 @@ def test_routing_is_top_k_of_biased_sigmoid_with_normalised_weights():
     router = jax.random.normal(jax.random.key(4), (8, 6))
     bias = jnp.array([0.0, 5.0, 0.0, 0.0, 0.0, -5.0])
     idx, w, loads = moe.route_sigmoid_topk(x, router, bias, top_k=2,
-                                           routed_scaling=2.5)
+                                           routed_scaling=2.5, norm_eps=1e-20)
     assert (np.asarray(idx) == 1).any(1).all()      # the bias selects
     assert not (np.asarray(idx) == 5).any()
     s = jax.nn.sigmoid(x @ router)
@@ -404,7 +405,8 @@ def test_no_token_is_dropped_however_uneven_the_routing():
     bias = jnp.array([9.0, 9.0, 0, 0, 0, 0, 0, 0])
     held = moe.HeldExperts(r["router"], r["w_gate_up"], r["w_down"])
     y, loads = moe.held_experts_layer(x, held, bias, held=(0, 4), top_k=2,
-                                      routed_scaling=2.5)
+                                      routed_scaling=2.5,
+                                      norm_eps=d.router_eps)
     assert loads[:2].tolist() == [TOKENS, TOKENS]
     assert moe.held_capacity_rows(TOKENS, 2, 4) >= 2 * TOKENS
     want, _ = ref.routed_experts(c, r, bias, x)
