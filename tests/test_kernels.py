@@ -9,6 +9,7 @@ back as ``m = -inf``, ``l = 0``, ``o = 0`` rather than NaN.
 """
 
 import math
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -16,18 +17,28 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from mpi4jax_tpu.kernels import flash_attention
 from mpi4jax_tpu.kernels.flash_attention import (
+    causal_tile_counts,
     flash_block_partials,
     merge_partials,
 )
 
 
 def _qkv(seed, b, tq, tk, h, d, dtype=jnp.float32):
+    """q, k, v; ``h`` is the heads or ``(query heads, K/V heads)``, ``d``
+    the width or ``(q/k width, v width)``."""
+    h, hkv = h if isinstance(h, tuple) else (h, h)
+    d, dv = d if isinstance(d, tuple) else (d, d)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, tq, h, d), dtype)
-    k = jax.random.normal(ks[1], (b, tk, h, d), dtype)
-    v = jax.random.normal(ks[2], (b, tk, h, d), dtype)
+    k = jax.random.normal(ks[1], (b, tk, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (b, tk, hkv, dv), dtype)
     return q, k, v
+
+
+def _scale(d):
+    return 1.0 / math.sqrt(d[0] if isinstance(d, tuple) else d)
 
 
 @pytest.mark.parametrize(
@@ -190,6 +201,11 @@ def test_merge_with_fully_masked_block_is_identity():
         (2, 16, 4, 32),     # single q tile
         (1, 1024, 1, 32),   # two full tiles (exercises the fori_loop)
         (1, 1100, 1, 32),   # ragged final tile (K/V padding + kpos guard)
+        # grouped K/V (4 query heads a K/V head) at width 64 over three
+        # tiles, whole and ragged; latent widths (qk 192, v 128)
+        pytest.param(1, 1536, (8, 2), 64, id="grouped-64-t1536"),
+        pytest.param(1, 1100, (8, 2), 64, id="grouped-64-t1100"),
+        pytest.param(1, 1536, 2, (192, 128), id="qk192-v128-t1536"),
     ],
 )
 def test_causal_kernel_matches_tril_mask(b, t, h, d):
@@ -197,7 +213,7 @@ def test_causal_kernel_matches_tril_mask(b, t, h, d):
     kernel/jnp path given the equivalent triangular mask, including across
     tile boundaries and ragged tails."""
     q, k, v = _qkv(3, b, t, t, h, d)
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale(d)
     o_c, m_c, l_c = flash_block_partials(q, k, v, None, scale=scale,
                                          causal=True, interpret=True)
     mask = jnp.tril(jnp.ones((t, t), bool))
@@ -226,6 +242,17 @@ def _normalized(o, l):
         (1, 550, 550, 1, 32, True, False),  # ragged tiles (padding guards)
         (1, 257, 1100, 1, 32, False, False),  # streaming non-causal tiles
         (1, 257, 1100, 1, 32, False, True),   # ... with a mask
+        # grouped K/V at width 64 over three tiles, whole and ragged
+        pytest.param(1, 1536, 1536, (8, 2), 64, True, False,
+                     id="grouped-64-t1536"),
+        pytest.param(1, 1100, 1100, (8, 2), 64, True, False,
+                     id="grouped-64-t1100"),
+        pytest.param(1, 1536, 1536, 2, (192, 128), True, False,
+                     id="qk192-v128-t1536"),
+        # no user mask: the ragged last key tile (dq, forward) and the
+        # ragged last query tile (dk/dv) are the only masked tiles
+        pytest.param(1, 1100, 1300, 2, 32, False, False,
+                     id="ragged-tiles-only-masked"),
     ],
 )
 def test_grad_kernel_matches_jnp_path(b, tq, tk, h, d, causal, masked):
@@ -237,7 +264,7 @@ def test_grad_kernel_matches_jnp_path(b, tq, tk, h, d, causal, masked):
         jax.random.bernoulli(jax.random.PRNGKey(7), 0.8, (tq, tk))
         if masked else None
     )
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale(d)
 
     def loss(q, k, v, **kwargs):
         o, _, l = flash_block_partials(
@@ -352,3 +379,41 @@ def test_causal_kernel_validation():
     with pytest.raises(ValueError, match="replaces mask"):
         flash_block_partials(q2, k2, v2, jnp.ones((16, 16), bool),
                              scale=1.0, causal=True, interpret=True)
+
+
+@pytest.mark.parametrize("t,unmasked,masked", [
+    (512, 0, 1),
+    (1536, 3, 3),
+    (8192, 120, 16),    # a layer of the latent-attention training cell
+    (16384, 496, 32),   # a layer of the hybrid training cell
+])
+def test_causal_tile_counts(t, unmasked, masked):
+    """A causal call of n whole tiles puts the n diagonal tiles of each
+    kernel on the masked path and the n(n-1)/2 below them on the unmasked
+    one, for each (batch, head) pair."""
+    assert causal_tile_counts(t) == {
+        k: (unmasked, masked) for k in ("fwd", "bwd_dq", "bwd_dkv")}
+
+
+@pytest.mark.parametrize("over_queries", [False, True],
+                         ids=["walking-keys", "walking-queries"])
+@pytest.mark.parametrize("t", [300, 1100, 1536, 2048])
+def test_tile_runs_cover_the_causal_triangle_once(t, over_queries):
+    """The runs the kernels walk visit each tile of the causal triangle
+    once and mask exactly the diagonal tiles and, walking queries, a
+    ragged last query tile (walking keys, a ragged last key tile is only
+    ever on the diagonal)."""
+    bq = 512 if t > 512 else t
+    n = -(-t // bq)
+    ragged = t % bq != 0
+    seen = Counter()
+    for i in range(n):
+        for start, count, needs_mask in flash_attention._tile_runs(
+                i, n, causal=True, ragged=ragged, masked=False,
+                over_queries=over_queries):
+            for j in range(int(start), int(start) + int(count)):
+                qt, kt = (j, i) if over_queries else (i, j)
+                seen[qt, kt] += 1
+                assert needs_mask == (
+                    qt == kt or (over_queries and ragged and qt == n - 1))
+    assert seen == {(qt, kt): 1 for qt in range(n) for kt in range(qt + 1)}
